@@ -14,9 +14,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
 import numpy as np
 
 from nnstreamer_tpu.elements.converter import TensorConverter

@@ -13,9 +13,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
 import jax
 import numpy as np
 
@@ -53,8 +50,8 @@ print(f"free slots at end: {cb.n_free}/4")
 # step() pays one dispatch + one [B] readback PER TOKEN; step_pump(n)
 # scans n steps in one program with ONE [B, n] readback, and
 # spec_pump(rounds, k) runs whole speculative rounds on device with
-# proposals mined there (device_ngram_propose). On a remote-attached
-# TPU each saved readback is a full round trip.
+# proposals mined there (device_ngram_propose). Each saved readback is
+# a host↔device sync.
 cb2 = ContinuousBatcher(params, n_heads=8, n_slots=4, max_len=128,
                         prompt_len=32)
 rng = np.random.default_rng(0)

@@ -24,9 +24,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), ".."))
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
 import multiprocessing as mp
 
 TRACE_DIR = os.environ.get("NNS_TRACE_DIR")

@@ -21,10 +21,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env  # noqa: E402
-
-honor_jax_platforms_env()
-
 import jax  # noqa: E402
 
 import numpy as np  # noqa: E402

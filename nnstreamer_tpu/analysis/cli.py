@@ -18,10 +18,6 @@ import argparse
 import json
 import sys
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="nns-lint", description=__doc__)

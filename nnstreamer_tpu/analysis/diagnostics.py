@@ -261,7 +261,7 @@ CATALOG: Dict[str, Tuple[Severity, str, str]] = {
         "a Pallas block is misaligned or its index map is hazardous: a "
         "block dim that is neither the whole axis nor a multiple of the "
         "hardware tile (lane 128; sublane 8/16/32 for 4/2/1-byte "
-        "dtypes) pads every DMA and register, and an index map that "
+        "dtypes) is refused by the TPU compiler, and an index map that "
         "picks blocks outside the block grid (or a scalar-prefetch "
         "operand whose values drift from its declared SMEM shape) reads "
         "garbage",

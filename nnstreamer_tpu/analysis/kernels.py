@@ -12,11 +12,10 @@ DMA engine is fed" — becomes checkable facts:
   what hides the next DMA behind compute), plus all scratch. The sum
   must fit per-core VMEM (``[tpu] vmem_bytes``, default 16 MiB —
   costmodel.configured_vmem_bound).
-- **Tile alignment** (NNS-W128): a block dim that is neither the whole
-  axis nor 1 pads up to the hardware tile — last dim to the 128-wide
-  lane, second-minor to the dtype sublane (f32 8, bf16 16, int8 32); a
-  misaligned pick silently wastes the padded fraction of every DMA and
-  every register.
+- **Tile alignment** (NNS-W128): each of a block's last two dims must
+  be the whole axis or a multiple of the hardware tile — last dim the
+  128-wide lane, second-minor the dtype sublane (f32 8, bf16 16, int8
+  32). The TPU compiler refuses any other block, a unit dim included.
 - **Index-map hazards** (NNS-W128): the REAL index-map callables run
   over the REAL grid (with representative scalar-prefetch values),
   catching out-of-bounds block picks and prefetch shape drift
@@ -32,9 +31,9 @@ a pipeline that REQUESTS impl=pallas on an element whose kernel would
 degrade to the jnp path (unsupported dtype, kill switch, a mode with no
 kernel) is told at lint time, not by reading dispatch tallies after the
 frames already ran. :func:`differential_sweep` and :func:`engage` are
-the dynamic complements: interpret-mode parity vs each kernel's jnp
-reference, and dispatch-tally proof that a requested pallas path
-actually engaged (docs/kernel-analysis.md).
+the dynamic complements: parity (interpreted off-TPU, compiled on a
+chip) vs each kernel's jnp reference, and dispatch-tally proof that a
+requested pallas path actually engaged (docs/kernel-analysis.md).
 """
 
 from __future__ import annotations
@@ -157,16 +156,18 @@ class CaseReport:
 
 
 def _alignment_problems(b: BlockDesc) -> List[str]:
-    """Lane/sublane tile verdicts for one block. A dim equal to the
-    whole axis is exempt (Pallas pads a sole partial block once, not
-    per step); so is 1 (broadcast/scalar rows live in their own
-    layout)."""
+    """Lane/sublane tile verdicts for one block: the TPU compiler's
+    block rule. The last two block dims must each be the whole axis or
+    a multiple of the hardware tile — 128 lanes, and the dtype's
+    sublane count — and it REFUSES anything else, a unit dim included:
+    a ``(1, 1, 1, d)`` block over ``[B, 1, H, d]`` picks one head off
+    the sublane axis, which no tiled DMA can do."""
     probs: List[str] = []
     if not b.block_shape:
         return probs
     dt = _np_dtype(b.dtype)
     last_b, last_a = b.block_shape[-1], b.array_shape[-1]
-    if last_b not in (1, last_a) and last_b % LANE:
+    if last_b != last_a and last_b % LANE:
         probs.append(
             f"last dim {last_b} is neither the whole axis ({last_a}) nor "
             f"a multiple of the {LANE}-wide lane tile"
@@ -174,7 +175,7 @@ def _alignment_problems(b: BlockDesc) -> List[str]:
     sub = SUBLANE.get(dt.itemsize)
     if sub is not None and len(b.block_shape) >= 2:
         sec_b, sec_a = b.block_shape[-2], b.array_shape[-2]
-        if sec_b not in (1, sec_a) and sec_b % sub:
+        if sec_b != sec_a and sec_b % sub:
             probs.append(
                 f"second-minor dim {sec_b} is neither the whole axis "
                 f"({sec_a}) nor a multiple of the {dt.name} sublane "

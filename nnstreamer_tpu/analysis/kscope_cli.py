@@ -3,7 +3,7 @@
     nns-kscope                     # VMEM/alignment/roofline per kernel x shape
     nns-kscope --json              # machine-readable rows + findings
     nns-kscope --kernel flash_attention
-    nns-kscope --self-check        # wiring check + interpret-mode parity sweep
+    nns-kscope --self-check        # wiring check + parity sweep vs the jnp refs
     nns-kscope --self-check --full # ... over the full shape grid (slow)
     nns-kscope --engage            # prove requested pallas paths engage
     nns-kscope --strict            # warnings fail hard (exit 2)
@@ -24,10 +24,6 @@ from __future__ import annotations
 import argparse
 import json
 import sys
-
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
 
 
 def _print_case(r) -> None:
@@ -57,7 +53,8 @@ def main(argv=None) -> int:
     ap.add_argument(
         "--self-check", action="store_true",
         help="W127-W129 emitters<->catalog<->docs + registry wiring, "
-        "then the interpret-mode differential sweep vs each kernel's "
+        "then the differential sweep (interpreter off-TPU, compiled "
+        "kernels on a chip) vs each kernel's "
         "jnp reference (tier-1 shape subset)",
     )
     ap.add_argument(

@@ -18,10 +18,6 @@ import argparse
 import json
 import sys
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 
 def _emit(report, as_json: bool, strict: bool) -> int:
     rc = report.exit_code

@@ -364,7 +364,7 @@ def dtype_findings(
     """NNS-W122 walker: silent f64/complex128 promotion (a wide value
     appears with no wide input) and traced-vs-negotiated output dtype
     drift. Pure jaxpr arithmetic — callable directly in tests under
-    ``jax.experimental.enable_x64``."""
+    ``jax.enable_x64``."""
     msgs: List[str] = []
     if not any(_is_wide(a.dtype) for a in jaxpr.in_avals):
         for eqn in _iter_eqns(jaxpr.jaxpr):

@@ -20,10 +20,6 @@ import argparse
 import json
 import sys
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(prog="nns-xray", description=__doc__)

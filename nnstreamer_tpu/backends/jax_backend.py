@@ -33,77 +33,11 @@ import numpy as np
 
 from nnstreamer_tpu import registry
 from nnstreamer_tpu.backends.base import Backend, BackendError, FilterProps
+from nnstreamer_tpu.compile_cache import ensure_compile_cache
 from nnstreamer_tpu.log import get_logger
 from nnstreamer_tpu.tensors.spec import DType, TensorSpec, TensorsSpec
 
 _log = get_logger("backends.jax")
-
-_cache_initialized = False
-
-
-def _init_persistent_cache() -> None:
-    """``NNS_TPU_COMPILE_CACHE_DIR`` (or ``[jax] persistent_cache``)
-    enables XLA's on-disk compilation cache — the checkpoint/resume
-    analogue for an inference framework (SURVEY.md §5.4:
-    compiled-executable persistence), cutting model-open time on every
-    process restart. The warm-restart path (Executor.drain/snapshot/
-    resume, docs/resilience.md) leans on it: a restarted pipeline
-    replays its programs from disk and reaches steady-state fps in
-    seconds instead of a cold recompile.
-
-    Corruption tolerant by construction: cache errors are forced
-    non-fatal (``jax_raise_persistent_cache_errors=False``), so a
-    truncated/garbage entry logs and recompiles — a stale cache can
-    slow a restart down, never crash it."""
-    global _cache_initialized
-    if _cache_initialized:
-        return
-    _cache_initialized = True
-    from nnstreamer_tpu.config import conf
-
-    cache_dir = (
-        os.environ.get("NNS_TPU_COMPILE_CACHE_DIR")
-        or conf().get("jax", "persistent_cache")
-    )
-    if not cache_dir:
-        return
-    cache_dir = os.path.expanduser(cache_dir)
-    try:
-        # CPU AOT cache entries embed the COMPILING host's feature set
-        # yet reload on any host (cpu_aot_loader then warns about
-        # mismatched machine features and may SIGILL mid-inference) —
-        # key the directory by a host fingerprint so a cache baked on
-        # one machine is never replayed on a different one. TPU entries
-        # key on the device kind already and stay SHARED (a fleet
-        # cache over NFS must not recompile per host CPU stepping), so
-        # the fingerprint applies only when the backend compiling into
-        # this cache is the CPU.
-        if jax.default_backend() == "cpu":
-            import hashlib
-            import platform as _platform
-
-            fp = _platform.machine()
-            try:
-                with open("/proc/cpuinfo") as f:
-                    flags = next(
-                        (ln for ln in f if ln.startswith("flags")), ""
-                    )
-                if flags:
-                    fp += (
-                        "-" + hashlib.sha1(flags.encode()).hexdigest()[:12]
-                    )
-            except OSError:
-                pass
-            cache_dir = os.path.join(cache_dir, fp)
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        # a bad cache entry (truncated write, version skew, bit rot) must
-        # log + recompile, never kill the pipeline
-        jax.config.update("jax_raise_persistent_cache_errors", False)
-        _log.info("persistent compilation cache at %s", cache_dir)
-    except Exception as exc:  # cache is an optimization, never fatal
-        _log.warning("persistent cache setup failed: %s", exc)
 
 
 def _spec_from_avals(avals) -> TensorsSpec:
@@ -147,7 +81,7 @@ class JaxBackend(Backend):
 
     # -- lifecycle ---------------------------------------------------------
     def open(self, props: FilterProps) -> None:
-        _init_persistent_cache()
+        ensure_compile_cache()
         self.props = props
         path = props.model_path
         options = props.custom_dict()

@@ -20,10 +20,6 @@ import os
 import sys
 import time
 
-from nnstreamer_tpu.platform_pin import honor_jax_platforms_env
-
-honor_jax_platforms_env()
-
 
 def _inspect(name: str | None) -> int:
     from nnstreamer_tpu import registry

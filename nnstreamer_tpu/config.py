@@ -48,10 +48,6 @@ _DEFAULTS: Dict[str, Dict[str, str]] = {
     "jax": {
         # default compute dtype for fused segments on TPU
         "compute_dtype": "bfloat16",
-        # on-disk XLA executable cache (SURVEY.md §5.4 checkpoint/resume
-        # analogue): ON by default — first model open compiles, every
-        # later process reloads in ms. Set empty to disable.
-        "persistent_cache": "~/.cache/nnstreamer_tpu/xla",
     },
     "edge": {
         "default_port": "3000",  # reference edge_common.h:36-37

@@ -96,7 +96,7 @@ def nms(
     if impl not in ("auto", "jnp", "pallas"):
         raise ValueError(f"nms impl {impl!r} not auto/jnp/pallas")
     from nnstreamer_tpu.ops.dispatch import record as _record_dispatch
-    from nnstreamer_tpu.ops.pallas._compat import pallas_ok
+    from nnstreamer_tpu.ops.pallas._compat import interpret_default, pallas_ok
 
     use_pallas = impl == "pallas" or (
         impl == "auto" and jax.default_backend() == "tpu"
@@ -113,7 +113,7 @@ def nms(
         # tests); auto never picks it there
         return pallas_nms(
             boxes, scores, iou_threshold, max_out,
-            interpret=jax.default_backend() != "tpu",
+            interpret=interpret_default(),
         )
     n = boxes.shape[0]
     k = min(max_out, n)

@@ -53,6 +53,7 @@ def crop_and_resize(image, boxes, out_h: int, out_w: int, impl: str = "auto"):
     → [N, out_h, out_w, C], image dtype.
     """
     if _use_pallas(impl, op="crop_and_resize", dtype=image.dtype):
+        from nnstreamer_tpu.ops.pallas._compat import interpret_default
         from nnstreamer_tpu.ops.pallas.image_kernels import (
             crop_and_resize as pallas_crop,
         )
@@ -60,8 +61,7 @@ def crop_and_resize(image, boxes, out_h: int, out_w: int, impl: str = "auto"):
         # explicit impl=pallas off-TPU runs the interpreter (parity
         # tests); auto never picks it there
         return pallas_crop(
-            image, boxes, out_h, out_w,
-            interpret=jax.default_backend() != "tpu",
+            image, boxes, out_h, out_w, interpret=interpret_default()
         )
     h, w, _ = image.shape
     boxes = boxes.astype(jnp.float32)
@@ -93,7 +93,7 @@ def _round_clip_cast(x, dtype):
     convention for integers: round + clip to the dtype's own range (a
     truncating astype would make integer results backend-dependent,
     and 0..255 would wrap int8 / clamp valid uint16). The ONE home of
-    this epilogue — the Pallas kernel mirrors it in-kernel."""
+    this epilogue — the Pallas kernels' wrapper applies it too."""
     if jnp.issubdtype(dtype, jnp.integer):
         info = jnp.iinfo(dtype)
         x = jnp.clip(jnp.round(x), info.min, info.max)
@@ -127,14 +127,12 @@ def resize_bilinear(image, out_h: int, out_w: int, impl: str = "auto"):
     squeeze = image.ndim == 3
     img = image[None] if squeeze else image
     if _use_pallas(impl, op="resize_bilinear", dtype=img.dtype):
+        from nnstreamer_tpu.ops.pallas._compat import interpret_default
         from nnstreamer_tpu.ops.pallas.image_kernels import (
             resize_bilinear as pallas_resize,
         )
 
-        out = pallas_resize(
-            img, out_h, out_w,
-            interpret=jax.default_backend() != "tpu",
-        )
+        out = pallas_resize(img, out_h, out_w, interpret=interpret_default())
     else:
         _, h, w, _ = img.shape
         box = jnp.asarray([[0.0, 0.0, float(w), float(h)]], jnp.float32)

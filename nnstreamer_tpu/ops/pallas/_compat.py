@@ -1,10 +1,6 @@
-"""jax-version compatibility shims and dispatch gating for the Pallas
-kernels.
-
-One home (the parallel layer's analogue is ``parallel/mesh.py
-shard_map``): the next upstream rename gets fixed once, not once per
-kernel module — and every dual-path dispatch site asks the same
-:func:`pallas_ok` question before committing to a kernel.
+"""Dispatch gating for the Pallas kernels: every dual-path dispatch
+site asks the same :func:`pallas_ok` question before committing to a
+kernel, and the same :func:`interpret_default` one before running it.
 """
 
 from __future__ import annotations
@@ -49,12 +45,12 @@ def pallas_ok(kernel: str, dtype: Optional[Any] = None) -> Tuple[bool, str]:
     return True, ""
 
 
-def compiler_params(pltpu, **kw):
-    """Version-portable TPU compiler params: newer jax renames
-    ``TPUCompilerParams`` -> ``CompilerParams`` (the fields used by the
-    in-tree kernels exist in both spellings). ``pltpu`` is passed in
-    because the kernels import it lazily (CPU runs interpret)."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:  # pragma: no cover - depends on the installed jax
-        cls = pltpu.TPUCompilerParams
-    return cls(**kw)
+def interpret_default() -> bool:
+    """Pallas kernels run compiled on a TPU backend and through the
+    interpreter everywhere else (the CPU test mesh). The one home of
+    that choice: every kernel factory, dispatch site and registry
+    ``run_case`` asks here, so the same parity sweep that interprets on
+    the CPU checks the Mosaic-compiled kernels on a chip."""
+    import jax
+
+    return jax.default_backend() != "tpu"

@@ -9,14 +9,21 @@ between; this kernel is the flash-style single pass — each cache block is
 read once, scores never leave VMEM, and the per-slot fill level arrives
 as a scalar-prefetch operand, so masking costs no extra HBM tensor.
 
-The kernel indexes the serving cache layout [B, S, H, D] directly via
-BlockSpecs (grid (B, H, k-blocks), block (1, bk, 1, d)) — no transpose,
-no pad, no bias materialization on the host side; ``pos`` [B] rides in
-SMEM. k innermost with "arbitrary" semantics (sequential on TPU), the
-online-softmax scratch (m, l, acc) carried across k iterations — the
-shared recurrence of ops/pallas/_primitives.py specialized to one query
-row. Blocks entirely beyond a slot's fill level are predicated off with
-@pl.when.
+The kernel indexes the serving cache layout [B, S, KV, D] directly via
+BlockSpecs (grid (B, k-blocks), block (1, bk, KV, d): every KV head of
+``bk`` positions in one contiguous DMA, the block's last two dims being
+the array's own — the (8, 128) rule Mosaic holds blocks to) — no
+transpose, no pad, no bias materialization on the host side; ``pos``
+[B] rides in SMEM. k innermost with "arbitrary" semantics (sequential
+on TPU), the online-softmax scratch (m, l, acc) carried across k
+iterations — the decode form of the shared recurrence in
+ops/pallas/_primitives.py, all heads at once. Blocks entirely beyond a
+slot's fill level are predicated off with @pl.when.
+
+Grouped-query attention: the wrapper lays the (tiny) query out as
+[B, g, KV, D] — query head ``kv*g + gi`` at ``[gi, kv]`` — so each of
+the g query rows a KV head serves is one [KV, D] tile in the cache
+block's own layout; the kernel walks gi over one resident cache block.
 
 Int8 caches: pass ``k_scale``/``v_scale`` [B, S, KV] (per-token-per-head
 symmetric scales, models/serving.quantize_kv layout) and int8 cache
@@ -33,29 +40,28 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.pallas import registry as _registry
-from nnstreamer_tpu.ops.pallas._compat import compiler_params as _compiler_params
+from nnstreamer_tpu.ops.pallas._compat import interpret_default, pallas_ok
 from nnstreamer_tpu.ops.pallas._primitives import (
     NEG_INF,
-    dequant_rows,
-    mask_dead_columns,
-    online_softmax_finalize,
+    decode_attend_block,
+    decode_softmax_finalize,
+    load_cache_block,
     online_softmax_init,
-    online_softmax_update,
-    scaled_qk,
 )
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
             scale: float, block_k: int, n_k: int, s_len: int,
             quantized: bool):
+    ks_ref = vs_ref = None
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    ki = pl.program_id(2)
+    ki = pl.program_id(1)
 
     @pl.when(ki == 0)
     def _init():
@@ -65,54 +71,62 @@ def _kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
     # positions 0..pos inclusive are attendable; a windowed ring passes
     # ABSOLUTE pos, so after a wrap pos+1 exceeds the cache length and
     # every row is live — clamp to the static cache length so the tail
-    # block's pad columns (cols in [s_len, n_k*block_k)) stay masked
+    # block's pad rows (positions in [s_len, n_k*block_k)) stay masked
     # instead of streaming pad garbage into the softmax.
     live_len = jnp.minimum(pos_ref[b] + 1, s_len)
 
     @pl.when(k_start < live_len)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)       # [1, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bk, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = dequant_rows(k, ks_ref[0, :, 0])
-            v = dequant_rows(v, vs_ref[0, :, 0])
-        s = scaled_qk(q, k, scale)                 # [1, bk]
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s, v = mask_dead_columns(s, v, cols, live_len)
-        m_ref[:], l_ref[:], acc_ref[:] = online_softmax_update(
-            s, v, m_ref[:], l_ref[:], acc_ref[:]
+        decode_attend_block(
+            q_ref, load_cache_block(k_ref, ks_ref),   # [bk, KV, d]
+            load_cache_block(v_ref, vs_ref), k_start, live_len, scale,
+            m_ref, l_ref, acc_ref,
         )
 
     @pl.when(ki == n_k - 1)
     def _final():
-        o_ref[0, 0] = online_softmax_finalize(l_ref[:], acc_ref[:], o_ref.dtype)
+        o_ref[0] = decode_softmax_finalize(l_ref[:], acc_ref[:], o_ref.dtype)
 
 
 def _pick_block(s_len: int, block_k: int) -> Tuple[int, int]:
     """(block size, grid length) covering s_len with ceil-division.
 
     Blocks need not divide the cache length: Pallas pads the tail block,
-    and the kernel's ``cols < live_len`` mask (live_len ≤ s_len) already
-    neutralizes the pad columns — so a prime or odd cache length keeps
-    full-width blocks instead of degenerating to 1-row blocks."""
+    and the kernel's ``position < live_len`` mask (live_len ≤ s_len)
+    already neutralizes the pad rows — so a prime or odd cache length
+    keeps full-width blocks instead of degenerating to 1-row blocks."""
     bk = min(block_k, s_len)
     return bk, -(-s_len // bk)
 
 
+def group_queries(q, n_kv: int):
+    """q [B, 1, H, D] → [B, g, KV, D]: query head ``kv*g + gi`` lands at
+    ``[gi, kv]``, one [KV, D] tile per group member in the cache
+    block's own (heads on sublanes) layout. g = H / KV; a no-op
+    relabelling under plain multi-head attention (g = 1)."""
+    b, _, h, d = q.shape
+    return q.reshape(b, n_kv, h // n_kv, d).transpose(0, 2, 1, 3)
+
+
+def ungroup_heads(o):
+    """Inverse of :func:`group_queries`: o [B, g, KV, D] → [B, 1, H, D]."""
+    b, g, n_kv, d = o.shape
+    return o.transpose(0, 2, 1, 3).reshape(b, 1, g * n_kv, d)
+
+
 # BlockSpec index maps — module-level so the registered LaunchPlan and
-# the live pallas_call share the SAME callables (grid (b, h, k-blocks),
-# pos prefetched). GQA: query head hi reads kv head hi//group.
-def _q_index_map(bi, hi, kk, pos_ref):
-    return (bi, 0, hi, 0)
+# the live pallas_call share the SAME callables (grid (b, k-blocks),
+# pos prefetched).
+def _q_index_map(bi, kk, pos_ref):
+    return (bi, 0, 0, 0)
 
 
-def _kv_index_map(group):
-    return lambda bi, hi, kk, pos_ref: (bi, kk, hi // group, 0)
+def _kv_index_map(bi, kk, pos_ref):
+    return (bi, kk, 0, 0)
 
 
-def _scale_index_map(group):
-    return lambda bi, hi, kk, pos_ref: (bi, kk, hi // group)
+def _scale_index_map(bi, kk, pos_ref):
+    return (bi, kk, 0)
 
 
 @functools.partial(
@@ -131,10 +145,10 @@ def decode_attention(
 ):
     """q [B,1,H,D], cache_k/v [B,S,KV,D] (the serving layout, consumed
     in place; KV ≤ H under grouped-query attention — query head hi reads
-    kv head hi//(H/KV) straight from the BlockSpec index map, no
-    expansion pass), pos [B] → o [B,1,H,D] float32. Positions > pos[b]
-    are masked per slot. With ``k_scale``/``v_scale`` [B,S,KV] the cache
-    arrays are int8 and dequantized blockwise in VMEM."""
+    kv head hi//(H/KV), no expansion pass), pos [B] → o [B,1,H,D]
+    float32. Positions > pos[b] are masked per slot. With
+    ``k_scale``/``v_scale`` [B,S,KV] the cache arrays are int8 and
+    dequantized blockwise in VMEM."""
     b, _, h, d = q.shape
     s_len = cache_k.shape[1]
     n_kv = cache_k.shape[2]
@@ -150,42 +164,35 @@ def decode_attention(
         _kernel, scale=scale, block_k=bk, n_k=n_k, s_len=s_len,
         quantized=quantized,
     )
-
-    from jax.experimental.pallas import tpu as pltpu  # lazy: CPU interprets
-
-    kv_spec = pl.BlockSpec((1, bk, 1, d), _kv_index_map(group))
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, d), _q_index_map),
-        kv_spec,
-        kv_spec,
-    ]
-    operands = [pos.astype(jnp.int32), q, cache_k, cache_v]
+    q_spec = pl.BlockSpec((1, group, n_kv, d), _q_index_map)
+    kv_spec = pl.BlockSpec((1, bk, n_kv, d), _kv_index_map)
+    in_specs = [q_spec, kv_spec, kv_spec]
+    operands = [pos.astype(jnp.int32), group_queries(q, n_kv), cache_k, cache_v]
     if quantized:
-        scale_spec = pl.BlockSpec((1, bk, 1), _scale_index_map(group))
+        scale_spec = pl.BlockSpec((1, bk, n_kv), _scale_index_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, h, n_k),
+        grid=(b, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, d), _q_index_map),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((group, n_kv, 1), jnp.float32),
+            pltpu.VMEM((group, n_kv, 1), jnp.float32),
+            pltpu.VMEM((group, n_kv, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, group, n_kv, d), jnp.float32),
         grid_spec=grid_spec,
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
-    return out
+    return ungroup_heads(out)
 
 
 def decode_attention_ref(q, cache_k, cache_v, pos, k_scale=None,
@@ -226,10 +233,9 @@ def make_decode_attention(interpret: Optional[bool] = None, **kwargs):
     instead of a trace-time Mosaic error; the resolved choice lands in
     the dispatch tally as op "decode_attention"."""
     from nnstreamer_tpu.ops.dispatch import record as _record_dispatch
-    from nnstreamer_tpu.ops.pallas._compat import pallas_ok
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     def attn(q, cache_k, cache_v, pos):
         payload = cache_k[0] if isinstance(cache_k, tuple) else cache_k
@@ -265,38 +271,39 @@ def _plan(params):
     group = h // n_kv
     bk, n_k = _pick_block(s_len, params.get("block_k", 128))
     quantized = dtype == "int8"
+    q_desc = ((b, group, n_kv, d), (1, group, n_kv, d))
     blocks = [
         _registry.BlockDesc(
-            "q", "in", (b, 1, h, d), (1, 1, 1, d), dtype if not quantized
-            else "float32", _q_index_map,
+            "q", "in", *q_desc, dtype if not quantized else "float32",
+            _q_index_map,
         ),
         _registry.BlockDesc(
-            "cache_k", "in", (b, s_len, n_kv, d), (1, bk, 1, d), dtype,
-            _kv_index_map(group),
+            "cache_k", "in", (b, s_len, n_kv, d), (1, bk, n_kv, d), dtype,
+            _kv_index_map,
         ),
         _registry.BlockDesc(
-            "cache_v", "in", (b, s_len, n_kv, d), (1, bk, 1, d), dtype,
-            _kv_index_map(group),
+            "cache_v", "in", (b, s_len, n_kv, d), (1, bk, n_kv, d), dtype,
+            _kv_index_map,
         ),
     ]
     if quantized:
         for nm in ("k_scale", "v_scale"):
             blocks.append(_registry.BlockDesc(
-                nm, "in", (b, s_len, n_kv), (1, bk, 1), "float32",
-                _scale_index_map(group),
+                nm, "in", (b, s_len, n_kv), (1, bk, n_kv), "float32",
+                _scale_index_map,
             ))
     blocks.append(_registry.BlockDesc(
-        "o", "out", (b, 1, h, d), (1, 1, 1, d), "float32", _q_index_map,
+        "o", "out", *q_desc, "float32", _q_index_map,
     ))
     import numpy as np
 
     return _registry.LaunchPlan(
-        grid=(b, h, n_k),
+        grid=(b, n_k),
         blocks=tuple(blocks),
         scratch=(
-            _registry.ScratchDesc("m", (1,)),
-            _registry.ScratchDesc("l", (1,)),
-            _registry.ScratchDesc("acc", (1, d)),
+            _registry.ScratchDesc("m", (group, n_kv, 1)),
+            _registry.ScratchDesc("l", (group, n_kv, 1)),
+            _registry.ScratchDesc("acc", (group, n_kv, d)),
         ),
         prefetch=(
             _registry.PrefetchDesc(
@@ -328,14 +335,14 @@ def _run_case(params):
         ks = jnp.asarray(rng.uniform(0.01, 0.1, (b, s_len, n_kv)), jnp.float32)
         vs = jnp.asarray(rng.uniform(0.01, 0.1, (b, s_len, n_kv)), jnp.float32)
         got = decode_attention(q, ck, cv, pos, k_scale=ks, v_scale=vs,
-                               block_k=block_k, interpret=True)
+                               block_k=block_k, interpret=interpret_default())
         want = decode_attention_ref(q, ck, cv, pos, k_scale=ks, v_scale=vs)
         return got, want, 2e-5
     cast = jnp.dtype(dtype)
     qd = q.astype(cast)
     ck = jnp.asarray(rng.standard_normal((b, s_len, n_kv, d)), jnp.float32).astype(cast)
     cv = jnp.asarray(rng.standard_normal((b, s_len, n_kv, d)), jnp.float32).astype(cast)
-    got = decode_attention(qd, ck, cv, pos, block_k=block_k, interpret=True)
+    got = decode_attention(qd, ck, cv, pos, block_k=block_k, interpret=interpret_default())
     want = decode_attention_ref(qd, ck, cv, pos)
     return got, want, (2e-2 if cast == jnp.bfloat16 else 2e-5)
 
@@ -348,7 +355,7 @@ def _probe():
     ck = jnp.asarray(rng.standard_normal((1, 16, 2, 8)), jnp.float32)
     cv = jnp.asarray(rng.standard_normal((1, 16, 2, 8)), jnp.float32)
     pos = jnp.asarray([7], jnp.int32)
-    np.asarray(make_decode_attention(interpret=True)(q, ck, cv, pos))
+    np.asarray(make_decode_attention()(q, ck, cv, pos))
 
 
 _registry.register(_registry.KernelSpec(
@@ -378,6 +385,14 @@ _registry.register(_registry.KernelSpec(
         ),
         _registry.ShapeCase(
             "serve-2048", {"b": 8, "h": 8, "d": 128, "s_len": 2048},
+        ),
+        # the width chip_smoke.py serves (16 heads of 128), fp and int8
+        _registry.ShapeCase(
+            "serve-h16-d128", {"b": 4, "h": 16, "d": 128, "s_len": 2048},
+        ),
+        _registry.ShapeCase(
+            "serve-h16-d128-int8",
+            {"b": 4, "h": 16, "d": 128, "s_len": 512, "dtype": "int8"},
         ),
     ),
     plan=_plan,

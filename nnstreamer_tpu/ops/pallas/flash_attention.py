@@ -32,9 +32,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.pallas import registry as _registry
-from nnstreamer_tpu.ops.pallas._compat import compiler_params as _compiler_params
+from nnstreamer_tpu.ops.pallas._compat import interpret_default
 from nnstreamer_tpu.ops.pallas._primitives import (
     NEG_INF,
     online_softmax_finalize,
@@ -152,9 +153,6 @@ def flash_attention(
         n_k=n_k,
         valid_len=t if t_pad != t else None,
     )
-
-    from jax.experimental.pallas import tpu as pltpu  # lazy: CPU tests interpret
-
     out = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b * h, t_pad, d), jnp.float32),
@@ -170,8 +168,7 @@ def flash_attention(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_compiler_params(
-            pltpu,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -190,7 +187,7 @@ def make_flash_attention(interpret: Optional[bool] = None, **kwargs):
     from nnstreamer_tpu.ops.pallas._compat import pallas_ok
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     def attn(q, k, v, causal: bool = True):
         ok, _ = pallas_ok("flash_attention", q.dtype)
@@ -259,7 +256,7 @@ def _run_case(params):
         q, k, v, causal=causal,
         block_q=params.get("block_q", 128),
         block_k=params.get("block_k", 128),
-        interpret=True,
+        interpret=interpret_default(),
     )
     want = dense_attention(q, k, v, causal=causal)
     return got, want, (2e-2 if dtype == jnp.bfloat16 else 2e-5)
@@ -273,7 +270,7 @@ def _probe():
         jnp.asarray(rng.standard_normal((1, 16, 1, 8)), jnp.float32)
         for _ in range(3)
     )
-    np.asarray(make_flash_attention(interpret=True, block_q=16, block_k=16)(q, k, v))
+    np.asarray(make_flash_attention(block_q=16, block_k=16)(q, k, v))
 
 
 _registry.register(_registry.KernelSpec(

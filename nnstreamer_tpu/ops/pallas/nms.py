@@ -25,9 +25,10 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.pallas import registry as _registry
-from nnstreamer_tpu.ops.pallas._compat import compiler_params as _compiler_params
+from nnstreamer_tpu.ops.pallas._compat import interpret_default
 
 
 # BlockSpec index map — module-level so the registered LaunchPlan and
@@ -35,6 +36,11 @@ from nnstreamer_tpu.ops.pallas._compat import compiler_params as _compiler_param
 # candidate list stays VMEM-resident across the greedy recurrence)
 def _whole_index_map(i):
     return (0, 0)
+
+
+def _pad_n(n: int) -> int:
+    """Candidate count padded to whole 128-lane tiles."""
+    return max(128, -(-n // 128) * 128)
 
 
 def _nms_kernel(coords_ref, scores_ref, alive_ref, *, n: int, n_pad: int,
@@ -50,11 +56,15 @@ def _nms_kernel(coords_ref, scores_ref, alive_ref, *, n: int, n_pad: int,
     alive_ref[:] = (scores_ref[:] > 0.0).astype(jnp.float32)
 
     def step(i, _):
-        # the i-th ranked candidate: scalar corners via a [1,1] slice
-        bx1 = coords_ref[0:1, pl.ds(i, 1)]
-        by1 = coords_ref[1:2, pl.ds(i, 1)]
-        bx2 = coords_ref[2:3, pl.ds(i, 1)]
-        by2 = coords_ref[3:4, pl.ds(i, 1)]
+        # the i-th ranked candidate's scalars by masked reduction: a
+        # dynamic single-lane slice is something Mosaic cannot align,
+        # and summing one selected lane with zeros is exact
+        pivot = col == i
+
+        def pick(row):
+            return jnp.sum(jnp.where(pivot, row, 0.0), axis=1, keepdims=True)
+
+        bx1, by1, bx2, by2 = pick(x1), pick(y1), pick(x2), pick(y2)
         barea = jnp.maximum(bx2 - bx1, 0.0) * jnp.maximum(by2 - by1, 0.0)
         iw = jnp.maximum(
             jnp.minimum(x2, bx2) - jnp.maximum(x1, bx1), 0.0
@@ -65,8 +75,8 @@ def _nms_kernel(coords_ref, scores_ref, alive_ref, *, n: int, n_pad: int,
         inter = iw * ih
         union = area + barea - inter
         iou = jnp.where(union > 0.0, inter / union, 0.0)
-        keep_i = alive_ref[0:1, pl.ds(i, 1)]  # [1,1]: still live?
         alive = alive_ref[:]
+        keep_i = pick(alive)  # [1,1]: still live?
         suppress = (
             (iou > thr)
             & (col > i)
@@ -99,23 +109,13 @@ def nms(
     sscores = scores.astype(jnp.float32)[order]
     # lane-pad the candidate list; padded columns carry score 0 (never
     # alive, never selected) and zero-area boxes (suppress nothing)
-    n_pad = max(128, -(-n // 128) * 128)
+    n_pad = _pad_n(n)
     coords = jnp.zeros((4, n_pad), jnp.float32)
     coords = coords.at[:, :n].set(sboxes.T)
     srow = jnp.zeros((1, n_pad), jnp.float32).at[0, :n].set(sscores)
     kernel = functools.partial(
         _nms_kernel, n=n, n_pad=n_pad, thr=float(iou_threshold)
     )
-    if interpret:
-        kw = {}
-    else:  # pragma: no cover - real-TPU path (CPU tests interpret)
-        from jax.experimental.pallas import tpu as pltpu
-
-        kw = {
-            "compiler_params": _compiler_params(
-                pltpu, dimension_semantics=("arbitrary",)
-            ),
-        }
     alive_row = pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
@@ -125,8 +125,10 @@ def nms(
             pl.BlockSpec((1, n_pad), _whole_index_map),
         ],
         out_specs=pl.BlockSpec((1, n_pad), _whole_index_map),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
         interpret=interpret,
-        **kw,
     )(coords, srow)
     alive = alive_row[0, :n] > 0.0
     # packing identical to the jnp reference (bit-comparable selection)
@@ -144,10 +146,6 @@ def nms(
 
 
 # -- kernel registration (nns-kscope) ----------------------------------------
-
-
-def _pad_n(n: int) -> int:
-    return max(128, -(-n // 128) * 128)
 
 
 def _plan(params):
@@ -193,7 +191,7 @@ def _run_case(params):
     boxes, scores = _boxes_scores(params)
     thr = params.get("thr", 0.5)
     max_out = params.get("max_out", 8)
-    got = nms(boxes, scores, thr, max_out, interpret=True)
+    got = nms(boxes, scores, thr, max_out, interpret=interpret_default())
     want = detection.nms(boxes, scores, thr, max_out, impl="jnp")
     # the two implementations are pinned bit-comparable (same ranking,
     # same suppression predicate, same packing)

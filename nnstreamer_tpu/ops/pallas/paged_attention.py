@@ -10,24 +10,28 @@ and the whole point of this kernel is that the arena is attended
 contiguous view ever materialized in HBM (the gather → attend →
 scatter round trip the jnp gather formulation pays).
 
-Mechanics (grid ``(B, H, nb)``, k innermost with "arbitrary"
-semantics):
+Mechanics (grid ``(B, nb)``, k innermost with "arbitrary" semantics):
 
 - the block table and per-slot fill levels ride as SCALAR-PREFETCH
   operands, so each grid step's BlockSpec index map picks the physical
   arena block to DMA (``tables[b, kb]``) before the body runs — each
-  live arena block is read from HBM exactly once per (slot, head);
+  live arena block, all its KV heads in one contiguous
+  ``(1, bs, KV, d)`` DMA (the block's last two dims are the array's
+  own: the (8, 128) rule Mosaic holds blocks to), is read from HBM
+  exactly once per slot;
 - blocks at or beyond a slot's fill level — including the
   scratch-mapped unallocated table tail — are predicated off with
-  ``@pl.when``; partially-filled blocks mask their dead columns to
+  ``@pl.when``; partially-filled blocks mask their dead positions to
   softmax weight exactly zero and zero the matching V rows, so
   arbitrary scratch content can never leak into the output;
 - the online-softmax scratch (m, l, acc) carries across blocks (the
-  shared recurrence of ops/pallas/_primitives.py), and the pending
-  token's OWN K/V (``fresh_k``/``fresh_v``, not yet in the arena — the
-  batcher lands it after the layer scan with one in-place block write)
-  folds in the final grid step: it is position ``pos``, the highest
-  live column, so the reduction order equals position order;
+  decode form of the shared recurrence in ops/pallas/_primitives.py,
+  all heads at once; grouped queries laid out [B, g, KV, D] as in
+  ops/pallas/decode_attention.py), and the pending token's OWN K/V
+  (``fresh_k``/``fresh_v``, not yet in the arena — the batcher lands it
+  after the layer scan with one in-place block write) folds in the
+  final grid step: it is position ``pos``, the highest live position,
+  so the reduction order equals position order;
 - int8 arenas pass ``k_scale``/``v_scale`` ``[N, bs, KV]`` (the
   per-token-per-head symmetric scales of models/serving.quantize_kv)
   and dequantize per block in VMEM — HBM traffic stays at the int8
@@ -47,28 +51,33 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from nnstreamer_tpu.ops.pallas import registry as _registry
-from nnstreamer_tpu.ops.pallas._compat import compiler_params as _compiler_params
+from nnstreamer_tpu.ops.pallas._compat import interpret_default
 from nnstreamer_tpu.ops.pallas._primitives import (
-    NEG_INF,
-    dequant_rows,
-    mask_dead_columns,
-    online_softmax_finalize,
+    decode_attend_block,
+    decode_scores,
+    decode_softmax_finalize,
+    decode_softmax_update,
+    load_cache_block,
     online_softmax_init,
-    online_softmax_update,
-    scaled_qk,
+)
+from nnstreamer_tpu.ops.pallas.decode_attention import (
+    group_queries,
+    ungroup_heads,
 )
 
 
 def _kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, fk_ref, fv_ref, *rest,
             scale: float, block_k: int, n_b: int, quantized: bool):
+    ks_ref = vs_ref = None
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    kb = pl.program_id(2)
+    kb = pl.program_id(1)
+    group = q_ref.shape[1]
 
     @pl.when(kb == 0)
     def _init():
@@ -82,17 +91,10 @@ def _kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, fk_ref, fv_ref, *rest,
 
     @pl.when(k_start < hist)
     def _block():
-        q = q_ref[0, 0].astype(jnp.float32)        # [1, d]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bs, d]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
-        if quantized:
-            k = dequant_rows(k, ks_ref[0, :, 0])
-            v = dequant_rows(v, vs_ref[0, :, 0])
-        s = scaled_qk(q, k, scale)                  # [1, bs]
-        cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s, v = mask_dead_columns(s, v, cols, hist)
-        m_ref[:], l_ref[:], acc_ref[:] = online_softmax_update(
-            s, v, m_ref[:], l_ref[:], acc_ref[:]
+        decode_attend_block(
+            q_ref, load_cache_block(k_ref, ks_ref),   # [bs, KV, d]
+            load_cache_block(v_ref, vs_ref), k_start, hist, scale,
+            m_ref, l_ref, acc_ref,
         )
 
     @pl.when(kb == n_b - 1)
@@ -100,36 +102,30 @@ def _kernel(tab_ref, pos_ref, q_ref, k_ref, v_ref, fk_ref, fv_ref, *rest,
         # fold the pending token's own column (position pos — the
         # highest live position, so folding it LAST keeps the reduction
         # in position order), then normalize
-        q = q_ref[0, 0].astype(jnp.float32)         # [1, d]
-        fk = fk_ref[0, 0, 0].astype(jnp.float32)    # [d]
-        fv = fv_ref[0, 0, 0].astype(jnp.float32)
-        s1 = scaled_qk(q, fk[None, :], scale)       # [1, 1] — always live
-        _, l, acc = online_softmax_update(
-            s1, fv[None, :], m_ref[:], l_ref[:], acc_ref[:]
-        )
-        o_ref[0, 0] = online_softmax_finalize(l, acc, o_ref.dtype)
+        fk = fk_ref[0].astype(jnp.float32)          # [1, KV, d] — always live
+        fv = fv_ref[0].astype(jnp.float32)
+        for gi in range(group):
+            s1 = decode_scores(q_ref[0, gi].astype(jnp.float32), fk, scale)
+            _, l, acc = decode_softmax_update(
+                s1, fv, m_ref[gi], l_ref[gi], acc_ref[gi]
+            )
+            o_ref[0, gi] = decode_softmax_finalize(l, acc, o_ref.dtype)
 
 
 # BlockSpec index maps — module-level so the registered LaunchPlan and
-# the live pallas_call share the SAME callables (grid (b, h, nb),
-# tables + pos prefetched). The kv map is where the gather disappears:
-# the PREFETCHED table picks the physical arena block each step DMAs.
-def _q_index_map(bi, hi, kb, tab_ref, pos_ref):
-    return (bi, 0, hi, 0)
+# the live pallas_call share the SAME callables (grid (b, nb), tables +
+# pos prefetched). The kv map is where the gather disappears: the
+# PREFETCHED table picks the physical arena block each step DMAs.
+def _q_index_map(bi, kb, tab_ref, pos_ref):
+    return (bi, 0, 0, 0)
 
 
-def _kv_index_map(group):
-    return lambda bi, hi, kb, tab_ref, pos_ref: (tab_ref[bi, kb], 0,
-                                                 hi // group, 0)
+def _kv_index_map(bi, kb, tab_ref, pos_ref):
+    return (tab_ref[bi, kb], 0, 0, 0)
 
 
-def _fresh_index_map(group):
-    return lambda bi, hi, kb, tab_ref, pos_ref: (bi, 0, hi // group, 0)
-
-
-def _scale_index_map(group):
-    return lambda bi, hi, kb, tab_ref, pos_ref: (tab_ref[bi, kb], 0,
-                                                 hi // group)
+def _scale_index_map(bi, kb, tab_ref, pos_ref):
+    return (tab_ref[bi, kb], 0, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -148,12 +144,12 @@ def paged_decode_attention(
 ):
     """q [B,1,H,D]; arena_k/v [N, bs, KV, D] (the kv.gather arena leaves
     of ONE layer, consumed in place; KV ≤ H under grouped-query
-    attention — query head hi reads kv head hi//(H/KV) straight from
-    the BlockSpec index map); tables [B, nb] int32 block tables; pos
-    [B] int32 HISTORY lengths (positions 0..pos-1 attendable from
-    blocks); fresh_k/v [B,1,KV,D] the pending token's K/V (column pos)
-    → o [B,1,H,D] float32. With ``k_scale``/``v_scale`` [N, bs, KV]
-    the arena payloads are int8 and dequantized blockwise in VMEM."""
+    attention — query head hi reads kv head hi//(H/KV), no expansion
+    pass); tables [B, nb] int32 block tables; pos [B] int32 HISTORY
+    lengths (positions 0..pos-1 attendable from blocks); fresh_k/v
+    [B,1,KV,D] the pending token's K/V (column pos) → o [B,1,H,D]
+    float32. With ``k_scale``/``v_scale`` [N, bs, KV] the arena
+    payloads are int8 and dequantized blockwise in VMEM."""
     b, _, h, d = q.shape
     n_kv = arena_k.shape[2]
     bs = arena_k.shape[1]
@@ -168,48 +164,39 @@ def paged_decode_attention(
     kernel = functools.partial(
         _kernel, scale=scale, block_k=bs, n_b=nb, quantized=quantized,
     )
-
-    from jax.experimental.pallas import tpu as pltpu  # lazy: CPU interprets
-
-    kv_spec = pl.BlockSpec((1, bs, 1, d), _kv_index_map(group))
-    fresh_spec = pl.BlockSpec((1, 1, 1, d), _fresh_index_map(group))
-    in_specs = [
-        pl.BlockSpec((1, 1, 1, d), _q_index_map),
-        kv_spec,
-        kv_spec,
-        fresh_spec,
-        fresh_spec,
-    ]
+    q_spec = pl.BlockSpec((1, group, n_kv, d), _q_index_map)
+    kv_spec = pl.BlockSpec((1, bs, n_kv, d), _kv_index_map)
+    fresh_spec = pl.BlockSpec((1, 1, n_kv, d), _q_index_map)
+    in_specs = [q_spec, kv_spec, kv_spec, fresh_spec, fresh_spec]
     operands = [
         tables.astype(jnp.int32), pos.astype(jnp.int32),
-        q, arena_k, arena_v, fresh_k, fresh_v,
+        group_queries(q, n_kv), arena_k, arena_v, fresh_k, fresh_v,
     ]
     if quantized:
-        scale_spec = pl.BlockSpec((1, bs, 1), _scale_index_map(group))
+        scale_spec = pl.BlockSpec((1, bs, n_kv), _scale_index_map)
         in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, h, nb),
+        grid=(b, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, 1, d), _q_index_map),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
-            pltpu.VMEM((1, d), jnp.float32),
+            pltpu.VMEM((group, n_kv, 1), jnp.float32),
+            pltpu.VMEM((group, n_kv, 1), jnp.float32),
+            pltpu.VMEM((group, n_kv, d), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, group, n_kv, d), jnp.float32),
         grid_spec=grid_spec,
-        compiler_params=_compiler_params(
-            pltpu,
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(*operands)
-    return out
+    return ungroup_heads(out)
 
 
 def make_paged_attention(interpret: Optional[bool] = None, **kwargs):
@@ -222,7 +209,7 @@ def make_paged_attention(interpret: Optional[bool] = None, **kwargs):
     them; ``fk``/``fv`` are the pending token's (already dequantized)
     K/V, folded as the final online-softmax column."""
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_default()
 
     def attn(q, cache_k, cache_v, tables, pos, fresh_kv):
         fk, fv = fresh_kv
@@ -252,45 +239,38 @@ def _plan(params):
     group = h // n_kv
     quantized = dtype == "int8"
     float_dtype = "float32" if quantized else dtype
+    q_desc = ((b, group, n_kv, d), (1, group, n_kv, d))
     blocks = [
-        _registry.BlockDesc(
-            "q", "in", (b, 1, h, d), (1, 1, 1, d), float_dtype, _q_index_map,
-        ),
-        _registry.BlockDesc(
-            "arena_k", "in", (n_blocks, bs, n_kv, d), (1, bs, 1, d), dtype,
-            _kv_index_map(group),
-        ),
-        _registry.BlockDesc(
-            "arena_v", "in", (n_blocks, bs, n_kv, d), (1, bs, 1, d), dtype,
-            _kv_index_map(group),
-        ),
-        _registry.BlockDesc(
-            "fresh_k", "in", (b, 1, n_kv, d), (1, 1, 1, d), float_dtype,
-            _fresh_index_map(group),
-        ),
-        _registry.BlockDesc(
-            "fresh_v", "in", (b, 1, n_kv, d), (1, 1, 1, d), float_dtype,
-            _fresh_index_map(group),
-        ),
+        _registry.BlockDesc("q", "in", *q_desc, float_dtype, _q_index_map),
     ]
+    for nm in ("arena_k", "arena_v"):
+        blocks.append(_registry.BlockDesc(
+            nm, "in", (n_blocks, bs, n_kv, d), (1, bs, n_kv, d), dtype,
+            _kv_index_map,
+        ))
+    for nm in ("fresh_k", "fresh_v"):
+        blocks.append(_registry.BlockDesc(
+            nm, "in", (b, 1, n_kv, d), (1, 1, n_kv, d), float_dtype,
+            _q_index_map,
+        ))
     if quantized:
         for nm in ("k_scale", "v_scale"):
             blocks.append(_registry.BlockDesc(
-                nm, "in", (n_blocks, bs, n_kv), (1, bs, 1), "float32",
-                _scale_index_map(group),
+                nm, "in", (n_blocks, bs, n_kv), (1, bs, n_kv), "float32",
+                _scale_index_map,
             ))
     blocks.append(_registry.BlockDesc(
-        "o", "out", (b, 1, h, d), (1, 1, 1, d), "float32", _q_index_map,
+        "o", "out", *q_desc, "float32", _q_index_map,
     ))
     import numpy as np
 
     return _registry.LaunchPlan(
-        grid=(b, h, nb),
+        grid=(b, nb),
         blocks=tuple(blocks),
         scratch=(
-            _registry.ScratchDesc("m", (1,)),
-            _registry.ScratchDesc("l", (1,)),
-            _registry.ScratchDesc("acc", (1, d)),
+            _registry.ScratchDesc("m", (group, n_kv, 1)),
+            _registry.ScratchDesc("l", (group, n_kv, 1)),
+            _registry.ScratchDesc("acc", (group, n_kv, d)),
         ),
         prefetch=(
             _registry.PrefetchDesc(
@@ -347,7 +327,7 @@ def _run_case(params):
         vs = jnp.asarray(rng.uniform(0.01, 0.1, (n_blocks, bs, n_kv)), jnp.float32)
         got = paged_decode_attention(
             q, ak, av, tables, pos, fk, fv, k_scale=ks, v_scale=vs,
-            interpret=True,
+            interpret=interpret_default(),
         )
         want = paged_attention_ref(
             q, ak, av, tables, pos, (fk, fv), k_scale=ks, v_scale=vs
@@ -355,7 +335,9 @@ def _run_case(params):
         return got, want, 2e-5
     ak = jnp.asarray(rng.standard_normal((n_blocks, bs, n_kv, d)), jnp.float32)
     av = jnp.asarray(rng.standard_normal((n_blocks, bs, n_kv, d)), jnp.float32)
-    got = paged_decode_attention(q, ak, av, tables, pos, fk, fv, interpret=True)
+    got = paged_decode_attention(
+        q, ak, av, tables, pos, fk, fv, interpret=interpret_default()
+    )
     want = paged_attention_ref(q, ak, av, tables, pos, (fk, fv))
     return got, want, 2e-5
 
@@ -373,7 +355,7 @@ def _probe():
     fk = jnp.asarray(rng.standard_normal((1, 1, 2, 4)), jnp.float32)
     fv = jnp.asarray(rng.standard_normal((1, 1, 2, 4)), jnp.float32)
     np.asarray(block_attention(
-        q, arena, arena, tables, pos, (fk, fv), impl="pallas", interpret=True
+        q, arena, arena, tables, pos, (fk, fv), impl="pallas"
     ))
 
 
@@ -402,6 +384,22 @@ _registry.register(_registry.KernelSpec(
         _registry.ShapeCase(
             "serve-paged-2048",
             {"b": 8, "h": 8, "d": 128, "bs": 128, "nb": 16, "n_blocks": 128},
+        ),
+        # the width chip_smoke.py serves (16 heads of 128, 16-token
+        # blocks), plus its GQA and int8 variants
+        _registry.ShapeCase(
+            "serve-h16-d128",
+            {"b": 4, "h": 16, "d": 128, "bs": 16, "nb": 8, "n_blocks": 64},
+        ),
+        _registry.ShapeCase(
+            "gqa-16q-4kv-d128",
+            {"b": 4, "h": 16, "n_kv": 4, "d": 128, "bs": 16, "nb": 8,
+             "n_blocks": 64},
+        ),
+        _registry.ShapeCase(
+            "serve-h16-d128-int8",
+            {"b": 4, "h": 16, "d": 128, "bs": 16, "nb": 8, "n_blocks": 64,
+             "dtype": "int8"},
         ),
     ),
     plan=_plan,

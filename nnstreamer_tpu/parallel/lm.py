@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from nnstreamer_tpu.parallel.mesh import shard_map as _shard_map
-
 from nnstreamer_tpu.models import transformer as tfm
 from nnstreamer_tpu.parallel import moe as moe_mod
 from nnstreamer_tpu.parallel.ring_attention import ring_attention_local
@@ -77,7 +75,7 @@ def _make_attn_fn(mesh: Mesh, kind: str, dp_axis: str, sp_axis: str,
     spec = P(dp_axis, sp_axis, None, None)
 
     def attn(q, k, v, causal=True):
-        return _shard_map(
+        return jax.shard_map(
             functools.partial(local, axis_name=sp_axis, causal=causal, **extra),
             mesh=mesh,
             in_specs=(spec, spec, spec),

@@ -18,21 +18,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map(f, **kw):
-    """Version-portable ``shard_map``: newer jax exports it top-level
-    (``jax.shard_map``, replication check spelled ``check_vma``), older
-    releases keep it under ``jax.experimental.shard_map`` with the check
-    named ``check_rep`` — the MULTICHIP dryrun must launch on both (the
-    bench machine and the CI image disagree)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:  # pragma: no cover - depends on the installed jax
-        from jax.experimental.shard_map import shard_map as sm
-
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-    return sm(f, **kw)
-
-
 def make_mesh(
     n_devices: Optional[int] = None,
     axes: Sequence[str] = ("dp", "tp"),

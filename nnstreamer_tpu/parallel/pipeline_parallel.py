@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nnstreamer_tpu.parallel.mesh import shard_map as _shard_map
-
 
 def pipeline_forward_local(
     stage_params,
@@ -75,7 +73,7 @@ def make_pipeline_forward(
     stacked_params leaves are [L, ...], sharded over ``axis`` on the
     leading dim; L must divide by the axis size. x and y are replicated.
     """
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             pipeline_forward_local,
             axis_name=axis,

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from nnstreamer_tpu.parallel.mesh import shard_map as _shard_map
 
 NEG_INF = -1e30
 
@@ -145,7 +144,7 @@ def make_ring_attention(
     ``axis`` → attention output with the same sharding."""
     spec = P(None, axis, None, None)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(
             ring_attention_local, axis_name=axis, causal=causal,
             kv_chunk=kv_chunk,

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from nnstreamer_tpu.parallel.mesh import shard_map as _shard_map
-
 from nnstreamer_tpu.parallel.ring_attention import dense_attention
 
 
@@ -54,7 +52,7 @@ def ulysses_attention_local(
 def make_ulysses_attention(mesh: Mesh, axis: str = "sp", causal: bool = True):
     """Jitted full-array entry matching make_ring_attention's signature."""
     spec = P(None, axis, None, None)
-    fn = _shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention_local, axis_name=axis, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

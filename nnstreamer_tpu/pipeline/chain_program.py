@@ -43,7 +43,10 @@ import jax
 
 from nnstreamer_tpu.log import get_logger
 from nnstreamer_tpu.pipeline.batching import default_buckets
-from nnstreamer_tpu.pipeline.graph import FusedSegment
+from nnstreamer_tpu.pipeline.graph import (
+    FusedSegment,
+    jit_weights_as_arguments,
+)
 from nnstreamer_tpu.pipeline.transfer import (
     resolve_chain_mode,
     resolve_chain_unroll,
@@ -385,19 +388,18 @@ class ChainProgram:
         fn = self._cache.get(key)
         if fn is None:
             target = self._unrolled(bucket)
-            kw = {}
-            if donate:
-                # whole-chain donation: the W-window's staged uploads
-                # are node-owned, and _aliasable_argnums matches each
-                # output slot to at most one input buffer across the
-                # ENTIRE unrolled program — interior activations are
-                # XLA's to reuse already (they never escape the trace)
-                argnums = FusedSegment._aliasable_argnums(
+            # whole-chain donation: the W-window's staged uploads are
+            # node-owned, and _aliasable_argnums matches each output
+            # slot to at most one input buffer across the ENTIRE
+            # unrolled program — interior activations are XLA's to
+            # reuse already (they never escape the trace)
+            argnums = (
+                FusedSegment._aliasable_argnums(
                     target, tuple(sig) * bucket, 0
                 )
-                if argnums:
-                    kw = {"donate_argnums": argnums}
-            fn = jax.jit(target, **kw)
+                if donate else ()
+            )
+            fn = jit_weights_as_arguments(target, argnums)
             self._cache[key] = fn
             self.n_traces += 1
         self._last = (key, fn)

@@ -17,6 +17,7 @@ create elements, negotiate caps at PAUSED, stream at PLAYING) becomes:
 
 from __future__ import annotations
 
+import functools
 import threading
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
@@ -426,6 +427,44 @@ class Pipeline:
         return "\n".join(lines)
 
 
+def jit_weights_as_arguments(target: Callable, donate_argnums=()) -> Callable:
+    """``jax.jit(target)``, with every array the trace closes over — a
+    member filter's weights, a decoder's box priors — hoisted out of the
+    program and passed to it as ARGUMENTS.
+
+    The ops' ``make_fn()`` closures carry their weights by closure, and a
+    closed-over array is lowered as a CONSTANT: serialized into the
+    program's HLO, compiled with it, kept in HBM once per program (per
+    shape, per batch bucket, per unroll width) and written once per
+    program into the compile cache. An SSD-MobileNet segment serializes
+    to 233 MB that way — more than the chip machine's cache will hold —
+    against 24 MB with its 74 MB of weights as arguments (PERF.md,
+    PR 21). Tracing once to a jaxpr names exactly those arrays
+    (``consts``); the jitted program evaluates the jaxpr with them as
+    its first argument, so every entry shares the one resident copy.
+
+    Lazy like ``jax.jit``: the first call traces (every entry serves one
+    signature, so that trace is the only one). ``donate_argnums`` counts
+    the call's own arguments."""
+    bound: List[Callable] = []
+
+    def call(*args):
+        if not bound:
+            closed = jax.make_jaxpr(target)(*args)
+            jaxpr = closed.jaxpr
+
+            def run(consts, *tensors):
+                return tuple(jax.core.eval_jaxpr(jaxpr, consts, *tensors))
+
+            jitted = jax.jit(
+                run, donate_argnums=tuple(i + 1 for i in donate_argnums)
+            )
+            bound.append(functools.partial(jitted, closed.consts))
+        return bound[0](*args)
+
+    return call
+
+
 class FusedSegment:
     """A maximal linear chain of TensorOps compiled into ONE jitted fn.
 
@@ -554,12 +593,11 @@ class FusedSegment:
             # uint8 image feeding a float program would just be deleted
             # with an XLA "unusable donation" warning, so those stay
             # un-donated.
-            kw = {}
-            if donate:
-                argnums = self._aliasable_argnums(target, sig, bucket)
-                if argnums:
-                    kw = {"donate_argnums": argnums}
-            fn = jax.jit(target, **kw)
+            argnums = (
+                self._aliasable_argnums(target, sig, bucket) if donate
+                else ()
+            )
+            fn = jit_weights_as_arguments(target, argnums)
             self._cache[key] = fn
             self.n_traces += 1
         self._last = (key, fn)

@@ -321,7 +321,13 @@ def build_plane_program(backends: Sequence[Any], cfg) -> Any:
     if cfg.mode == "shard":
         from nnstreamer_tpu.parallel.mesh import make_mesh
 
-        n = max(1, min(int(cfg.devices), len(jax.devices())))
+        n = max(1, int(cfg.devices))
+        if n > len(jax.devices()):
+            raise ValueError(
+                f"plane mode=shard asks for {n} devices; jax has "
+                f"{len(jax.devices())} — a silently narrower mesh would "
+                "serve at a fraction of the capacity the config names"
+            )
         if n == 1:
             return VmapProgram(fn, buckets, device=device)
         if device is not None:

@@ -811,33 +811,60 @@ class TestReplicaLint:
 
 # ------------------------------------------------ persistent compile cache
 class TestCompileCache:
-    def _reinit(self, monkeypatch, cache_dir):
-        from nnstreamer_tpu.backends import jax_backend
+    @pytest.fixture
+    def place_cache(self, monkeypatch):
+        """Place the cache the way an operator would — the standard
+        JAX_COMPILATION_CACHE_DIR, which jax reads into its config at
+        import (mirrored here, since jax is long imported) — and run
+        the program's once-per-process setup again. Restores the
+        worker's own cache afterwards."""
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
 
-        monkeypatch.setenv("NNS_TPU_COMPILE_CACHE_DIR", str(cache_dir))
-        monkeypatch.setattr(jax_backend, "_cache_initialized", False)
-        jax_backend._init_persistent_cache()
+        from nnstreamer_tpu import compile_cache
 
-    def test_env_var_enables_cache_dir(self, monkeypatch, tmp_path):
+        prev = jax.config.jax_compilation_cache_dir
+
+        def place(cache_dir):
+            if cache_dir is None:
+                monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            else:
+                monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(cache_dir))
+                jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+            compilation_cache.reset_cache()
+            monkeypatch.setattr(compile_cache, "_done", False)
+            compile_cache.ensure_compile_cache()
+            return jax.config.jax_compilation_cache_dir
+
+        yield place
+        jax.config.update("jax_compilation_cache_dir", prev)
+        compilation_cache.reset_cache()
+
+    def test_cache_dir_placed_from_outside_or_fixed(self, place_cache,
+                                                    tmp_path):
         import jax
 
-        self._reinit(monkeypatch, tmp_path / "xla")
-        # the setup appends a per-machine subdir (arch-hostname) so one
-        # shared cache dir serves heterogeneous hosts safely
-        assert jax.config.jax_compilation_cache_dir.startswith(
-            str(tmp_path / "xla")
-        )
+        from nnstreamer_tpu import compile_cache
+
+        # JAX_COMPILATION_CACHE_DIR set: that directory, untouched — no
+        # subdirectory, nothing else named in code
+        assert place_cache(tmp_path / "xla") == str(tmp_path / "xla")
         # corruption tolerance: a bad entry logs + recompiles, never
         # raises (jax_raise_persistent_cache_errors forced off)
         assert jax.config.jax_raise_persistent_cache_errors is False
+        # unset: the FIXED path inside the checkout (the path is part of
+        # every entry's key), per-host subdirectory on the CPU backend
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert compile_cache.DEFAULT_DIR == os.path.join(repo, ".jax_cache")
+        assert place_cache(None).startswith(compile_cache.DEFAULT_DIR + os.sep)
 
-    def test_corrupt_cache_entry_never_crashes(self, monkeypatch, tmp_path):
+    def test_corrupt_cache_entry_never_crashes(self, place_cache, tmp_path):
         cache = tmp_path / "xla"
         cache.mkdir()
         # seed the directory with garbage "entries" before any compile
         (cache / "jit_f-deadbeef").write_bytes(b"\x00garbage\xff" * 16)
         (cache / "truncated").write_bytes(b"")
-        self._reinit(monkeypatch, cache)
+        place_cache(cache)
         p = parse_pipeline(
             "tensorsrc dimensions=4 num-frames=10 pattern=counter ! "
             "tensor_filter framework=passthrough ! tensor_sink name=out"

@@ -123,11 +123,26 @@ class TestAlignment:
         assert self._one((64, 128), (16, 128), "int8").misaligned
         assert not self._one((64, 128), (16, 128)).misaligned
 
-    def test_whole_axis_and_unit_dims_exempt(self):
+    def test_whole_axis_exempt_unit_dim_is_not(self):
+        """The TPU compiler's block rule, both ways: a dim that IS the
+        whole axis passes whatever its size; a unit dim that is not —
+        the one-head ``(1, 1, 1, d)`` block the decode kernels used to
+        take over ``[B, 1, H, d]``, which Mosaic refuses — is flagged."""
         assert not self._one((8, 100), (8, 100)).misaligned
-        assert not self._one((8, 100), (1, 100)).misaligned
+        assert not self._one((1, 100), (1, 100)).misaligned
         bf16 = self._one((32, 256), (16, 128), "bfloat16")
         assert not bf16.misaligned  # bf16 sublane is exactly 16
+        one_head = self._one((4, 1, 16, 128), (1, 1, 1, 128))
+        assert any("second-minor" in p for p in one_head.blocks[0].problems)
+        all_heads = self._one((4, 1, 16, 128), (1, 1, 16, 128))
+        assert not all_heads.misaligned
+        assert self._one((8, 100), (1, 100)).misaligned
+        _, rep = analyze([_spec(
+            [kreg.BlockDesc("q", "in", (4, 1, 16, 128), (1, 1, 1, 128),
+                            "float32", lambda i: (0, 0, 0, 0))],
+            grid=(1,),
+        )], bound=1 << 30)
+        assert [d.code for d in rep.diagnostics] == ["NNS-W128"]
 
     def test_w128_fires_on_misalignment_and_only_then(self):
         bad = _spec(
@@ -181,7 +196,7 @@ class TestIndexMapHazards:
         """make() values (not zeros) feed the maps — a block-table map
         that would go OOB on zeros stays clean on the real table."""
         spec = _spec(
-            [kreg.BlockDesc("kv", "in", (4, 128), (1, 128), "float32",
+            [kreg.BlockDesc("kv", "in", (32, 128), (8, 128), "float32",
                             lambda i, tbl: (int(tbl[i]), 0))],
             grid=(2,),
             prefetch=(kreg.PrefetchDesc(

@@ -127,10 +127,8 @@ class TestMoE:
         x = jnp.asarray(rng.standard_normal((2, 16, 32)), jnp.float32)
         y_dense = moe.moe_ffn_dense(x, mp0, top_k=2)
         mesh = make_mesh(8, axes=("ep",))
-        from nnstreamer_tpu.parallel.mesh import shard_map
-
         f = jax.jit(
-            shard_map(
+            jax.shard_map(
                 functools.partial(moe.moe_ffn_local, axis_name="ep", top_k=2),
                 mesh=mesh,
                 in_specs=(P(), {"gate": P(), "w_in": P("ep"), "w_out": P("ep")}),

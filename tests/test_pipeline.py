@@ -585,7 +585,10 @@ class TestForwardingElimination:
 
     def test_queue_still_splits_fusion(self):
         """An explicit queue between traceable ops must keep forcing a
-        segment split (its planning role) even though its node is gone."""
+        segment split (its planning role) even though its node is gone:
+        two compile units either way — two FusedNodes, or since
+        compiled chains (chain_mode=auto) ONE ChainNode whose chain
+        still holds the two segments the queue split."""
         from nnstreamer_tpu.pipeline.parse import parse_pipeline
 
         p = parse_pipeline(
@@ -594,10 +597,14 @@ class TestForwardingElimination:
             "tensor_filter framework=passthrough ! tensor_sink name=out"
         )
         ex = p.run(timeout=60)
-        from nnstreamer_tpu.pipeline.executor import FusedNode
+        from nnstreamer_tpu.pipeline.executor import ChainNode, FusedNode
 
-        fused = [n for n in ex.nodes if isinstance(n, FusedNode)]
-        assert len(fused) == 2  # split held
+        segments = [
+            seg
+            for n in ex.nodes if isinstance(n, ChainNode)
+            for seg in n.chain.segments
+        ] + [n for n in ex.nodes if isinstance(n, FusedNode)]
+        assert len(segments) == 2  # split held
         assert p["out"].rendered == 2
 
 

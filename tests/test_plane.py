@@ -8,6 +8,7 @@ the observability surface (plane_* stats, nns-top --models)."""
 import os
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -392,6 +393,22 @@ class TestRegistryAndConfig:
         with pytest.raises(ValueError, match="plane-mode"):
             TensorFilter(framework="scaler", plane="m",
                          **{"plane-mode": "bogus"})
+
+    def test_shard_over_more_devices_than_exist_is_an_error(self):
+        """A plane asked to shard over more chips than jax has must
+        refuse — it used to clamp to what exists and serve narrower
+        than configured, in silence."""
+        from nnstreamer_tpu.serving_plane.sharding import build_plane_program
+
+        backend = ScalerBackend()
+        backend.open(FilterProps(custom="factor:2.0"))
+        cfg = PlaneConfig(mode="shard", devices=len(jax.devices()) + 1)
+        with pytest.raises(ValueError, match="asks for"):
+            build_plane_program([backend], cfg)
+        ok = build_plane_program(
+            [backend], PlaneConfig(mode="shard", devices=2)
+        )
+        assert isinstance(ok, MeshShardedProgram)
 
     def test_implicit_sharer_inherits_bound_config(self):
         """docs: 'the first attacher's resolved config binds the

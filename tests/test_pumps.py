@@ -303,12 +303,12 @@ def test_spec_pump_budget_tail_stays_on_warm_programs(params):
     # each power-of-two floor below 4 is a brand-new program
     rids = [b.submit(p, 5 + 3 * s) for s, p in enumerate(prompts)]
     b.spec_pump(rounds=4, k=4, ngram=1)
-    warm = b._spec_pump_greedy._cache_size()
+    warm = b._spec_pump_greedy.func._cache_size()
     spec_launches = 1
     while any(b.result(r) is None for r in rids):
         b.spec_pump(rounds=4, k=4, ngram=1)
         spec_launches += 1
-    assert b._spec_pump_greedy._cache_size() == warm, (
+    assert b._spec_pump_greedy.func._cache_size() == warm, (
         "budget tail recompiled spec_pump: the static scan length must "
         "not depend on live budgets (slots idle out on device)"
     )

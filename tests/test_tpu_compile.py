@@ -1,0 +1,181 @@
+"""The TPU compiler's verdict on the main-path programs, without a chip.
+
+libtpu is installed and compiles for a chip that is *described*, not
+attached (``jax.experimental.topologies``), so what Mosaic and XLA:TPU
+refuse — a block off the (8, 128) tiling, an unaligned dynamic slice, a
+kernel over its VMEM budget — fails here, on every later PR, at no chip
+time. Every Pallas kernel in ``ops/pallas/`` had passed its
+interpret-mode parity tests for nineteen PRs while the real compiler
+refused four of the five at every shape.
+
+Shapes: the width ``chip_smoke.py`` serves (16 heads of 128) and the zoo
+default (8 heads of 32); NMS at SSD's 1,917 anchors; crop-and-resize and
+resize at camera frames; plus the flagship MobileNet-v2 224 forward. A
+compile that passes here is not a chip run and is never reported as one.
+
+One file, on purpose: only one process at a time may load libtpu, and
+the worker that runs this file keeps it until it exits. The topology is
+described inside a module-scoped fixture — never at import, in a
+``skipif`` or in ``parametrize`` arguments — so every xdist worker
+collects the same tests and only the one that is handed this file loads
+the library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """The described chip, with the persistent compile cache off around
+    this file's compiles: a TPU entry written here could not be read
+    back without a chip, and the next run would warn."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Compile ``fn`` for the described chip; ``shapes`` are
+    ``(shape, dtype)`` pairs. Returns the compiled text."""
+    args = [
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    ]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _assert_kernel(text):
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+
+
+#: (heads, head dim): what chip_smoke.py serves, and the zoo default
+WIDTHS = [(16, 128), (8, 32)]
+f32, i8, i32 = jnp.float32, jnp.int8, jnp.int32
+
+
+@pytest.mark.parametrize("h,d", WIDTHS)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention(one_chip, h, d, dtype):
+    from nnstreamer_tpu.ops.pallas.flash_attention import flash_attention
+
+    q = ((2, 512, h, d), dtype)
+    _assert_kernel(_compile(
+        lambda q, k, v: flash_attention(q, k, v), one_chip, q, q, q
+    ))
+
+
+@pytest.mark.parametrize("h,d", WIDTHS)
+@pytest.mark.parametrize("quantized", [False, True], ids=["fp", "int8"])
+def test_decode_attention(one_chip, h, d, quantized):
+    from nnstreamer_tpu.ops.pallas.decode_attention import decode_attention
+
+    b, s_len = 4, 2048
+    q, pos = ((b, 1, h, d), f32), ((b,), i32)
+    if quantized:
+        cache, scale = ((b, s_len, h, d), i8), ((b, s_len, h), f32)
+        text = _compile(
+            lambda q, k, v, p, ks, vs: decode_attention(
+                q, k, v, p, k_scale=ks, v_scale=vs
+            ),
+            one_chip, q, cache, cache, pos, scale, scale,
+        )
+    else:
+        cache = ((b, s_len, h, d), f32)
+        text = _compile(
+            lambda q, k, v, p: decode_attention(q, k, v, p),
+            one_chip, q, cache, cache, pos,
+        )
+    _assert_kernel(text)
+
+
+@pytest.mark.parametrize("h,d", WIDTHS)
+@pytest.mark.parametrize("variant", ["fp", "gqa", "int8"])
+def test_paged_attention(one_chip, h, d, variant):
+    from nnstreamer_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+    )
+
+    b, n_blocks, bs, nb = 4, 64, 16, 8
+    kv = h // 4 if variant == "gqa" else h  # 16q/4kv at the smoke width
+    q, fresh = ((b, 1, h, d), f32), ((b, 1, kv, d), f32)
+    tables, pos = ((b, nb), i32), ((b,), i32)
+    if variant == "int8":
+        arena = ((n_blocks, bs, kv, d), i8)
+        scale = ((n_blocks, bs, kv), f32)
+        text = _compile(
+            lambda q, k, v, t, p, fk, fv, ks, vs: paged_decode_attention(
+                q, k, v, t, p, fk, fv, k_scale=ks, v_scale=vs
+            ),
+            one_chip, q, arena, arena, tables, pos, fresh, fresh,
+            scale, scale,
+        )
+    else:
+        arena = ((n_blocks, bs, kv, d), f32)
+        text = _compile(
+            lambda q, k, v, t, p, fk, fv: paged_decode_attention(
+                q, k, v, t, p, fk, fv
+            ),
+            one_chip, q, arena, arena, tables, pos, fresh, fresh,
+        )
+    _assert_kernel(text)
+
+
+def test_nms_ssd_anchors(one_chip):
+    from nnstreamer_tpu.ops.pallas.nms import nms
+
+    _assert_kernel(_compile(
+        lambda boxes, scores: nms(boxes, scores, 0.5, 100),
+        one_chip, ((1917, 4), f32), ((1917,), f32),
+    ))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.uint8])
+def test_crop_and_resize_720p(one_chip, dtype):
+    from nnstreamer_tpu.ops.pallas.image_kernels import crop_and_resize
+
+    _assert_kernel(_compile(
+        lambda image, boxes: crop_and_resize(image, boxes, 112, 112),
+        one_chip, ((720, 1280, 3), dtype), ((8, 4), f32),
+    ))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.uint8])
+def test_resize_1080p_to_224(one_chip, dtype):
+    from nnstreamer_tpu.ops.pallas.image_kernels import resize_bilinear
+
+    _assert_kernel(_compile(
+        lambda image: resize_bilinear(image, 224, 224),
+        one_chip, ((1, 1080, 1920, 3), dtype),
+    ))
+
+
+def test_mobilenet_v2_224_forward(one_chip):
+    """The flagship: ``__graft_entry__.entry()``'s MobileNet-v2 1.0
+    224x224 forward, as the quick-start pipeline runs it."""
+    import __graft_entry__ as graft
+
+    fn, (example,) = graft.entry()
+    text = _compile(fn, one_chip, (example.shape, example.dtype))
+    assert "convolution" in text

@@ -155,7 +155,7 @@ class TestTransferPrediction:
 # ------------------------------------------------- jaxpr lint walkers
 class TestJaxprWalkers:
     def test_dtype_promotion_flagged(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(
                 lambda x: jnp.sin(x.astype(jnp.float64))
             )(jax.ShapeDtypeStruct((4,), jnp.float32))
@@ -169,7 +169,7 @@ class TestJaxprWalkers:
         assert dtype_findings(jaxpr) == []
 
     def test_wide_input_excuses_wide_math(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             jaxpr = jax.make_jaxpr(lambda x: x + 1.0)(
                 jax.ShapeDtypeStruct((4,), jnp.float64)
             )
