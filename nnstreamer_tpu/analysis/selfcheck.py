@@ -13,6 +13,7 @@ in sync with the GObject param specs by construction.
 from __future__ import annotations
 
 import inspect
+import os
 import re
 from typing import Dict, List, Set
 
@@ -150,49 +151,75 @@ def _repo_root() -> str:
     )))
 
 
+def _package_sources(skip: str):
+    """Text of every ``.py`` file of the package but ``skip`` (a catalog
+    module does not count as an emitter of its own names)."""
+    import os
+
+    pkg_root = os.path.join(_repo_root(), "nnstreamer_tpu")
+    skip = os.path.join(pkg_root, skip)
+    for dirpath, dirnames, filenames in os.walk(pkg_root):
+        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+        for fn in filenames:
+            path = os.path.join(dirpath, fn)
+            if fn.endswith(".py") and not os.path.samefile(path, skip):
+                with open(path, encoding="utf-8") as f:
+                    yield f.read()
+
+
+def _catalog_problems(kind: str, catalog, emitted: Set[str],
+                      catalog_name: str) -> List[str]:
+    """Emitters ⟷ catalog ⟷ docs/observability.md, for one kind of name."""
+    problems = [
+        f"{kind} {name} is emitted but not in {catalog_name}"
+        for name in sorted(emitted - set(catalog))
+    ] + [
+        f"catalog {kind} {name} has no emitter in the package"
+        for name in sorted(set(catalog) - emitted)
+    ]
+    doc = os.path.join(_repo_root(), "docs", "observability.md")
+    if os.path.isfile(doc):  # repo checkouts only; wheels ship no docs
+        with open(doc, encoding="utf-8") as f:
+            text = f.read()
+        problems += [
+            f"{kind} {name} is not documented in docs/observability.md"
+            for name in sorted(catalog) if f"`{name}`" not in text
+        ]
+    return problems
+
+
 def obs_self_check() -> List[str]:
     """Validate the nns-obs metric catalog against the code and the docs
     (the metrics mirror of san_self_check): every metric name the
     package emits through a registry call exists in METRIC_CATALOG,
     every cataloged metric has an emitter, and docs/observability.md
     documents every cataloged name."""
-    import os
-
     from nnstreamer_tpu.obs.metrics import METRIC_CATALOG
 
-    problems: List[str] = []
-    pkg_root = os.path.join(_repo_root(), "nnstreamer_tpu")
-    catalog_file = os.path.join(pkg_root, "obs", "metrics.py")
     emitted: Set[str] = set()
-    for dirpath, dirnames, filenames in os.walk(pkg_root):
-        dirnames[:] = [d for d in dirnames if d != "__pycache__"]
-        for fn in filenames:
-            if not fn.endswith(".py"):
-                continue
-            path = os.path.join(dirpath, fn)
-            if os.path.samefile(path, catalog_file):
-                continue  # the catalog module itself doesn't count
-            with open(path, encoding="utf-8") as f:
-                emitted |= set(_METRIC_EMIT.findall(f.read()))
-    for name in sorted(emitted - set(METRIC_CATALOG)):
-        problems.append(
-            f"metric {name} is emitted but not in METRIC_CATALOG"
-        )
-    for name in sorted(set(METRIC_CATALOG) - emitted):
-        problems.append(
-            f"catalog metric {name} has no emitter in the package"
-        )
-    doc = os.path.join(_repo_root(), "docs", "observability.md")
-    if os.path.isfile(doc):  # repo checkouts only; wheels ship no docs
-        with open(doc, encoding="utf-8") as f:
-            text = f.read()
-        for name in sorted(METRIC_CATALOG):
-            if name not in text:
-                problems.append(
-                    f"metric {name} is not documented in "
-                    "docs/observability.md"
-                )
-    return problems
+    for text in _package_sources(os.path.join("obs", "metrics.py")):
+        emitted |= set(_METRIC_EMIT.findall(text))
+    return _catalog_problems("metric", METRIC_CATALOG, emitted,
+                             "METRIC_CATALOG")
+
+
+_SPAN_EMIT = re.compile(
+    r"""trace\.(?:span|instant)\(\s*["'](nns\.[a-z0-9_.]+)["']"""
+)
+
+
+def span_self_check() -> List[str]:
+    """The same three-way check for the program's spans: every ``nns.*``
+    literal passed to ``trace.span`` / ``trace.instant`` is in
+    trace.SPAN_CATALOG, every cataloged name has an emitter, and
+    docs/observability.md documents every one. The names are what the
+    benchmark's readers select events by, so a rename must show here."""
+    from nnstreamer_tpu.trace import SPAN_CATALOG
+
+    emitted: Set[str] = set()
+    for text in _package_sources("trace.py"):
+        emitted |= set(_SPAN_EMIT.findall(text))
+    return _catalog_problems("span", SPAN_CATALOG, emitted, "SPAN_CATALOG")
 
 
 # -- nns-xray self-check: chain codes wired emitters<->catalog<->docs -------

@@ -33,12 +33,14 @@ pending request, then ends its stream.
 from __future__ import annotations
 
 import threading
+import time as _time
 from collections import deque
 from typing import Dict, List, Optional
 
 import numpy as np
 
 from nnstreamer_tpu import registry
+from nnstreamer_tpu import trace as _trace
 from nnstreamer_tpu.elements.base import (
     ElementError,
     NegotiationError,
@@ -143,13 +145,16 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
     runs the EXACT construction a solo serversink would."""
     from nnstreamer_tpu.models import zoo
     from nnstreamer_tpu.models.serving import ContinuousBatcher
+    from nnstreamer_tpu.obs import metrics as _obs_metrics
 
     if not model.startswith("zoo:"):
         raise ElementError(
             f"tensor_llm_serversink: model must be zoo:<name>, got "
             f"{model!r}"
         )
+    t0 = _time.perf_counter()
     m = zoo.get(model[len("zoo:"):], **options)
+    t_weights = _time.perf_counter() - t0
     n_heads = int(options.get("n_heads", 8))
     draft_kw = {}
     if speculate_model:
@@ -186,12 +191,20 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
             prefill_chunks=prefill_chunks,
             kv_attn=kv_attn or "auto",
         )
-    return ContinuousBatcher(
+    t0 = _time.perf_counter()
+    cb = ContinuousBatcher(
         m.params, n_heads, n_slots=n_slots, max_len=max_len,
         prompt_len=prompt_len, cache_dtype=cache_dtype,
         attn_impl=attn_impl or "xla",
         **kv_kw, **draft_kw,
     )
+    reg = _obs_metrics.get()
+    if reg is not None:
+        reg.gauge("nns_llm_setup_seconds", phase="weights").set(t_weights)
+        reg.gauge("nns_llm_setup_seconds", phase="batcher").set(
+            _time.perf_counter() - t0
+        )
+    return cb
 
 
 class _LlmServer:
@@ -345,6 +358,9 @@ class _LlmServer:
                 speculate_model, kv_layout, block_size, kv_blocks,
                 cache_dtype, prefill_chunks, kv_attn, attn_impl,
             )
+        # until the first token of any request: what is left of set-up
+        # once the batcher exists (nns_llm_setup_seconds{first_token})
+        self._t_built: Optional[float] = _time.perf_counter()
         self.default_new = default_new
         self._lock = threading.Lock()
         self._pending: Dict[int, dict] = {}  # rid -> request meta
@@ -408,8 +424,14 @@ class _LlmServer:
                 self._restore_checkpoints()
 
     def submit(self, frame: Frame) -> None:
-        import time as _time
+        with _trace.span(
+            "nns.llm.submit",
+            prompt_tokens=int(np.prod(frame.tensors[0].shape)),
+        ):
+            self._submit(frame)
 
+    def _submit(self, frame: Frame) -> None:
+        t_in = _time.perf_counter()
         if frame.meta.get("_nns_srv") is not None:
             # remember which edge serversrc feeds this server, so
             # drain() can flip its readiness flag and NACK at admission
@@ -441,12 +463,14 @@ class _LlmServer:
                 self._stream, prompt, budget, kw, dict(frame.meta)
             )
             return
+        retries = 0
         while True:
             if self.stopped:
                 raise ElementError("tensor_llm_serversink: stopped")
             rid = self.cb.submit(prompt, budget, **kw)
             if rid is not None:
                 break
+            retries += 1
             # batch full: pumping here IS the backpressure — admission
             # waits until decoding frees a slot. A no-progress pump is
             # NOT an error: the src thread may have just stepped/ drained
@@ -455,6 +479,10 @@ class _LlmServer:
                 _time.sleep(0.005)
         with self._lock:
             self._pending[rid] = dict(frame.meta)
+        _trace.instant(
+            "nns.llm.admitted", rid=rid, retries=retries,
+            slot_wait_ms=(_time.perf_counter() - t_in) * 1000.0,
+        )
 
     def pump(self) -> bool:
         """One decode step; harvest finished requests (and, in streaming
@@ -464,6 +492,10 @@ class _LlmServer:
             # server's finished generations land on its own plane
             # stream deque (pop reads them there)
             return self._plane.pump()
+        with _trace.span("nns.llm.pump", pending=len(self._pending)):
+            return self._pump()
+
+    def _pump(self) -> bool:
         N = self.pump_tokens
         if self.speculate == -1:
             if N > 1:
@@ -500,7 +532,7 @@ class _LlmServer:
             emitted = self.cb.step()
         harvested = False
         finished: List[int] = []
-        with self._lock:
+        with _trace.span("nns.llm.harvest"), self._lock:
             if self.stream:
                 # count-based catch-up off cb.partials() (one batcher
                 # lock pass for all pending rids): robust to tokens
@@ -548,6 +580,12 @@ class _LlmServer:
             # prefill role: offload freshly-extractable requests to the
             # decode peers and relay finished handoffs into _out
             harvested |= self._disagg.tick(self)
+        if self._t_built is not None and (emitted or harvested):
+            if self._obs_reg is not None:
+                self._obs_reg.gauge(
+                    "nns_llm_setup_seconds", phase="first_token"
+                ).set(_time.perf_counter() - self._t_built)
+            self._t_built = None
         return bool(emitted) or harvested
 
     def _stream_new_locked(self, rid: int, meta: dict, toks) -> bool:
@@ -1217,6 +1255,7 @@ class LlmServerSrc(Source):
         # reusable across pipelines, so it never identifies the server
         self._server: Optional[_LlmServer] = None
         self._final_stats: Optional[Dict] = None
+        self._burst = None  # the open nns.llm.emit span, if any
 
     def _acquired(self, srv: Optional[_LlmServer]) -> Optional[_LlmServer]:
         if srv is not None and self.stream:
@@ -1237,6 +1276,7 @@ class LlmServerSrc(Source):
         # pipeline teardown (drained or not) releases the server — model
         # params and KV caches must not outlive the pipeline in _table;
         # keep a final stats snapshot for post-run --stats readers
+        self._end_burst()
         if self._final_stats is None:
             self._final_stats = self.serving_stats()
         _drop_server(self.srv_id, self._server)
@@ -1255,13 +1295,12 @@ class LlmServerSrc(Source):
         return TensorsSpec(format=TensorFormat.FLEXIBLE)
 
     def generate(self):
-        import time as _time
-
         srv = self._server
         if srv is None:
             srv = self._server = self._acquired(_get_server(self.srv_id))
         item = srv.pop()
         if item is None:
+            self._end_burst()
             if srv.drained:
                 self._final_stats = srv.stats()
                 _drop_server(self.srv_id, srv)
@@ -1273,6 +1312,19 @@ class LlmServerSrc(Source):
             item = srv.pop()
             if item is None:
                 return None
+        if self._burst is None:
+            # one span per burst of frames a pump left in the queue, held
+            # open across generate calls (always this node's thread): it
+            # covers the executor's push of each frame downstream, which
+            # is the emission
+            self._burst = _trace.span(
+                "nns.llm.emit", frames=len(srv._out) + 1
+            ).__enter__()
         toks, meta = item
         arr = np.asarray(toks, np.int32)[None, :]
         return Frame((arr,), meta=meta)
+
+    def _end_burst(self) -> None:
+        if self._burst is not None:
+            self._burst.close()
+            self._burst = None

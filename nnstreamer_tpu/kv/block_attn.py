@@ -178,40 +178,42 @@ def batched_decode_step_block(
         else:
             blk, ka, va = layer
         bsz, _, d = x.shape
-        q, k, v = tfm.block_qkv(x, blk, n_heads, pos[:, None])
-        if quantized:
-            k8, ks = quantize_kv(k)
-            v8, vs = quantize_kv(v)
-            fresh = (k8, ks, v8, vs)
-            if attn_fn is None:
-                ck = dequantize_kv(
-                    write(_take_layer(ka, tables), k8),
-                    write_scale(_take_layer(ksc, tables), ks),
-                )
-                cv = dequantize_kv(
-                    write(_take_layer(va, tables), v8),
-                    write_scale(_take_layer(vsc, tables), vs),
-                )
-                o = None
+        with jax.named_scope("nns.attn"):
+            q, k, v = tfm.block_qkv(x, blk, n_heads, pos[:, None])
+            if quantized:
+                k8, ks = quantize_kv(k)
+                v8, vs = quantize_kv(v)
+                fresh = (k8, ks, v8, vs)
+                if attn_fn is None:
+                    ck = dequantize_kv(
+                        write(_take_layer(ka, tables), k8),
+                        write_scale(_take_layer(ksc, tables), ks),
+                    )
+                    cv = dequantize_kv(
+                        write(_take_layer(va, tables), v8),
+                        write_scale(_take_layer(vsc, tables), vs),
+                    )
+                    o = None
+                else:
+                    o = attn_fn(
+                        q, (ka, ksc), (va, vsc), tables, pos,
+                        (dequantize_kv(k8, ks), dequantize_kv(v8, vs)),
+                    )
             else:
-                o = attn_fn(
-                    q, (ka, ksc), (va, vsc), tables, pos,
-                    (dequantize_kv(k8, ks), dequantize_kv(v8, vs)),
-                )
-        else:
-            fresh = (k, v)
-            if attn_fn is None:
-                ck = write(_take_layer(ka, tables), k)
-                cv = write(_take_layer(va, tables), v)
-                o = None
-            else:
-                o = attn_fn(q, ka, va, tables, pos, (k, v))
-        if o is None:
-            mask = jnp.arange(max_len)[None, :] <= pos[:, None]
-            o = tfm.cache_attention(q, ck, cv, mask[:, None, :])
-        o = o.astype(x.dtype).reshape(bsz, 1, -1)
-        x = x + o @ tfm.wt(blk["wo"], x.dtype)
-        x = tfm.block_ffn(x, blk)
+                fresh = (k, v)
+                if attn_fn is None:
+                    ck = write(_take_layer(ka, tables), k)
+                    cv = write(_take_layer(va, tables), v)
+                    o = None
+                else:
+                    o = attn_fn(q, ka, va, tables, pos, (k, v))
+            if o is None:
+                mask = jnp.arange(max_len)[None, :] <= pos[:, None]
+                o = tfm.cache_attention(q, ck, cv, mask[:, None, :])
+            o = o.astype(x.dtype).reshape(bsz, 1, -1)
+            x = x + o @ tfm.wt(blk["wo"], x.dtype)
+        with jax.named_scope("nns.ffn"):
+            x = tfm.block_ffn(x, blk)
         return x, fresh
 
     if quantized:

@@ -17,8 +17,10 @@ Three serving-scheduler concerns the paged batcher delegates here:
   survive in the pool's cached tier) and it re-enters the prefill queue
   to be re-prefilled from whatever prefix still matches — never an OOM.
 - **SLO ledger** (:class:`SLOLedger`): per-request queue / prefill /
-  TTFT / TPOT wall stamps, surfaced through ``nns-top --requests`` and
-  the ``nns_request_ttft_ms`` / ``nns_request_tpot_ms`` histograms.
+  TTFT / TPOT wall stamps, surfaced through ``nns-top --requests``, the
+  ``nns_request_queue_ms`` / ``nns_request_ttft_ms`` /
+  ``nns_request_tpot_ms`` histograms, and one ``nns.req.*`` instant per
+  state change on the profiler's timeline (nnstreamer_tpu/trace.py).
 """
 
 from __future__ import annotations
@@ -27,6 +29,8 @@ import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
+
+from nnstreamer_tpu import trace
 
 
 @dataclass
@@ -86,6 +90,7 @@ class SLORecord:
     rid: int
     t_submit: float
     deadline_s: Optional[float] = None
+    t_prefill: Optional[float] = None    # head of the prefill queue (latest)
     t_admit: Optional[float] = None      # prefill done, slot active
     t_first: Optional[float] = None      # first token materialized
     t_done: Optional[float] = None
@@ -124,9 +129,11 @@ class SLORecord:
 
 class SLOLedger:
     """Bounded per-request SLO accounting. Single-writer under the
-    batcher's state lock; emits the TTFT/TPOT histograms through the
-    obs registry resolved once at construction (the FaultGate
-    discipline)."""
+    batcher's state lock; emits the queue/TTFT/TPOT histograms through
+    the obs registry resolved once at construction (the FaultGate
+    discipline). The one place a request changes state, so also the one
+    place its ``nns.req.*`` instants are written: a request's events
+    share its ``rid``."""
 
     def __init__(self, keep: int = 1024, obs_registry=None):
         self._recs: "OrderedDict[int, SLORecord]" = OrderedDict()
@@ -140,6 +147,7 @@ class SLOLedger:
         self._recs[rid] = rec
         while len(self._recs) > self._keep:
             self._recs.popitem(last=False)
+        trace.instant("nns.req.submit", rid=rid)
         return rec
 
     def _get(self, rid: int) -> Optional[SLORecord]:
@@ -147,22 +155,40 @@ class SLOLedger:
 
     def prefilling(self, rid: int) -> None:
         rec = self._get(rid)
-        if rec is not None and rec.state == "queued":
-            rec.state = "prefilling"
+        if rec is None or rec.state != "queued":
+            return
+        rec.state = "prefilling"
+        now = time.perf_counter()
+        attrs = {}
+        if rec.t_prefill is None:  # a re-prefill after preemption queued once
+            attrs["queue_ms"] = (now - rec.t_submit) * 1000.0
+            if self._obs is not None:
+                self._obs.histogram("nns_request_queue_ms").observe(
+                    max(attrs["queue_ms"], 1e-6)
+                )
+        rec.t_prefill = now
+        trace.instant("nns.req.prefill_start", rid=rid, **attrs)
 
     def admitted(self, rid: int) -> None:
         rec = self._get(rid)
         if rec is not None:
             rec.t_admit = time.perf_counter()
             rec.state = "decoding"
+            trace.instant(
+                "nns.req.admitted", rid=rid,
+                prefill_ms=(rec.t_admit - (rec.t_prefill or rec.t_submit))
+                * 1000.0,
+            )
 
     def first_token(self, rid: int) -> None:
         rec = self._get(rid)
         if rec is not None and rec.t_first is None:
             rec.t_first = time.perf_counter()
+            ttft_ms = (rec.t_first - rec.t_submit) * 1000.0
+            trace.instant("nns.req.first_token", rid=rid, ttft_ms=ttft_ms)
             if self._obs is not None:
                 self._obs.histogram("nns_request_ttft_ms").observe(
-                    max((rec.t_first - rec.t_submit) * 1000.0, 1e-6)
+                    max(ttft_ms, 1e-6)
                 )
 
     def record(self, rid: int) -> Optional[SLORecord]:
@@ -194,11 +220,15 @@ class SLOLedger:
         rec.state = "done"
         if rec.t_first is None:  # one-token requests: first IS done
             rec.t_first = rec.t_done
-        if self._obs is not None and n_tokens > 1:
+        tpot = 0.0
+        if n_tokens > 1:
             tpot = (rec.t_done - rec.t_first) / (n_tokens - 1) * 1000.0
-            self._obs.histogram("nns_request_tpot_ms").observe(
-                max(tpot, 1e-6)
-            )
+            if self._obs is not None:
+                self._obs.histogram("nns_request_tpot_ms").observe(
+                    max(tpot, 1e-6)
+                )
+        trace.instant("nns.req.done", rid=rid, tokens=n_tokens,
+                      tpot_ms=tpot, preemptions=rec.preemptions)
 
     def view(self, extra: Optional[Dict[int, Dict]] = None
              ) -> Dict[int, Dict[str, Any]]:

@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from nnstreamer_tpu import trace as _trace
 from nnstreamer_tpu.compile_cache import ensure_compile_cache
 from nnstreamer_tpu.models import decode as dec
 from nnstreamer_tpu.models import transformer as tfm
@@ -138,38 +139,40 @@ def batched_decode_step(
         else:
             blk, ck, cv = layer
         bsz, _, d = x.shape
-        # per-slot positions: block_qkv → rope() take [B,T] (here T=1);
-        # k/v come back with KV ≤ H heads (GQA) matching the cache
-        q, k, v = tfm.block_qkv(x, blk, n_heads, pos[:, None])
-        if quantized:
-            k8, ks = quantize_kv(k)
-            v8, vs = quantize_kv(v)
-            ck8 = write(ck8, k8)
-            ksc = write_scale(ksc, ks)
-            cv8 = write(cv8, v8)
-            vsc = write_scale(vsc, vs)
-            out_layer = (ck8, ksc, cv8, vsc)
-            if attn_fn is None:
-                ck = dequantize_kv(ck8, ksc)
-                cv = dequantize_kv(cv8, vsc)
-        else:
-            ck = write(ck, k)
-            cv = write(cv, v)
-            out_layer = (ck, cv)
-        if attn_fn is not None:
+        with jax.named_scope("nns.attn"):
+            # per-slot positions: block_qkv → rope() take [B,T] (here T=1);
+            # k/v come back with KV ≤ H heads (GQA) matching the cache
+            q, k, v = tfm.block_qkv(x, blk, n_heads, pos[:, None])
             if quantized:
-                o = attn_fn(q, (ck8, ksc), (cv8, vsc), pos)
+                k8, ks = quantize_kv(k)
+                v8, vs = quantize_kv(v)
+                ck8 = write(ck8, k8)
+                ksc = write_scale(ksc, ks)
+                cv8 = write(cv8, v8)
+                vsc = write_scale(vsc, vs)
+                out_layer = (ck8, ksc, cv8, vsc)
+                if attn_fn is None:
+                    ck = dequantize_kv(ck8, ksc)
+                    cv = dequantize_kv(cv8, vsc)
             else:
-                o = attn_fn(q, ck, cv, pos)  # [B,1,H,Dh] f32
-        else:
-            # liveness mask [B, max_len]: the ≤pos prefix — which
-            # saturates to all-live past a ring wrap (windowed), exactly
-            # the last-W-tokens semantics
-            mask = jnp.arange(max_len)[None, :] <= pos[:, None]
-            o = tfm.cache_attention(q, ck, cv, mask[:, None, :])
-        o = o.astype(x.dtype).reshape(bsz, 1, -1)
-        x = x + o @ tfm.wt(blk["wo"], x.dtype)
-        x = tfm.block_ffn(x, blk)
+                ck = write(ck, k)
+                cv = write(cv, v)
+                out_layer = (ck, cv)
+            if attn_fn is not None:
+                if quantized:
+                    o = attn_fn(q, (ck8, ksc), (cv8, vsc), pos)
+                else:
+                    o = attn_fn(q, ck, cv, pos)  # [B,1,H,Dh] f32
+            else:
+                # liveness mask [B, max_len]: the ≤pos prefix — which
+                # saturates to all-live past a ring wrap (windowed), exactly
+                # the last-W-tokens semantics
+                mask = jnp.arange(max_len)[None, :] <= pos[:, None]
+                o = tfm.cache_attention(q, ck, cv, mask[:, None, :])
+            o = o.astype(x.dtype).reshape(bsz, 1, -1)
+            x = x + o @ tfm.wt(blk["wo"], x.dtype)
+        with jax.named_scope("nns.ffn"):
+            x = tfm.block_ffn(x, blk)
         return x, out_layer
 
     if quantized:
@@ -776,11 +779,6 @@ class _Request:
     tokens: List[int] = field(default_factory=list)
     done: bool = False
     fill0: int = 0  # cache fill at admission; pos = fill0+len(tokens)-1
-    # latency stamps (perf_counter): submit → first token → done; the
-    # serving analogue of the pipeline's wall-stamped p50-e2e cell
-    t_submit: float = 0.0
-    t_first: float = 0.0
-    t_done: float = 0.0
 
     def finished(self) -> bool:
         """Budget exhausted, or the stop token was emitted (which stays
@@ -808,10 +806,21 @@ class _PendingInsert:
     resumed: bool = False  # paged: re-admission after preemption
 
 
-def _weights_jit(fn, weights, donate_argnums=(), **kw):
+def _named(fn, name: str):
+    """``fn`` under a name of its own: a jitted lambda is ``jit__lambda``
+    in a device trace, indistinguishable from every other one. The name
+    becomes the XLA module's (``jit_<name>``), which is what a reader of
+    the trace selects programs by."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _weights_jit(fn, weights, donate_argnums=(), name=None, **kw):
     """``jax.jit`` for a program that runs a model: ``fn(weights,
     *args)`` with ``weights`` bound as its first ARGUMENT (the returned
     callable takes ``*args`` only; ``donate_argnums`` counts them).
+    ``name`` names the program in the device trace (:func:`_named`);
+    the decode programs keep the ``impl`` they are known by there.
 
     A weight pytree that a jitted function merely closes over is
     lowered as CONSTANTS: serialized into every program's HLO, compiled
@@ -822,6 +831,8 @@ def _weights_jit(fn, weights, donate_argnums=(), **kw):
     one resident copy."""
     if isinstance(donate_argnums, int):
         donate_argnums = (donate_argnums,)
+    if name is not None:
+        fn = _named(fn, name)
     jitted = jax.jit(
         fn, donate_argnums=tuple(i + 1 for i in donate_argnums), **kw
     )
@@ -870,14 +881,14 @@ class _DraftEngine:
                 w, toks, cpos, cache, n_heads,
                 compute_dtype=compute_dtype, return_logits=False,
             )[1],
-            params, donate_argnums=2,
+            params, donate_argnums=2, name="nns_draft_prefill_chunk",
         )
         self._wadvance = _weights_jit(
             lambda w, toks, cpos, n, cache: dec.windowed_chunk(
                 w, toks, cpos, n, cache, n_heads,
                 compute_dtype=compute_dtype, return_logits=False,
             )[1],
-            params, donate_argnums=3,
+            params, donate_argnums=3, name="nns_draft_prefill_ring",
         )
         self._insert = jax.jit(insert_slot, donate_argnums=0)
         self._propose_w = _weights_jit(
@@ -885,7 +896,7 @@ class _DraftEngine:
                 w, tok, pos, cache, n_heads, k,
                 compute_dtype=compute_dtype,
             ),
-            params, static_argnames=("k",),
+            params, static_argnames=("k",), name="nns_draft_propose",
         )
         self._commit_w = jax.jit(commit_ring_chunk, donate_argnums=0)
         self._pending_chunk = None  # windowed: (cks, cvs) awaiting commit
@@ -1241,7 +1252,10 @@ class ContinuousBatcher:
             # the quantize/dequantize in write_block/read_block so an
             # int8 span lands the exact bytes the source held
             self._adopt_scatter = jax.jit(
-                lambda leaf, ids, vals: leaf.at[:, ids].set(vals),
+                _named(
+                    lambda leaf, ids, vals: leaf.at[:, ids].set(vals),
+                    "nns_adopt_scatter",
+                ),
                 donate_argnums=0,
             )
             self._quantized = quantized_cache
@@ -1343,7 +1357,8 @@ class ContinuousBatcher:
             lambda w, toks: dec.prefill(
                 w[0], toks, n_heads, prompt_len,
                 compute_dtype=compute_dtype,
-            )
+            ),
+            name="nns_prefill",
         )
         # chunked-prefill programs (prompts longer than the bucket): a
         # staging cache padded to a bucket multiple — plus one spare
@@ -1364,14 +1379,14 @@ class ContinuousBatcher:
                 w[0], toks, cpos, cache, n_heads,
                 compute_dtype=compute_dtype,
             ),
-            donate_argnums=2,
+            donate_argnums=2, name="nns_prefill_chunk",
         )
         self._advance_chunk = wjit(
             lambda w, toks, cpos, cache: dec.verify_chunk(
                 w[0], toks, cpos, cache, n_heads,
                 compute_dtype=compute_dtype, return_logits=False,
             )[1],
-            donate_argnums=2,
+            donate_argnums=2, name="nns_prefill_chunk_nologits",
         )
         # windowed (ring) chunked-prefill programs: exact sliding-window
         # prefill for prompts of ANY length in the fixed W ring
@@ -1381,14 +1396,14 @@ class ContinuousBatcher:
                 w[0], toks, cpos, n, cache, n_heads,
                 compute_dtype=compute_dtype,
             )[:2],
-            donate_argnums=3,
+            donate_argnums=3, name="nns_prefill_ring",
         )
         self._wadvance = wjit(
             lambda w, toks, cpos, n, cache: dec.windowed_chunk(
                 w[0], toks, cpos, n, cache, n_heads,
                 compute_dtype=compute_dtype, return_logits=False,
             )[1],
-            donate_argnums=3,
+            donate_argnums=3, name="nns_prefill_ring_nologits",
         )
 
         def step_impl(sampling):
@@ -1398,14 +1413,15 @@ class ContinuousBatcher:
                     w[0], tok, pos, active, cache, n_heads,
                     compute_dtype, attn_fn=attn_fn, windowed=windowed,
                 )
-                if sampling:
-                    # per-slot key = fold_in(base, fill level): token
-                    # streams are deterministic per (seed, position),
-                    # independent of batch composition
-                    sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                    new = sample_tokens(logits, temp, topk, topp, sub)
-                else:
-                    new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                with jax.named_scope("nns.sample"):
+                    if sampling:
+                        # per-slot key = fold_in(base, fill level): token
+                        # streams are deterministic per (seed, position),
+                        # independent of batch composition
+                        sub = jax.vmap(jax.random.fold_in)(keys, pos2)
+                        new = sample_tokens(logits, temp, topk, topp, sub)
+                    else:
+                        new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 new = jnp.where(active, new, tok)
                 hist = hist_write_row(
                     hist, new[:, None], pos2, active.astype(jnp.int32),
@@ -1470,11 +1486,12 @@ class ContinuousBatcher:
                         w[0], tok, pos, active, arena, tables,
                         n_heads, compute_dtype, attn_fn=_pg_attn,
                     )
-                    if sampling:
-                        sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                        new = sample_tokens(logits, temp, topk, topp, sub)
-                    else:
-                        new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    with jax.named_scope("nns.sample"):
+                        if sampling:
+                            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
+                            new = sample_tokens(logits, temp, topk, topp, sub)
+                        else:
+                            new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                     new = jnp.where(active, new, tok)
                     hist = hist_write_row(
                         hist, new[:, None], pos2, active.astype(jnp.int32)
@@ -1540,11 +1557,12 @@ class ContinuousBatcher:
                         params, tok, pos, active, cache, n_heads,
                         compute_dtype, attn_fn=attn_fn, windowed=windowed,
                     )
-                    if sampling:
-                        sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                        new = sample_tokens(logits, temp, topk, topp, sub)
-                    else:
-                        new = jnp.argmax(logits, -1).astype(jnp.int32)
+                    with jax.named_scope("nns.sample"):
+                        if sampling:
+                            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
+                            new = sample_tokens(logits, temp, topk, topp, sub)
+                        else:
+                            new = jnp.argmax(logits, -1).astype(jnp.int32)
                     new = jnp.where(active, new, tok)
                     emit = jnp.where(active, new, -1)
                     hist = hist_write_row(
@@ -1608,13 +1626,14 @@ class ContinuousBatcher:
                                     attn_fn=_pg_attn,
                                 )
                             )
-                        if sampling:
-                            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                            new = sample_tokens(
-                                logits, temp, topk, topp, sub
-                            )
-                        else:
-                            new = jnp.argmax(logits, -1).astype(jnp.int32)
+                        with jax.named_scope("nns.sample"):
+                            if sampling:
+                                sub = jax.vmap(jax.random.fold_in)(keys, pos2)
+                                new = sample_tokens(
+                                    logits, temp, topk, topp, sub
+                                )
+                            else:
+                                new = jnp.argmax(logits, -1).astype(jnp.int32)
                         new = jnp.where(active, new, tok)
                         emit = jnp.where(active, new, -1)
                         if _gather_pump:
@@ -1685,11 +1704,12 @@ class ContinuousBatcher:
             self._pump_greedy = wjit(pump_impl(False, _wd), **_pdon)
             self._pump_sampling = wjit(pump_impl(True, _wd), **_pdon)
         # first-token pick: same device sampler over the prefill logits
-        self._sample1 = jax.jit(
+        self._sample1 = jax.jit(_named(
             lambda logits, temp, topk, topp, key: sample_tokens(
                 logits[None, :], temp, topk, topp, key[None]
-            )[0]
-        )
+            )[0],
+            "nns_sample_first",
+        ))
         self._insert = jax.jit(insert_slot, donate_argnums=0)
 
         # one speculative round = verify + device-side acceptance (+ ring
@@ -1955,9 +1975,14 @@ class ContinuousBatcher:
             if draft_params is not None else None
         )
         self._load_prefix = jax.jit(
-            lambda stage, ks, vs: (
-                jax.lax.dynamic_update_slice(stage[0], ks, (0, 0, 0, 0, 0)),
-                jax.lax.dynamic_update_slice(stage[1], vs, (0, 0, 0, 0, 0)),
+            _named(
+                lambda stage, ks, vs: (
+                    jax.lax.dynamic_update_slice(
+                        stage[0], ks, (0, 0, 0, 0, 0)),
+                    jax.lax.dynamic_update_slice(
+                        stage[1], vs, (0, 0, 0, 0, 0)),
+                ),
+                "nns_load_prefix",
             ),
             donate_argnums=0,
         )
@@ -1978,13 +2003,6 @@ class ContinuousBatcher:
         # tests/test_kv_block_attn.py); mirrored to the
         # nns_kv_gather_dispatch_total obs counter
         self._n_gather_dispatch = 0
-        self._step_time_s = 0.0
-        # bounded per-request latency windows (newest 1024): TTFT and
-        # full request wall time — stats() reports their p50s
-        self._lat_ttft: deque = deque(maxlen=1024)
-        self._lat_req: deque = deque(maxlen=1024)
-        self._lat_version = 0       # bumped per finished request
-        self._lat_cache = (-1, 0.0, 0.0)  # (version, p50_ttft_ms, p50_req_s)
 
     def _empty_stage(self):
         return (
@@ -2269,7 +2287,6 @@ class ContinuousBatcher:
             req = _Request(
                 rid, max_new_tokens, temperature=temperature, top_k=top_k,
                 top_p=top_p, stop_token=stop_token,
-                t_submit=_time.perf_counter(),
                 key=np.asarray(
                     jax.random.PRNGKey(rid if seed is None else seed)
                 ),
@@ -2332,7 +2349,6 @@ class ContinuousBatcher:
                 first = int(first_dev)
                 with self._lock:
                     req.fill0 = fill
-                    req.t_first = _time.perf_counter()
                     req.tokens.append(first)
                     self._finish(slot)
                 return rid
@@ -2392,19 +2408,19 @@ class ContinuousBatcher:
         OUTSIDE self._lock: it may wait on an in-flight chunked
         prefill, and readers (submit/result/partials/stats) must not
         stall behind it."""
-        with self._lock:
-            batch = self._pending
-            self._pending = []
-        if not batch:
-            return
-        firsts = np.asarray(jnp.stack(
-            [jnp.asarray(p.first_tok).reshape(()) for p in batch]
-        )).reshape(-1)
-        with self._lock:
-            self._apply_batch_locked(batch, firsts)
+        with _trace.span("nns.pump.admit"):
+            with self._lock:
+                batch = self._pending
+                self._pending = []
+            if not batch:
+                return
+            firsts = np.asarray(jnp.stack(
+                [jnp.asarray(p.first_tok).reshape(()) for p in batch]
+            )).reshape(-1)
+            with self._lock:
+                self._apply_batch_locked(batch, firsts)
 
     def _apply_batch_locked(self, batch, firsts) -> None:
-        now = _time.perf_counter()
         self._pump_state_dirty = True  # admission changes pump state
         for p, first in zip(batch, firsts):
             if self._slots[p.slot] is not p.req:
@@ -2419,7 +2435,6 @@ class ContinuousBatcher:
                 self._n_alloc[p.slot] = len(p.blocks)
                 self._tables_dirty = True
             if not p.resumed:
-                p.req.t_first = now
                 p.req.tokens.append(first)
                 self._slo.admitted(p.req.rid)
                 self._slo.first_token(p.req.rid)
@@ -2496,7 +2511,6 @@ class ContinuousBatcher:
             req = _Request(
                 rid, max_new_tokens, temperature=temperature,
                 top_k=top_k, top_p=top_p, stop_token=stop_token,
-                t_submit=_time.perf_counter(),
                 key=np.asarray(
                     jax.random.PRNGKey(rid if seed is None else seed)
                 ),
@@ -2547,24 +2561,25 @@ class ContinuousBatcher:
         drains (the cold-start admission latency fix; the interleave
         bound is unchanged the moment anything is live).
         Caller holds _step_lock; _lock is taken only for bookkeeping."""
-        budget = self._prefill_chunks
-        while True:
-            with self._lock:
-                job = self._prefill_q[0] if self._prefill_q else None
-                idle = not self._active.any() and not self._pending
-            if job is None or (budget <= 0 and not idle):
-                return
-            self._slo.prefilling(job.req.rid)
-            if not job.done_staging():
-                self._prefill_chunk_one(job)
-                budget -= 1
-            if job.done_staging():
-                if self._prefill_finalize(job):
-                    with self._lock:
-                        if self._prefill_q and self._prefill_q[0] is job:
-                            self._prefill_q.popleft()
-                else:
-                    return  # blocks not affordable yet (watermark)
+        with _trace.span("nns.pump.prefill", prefill_q=len(self._prefill_q)):
+            budget = self._prefill_chunks
+            while True:
+                with self._lock:
+                    job = self._prefill_q[0] if self._prefill_q else None
+                    idle = not self._active.any() and not self._pending
+                if job is None or (budget <= 0 and not idle):
+                    return
+                self._slo.prefilling(job.req.rid)
+                if not job.done_staging():
+                    self._prefill_chunk_one(job)
+                    budget -= 1
+                if job.done_staging():
+                    if self._prefill_finalize(job):
+                        with self._lock:
+                            if self._prefill_q and self._prefill_q[0] is job:
+                                self._prefill_q.popleft()
+                    else:
+                        return  # blocks not affordable yet (watermark)
 
     def _prefill_chunk_one(self, job) -> None:
         """One ``prompt_len`` bucket of chunked prefill for ``job``
@@ -3019,7 +3034,6 @@ class ContinuousBatcher:
                     rid, span.budget, temperature=span.temperature,
                     top_k=span.top_k, top_p=span.top_p,
                     stop_token=span.stop_token,
-                    t_submit=_time.perf_counter(),
                     key=np.asarray(span.key, np.uint32),
                     prompt=np.asarray(span.prompt, np.int32),
                 )
@@ -3107,7 +3121,6 @@ class ContinuousBatcher:
                 rid, span.budget, temperature=span.temperature,
                 top_k=span.top_k, top_p=span.top_p,
                 stop_token=span.stop_token,
-                t_submit=_time.perf_counter(),
                 key=np.asarray(span.key, np.uint32),
                 prompt=np.asarray(span.prompt, np.int32),
             )
@@ -3161,9 +3174,17 @@ class ContinuousBatcher:
         steppers. Slots admitted while a step is in flight join at the
         next step."""
         self._check_failed()
-        t0 = _time.perf_counter()
-        with self._step_lock:
-            return self._plain_step_locked(t0)
+        with self._pump_span(1), self._step_lock:
+            return self._plain_step_locked()
+
+    def _pump_span(self, n_steps: int):
+        """The ``nns.pump`` span of one entry into the decode loop, with
+        what the host knows at entry (no lock: the counts are for a
+        reader of the trace, not for control)."""
+        return _trace.span(
+            "nns.pump", n_steps=n_steps, active=int(self._active.sum()),
+            prefill_q=len(self._prefill_q) if self._paged else 0,
+        )
 
     def _harvest_rows_locked(
         self, active_np, rows
@@ -3173,28 +3194,29 @@ class ContinuousBatcher:
         them; returns ({rid: tokens}, n_emitted). One implementation of
         the budget/stop truncation discipline for every pump commit
         path (caller holds _lock)."""
-        out: Dict[int, List[int]] = {}
-        n_em = 0
-        for s, req in enumerate(self._slots):
-            if req is None or not active_np[s]:
-                continue
-            got: List[int] = []
-            for row in rows(s):
-                for t in row:
-                    if t < 0:
-                        break
-                    req.tokens.append(int(t))
-                    got.append(int(t))
-                    n_em += 1
+        with _trace.span("nns.pump.harvest"):
+            out: Dict[int, List[int]] = {}
+            n_em = 0
+            for s, req in enumerate(self._slots):
+                if req is None or not active_np[s]:
+                    continue
+                got: List[int] = []
+                for row in rows(s):
+                    for t in row:
+                        if t < 0:
+                            break
+                        req.tokens.append(int(t))
+                        got.append(int(t))
+                        n_em += 1
+                        if req.finished():
+                            break
                     if req.finished():
                         break
+                if got:
+                    out[req.rid] = got
                 if req.finished():
-                    break
-            if got:
-                out[req.rid] = got
-            if req.finished():
-                self._finish(s)
-        return out, n_em
+                    self._finish(s)
+            return out, n_em
 
     def _pump_host_state(self, active_np):
         """Per-slot budget remaining + stop ids for a device pump
@@ -3268,12 +3290,11 @@ class ContinuousBatcher:
         (gst/nnstreamer/tensor_filter/tensor_filter.c) batched along
         the token axis instead."""
         self._check_failed()
-        t0 = _time.perf_counter()
-        with self._step_lock:
+        with self._pump_span(int(n)), self._step_lock:
             if self._paged:
                 self._advance_prefill()
             self._apply_pending()
-            with self._lock:
+            with _trace.span("nns.pump.prepare"), self._lock:
                 if not self._active.any():
                     return {}
                 if self._paged:
@@ -3302,16 +3323,18 @@ class ContinuousBatcher:
                     )
             fn = self._pump_sampling if sampling else self._pump_greedy
             try:
-                if self._paged:
-                    emits, tok, pos, act, cache, hist, budget = fn(
-                        *args, n_steps=int(n)
-                    )
-                    dcache = None
-                else:
-                    emits, tok, pos, act, cache, hist, budget, dcache = fn(
-                        *args, n_steps=int(n)
-                    )
-                emits_np = np.asarray(emits)  # ONE [B, n] transfer
+                with _trace.span("nns.pump.launch",
+                                 active=int(active_np.sum())):
+                    if self._paged:
+                        emits, tok, pos, act, cache, hist, budget = fn(
+                            *args, n_steps=int(n)
+                        )
+                        dcache = None
+                    else:
+                        (emits, tok, pos, act, cache, hist, budget,
+                         dcache) = fn(*args, n_steps=int(n))
+                with _trace.span("nns.pump.wait"):
+                    emits_np = np.asarray(emits)  # ONE [B, n] transfer
             except Exception as exc:
                 # the launch donated _cache/_hist (and the draft cache):
                 # a raise here leaves them consumed — latch the failure
@@ -3334,7 +3357,6 @@ class ContinuousBatcher:
                 )
                 self._n_steps += int(n)
                 self._n_tokens += n_em
-                self._step_time_s += _time.perf_counter() - t0
                 return out
 
     def spec_pump(
@@ -3359,7 +3381,6 @@ class ContinuousBatcher:
         its own XLA program — quantization bounds the program variants
         to log2(rounds) instead of one per tail length."""
         self._check_failed()
-        t0 = _time.perf_counter()
         k = max(2, int(k))
         if self._draft is not None and self.windowed:
             return self._spec_fallback_rounds(int(rounds), k, ngram)
@@ -3449,7 +3470,7 @@ class ContinuousBatcher:
                     self._budget_dev = self._pin(budget)
                     self._active_dev = self._pin(act)
                     return self._spec_pump_commit_locked(
-                        t0, active_np, r, acc, cols, emits_np, tok, pos,
+                        active_np, r, acc, cols, emits_np, tok, pos,
                         cache, hist, dcache,
                     )
         # r < 1: no verify room at any width ≥ 2 — the shrinking-k host
@@ -3518,7 +3539,7 @@ class ContinuousBatcher:
         return {rid: toks for rid, toks in out.items() if toks}
 
     def _spec_pump_commit_locked(
-        self, t0, active_np, r, acc, cols, emits_np, tok, pos, cache,
+        self, active_np, r, acc, cols, emits_np, tok, pos, cache,
         hist, dcache,
     ) -> Dict[int, List[int]]:
         """spec_pump bookkeeping; caller holds _step_lock + _lock."""
@@ -3536,10 +3557,9 @@ class ContinuousBatcher:
         self._n_spec_rounds += r
         self._n_spec_accepted += acc
         self._n_spec_columns += cols
-        self._step_time_s += _time.perf_counter() - t0
         return out
 
-    def _plain_step_locked(self, t0) -> Dict[int, int]:
+    def _plain_step_locked(self) -> Dict[int, int]:
         """step() body; caller holds _step_lock."""
         if self._paged:
             self._advance_prefill()
@@ -3596,7 +3616,6 @@ class ContinuousBatcher:
                     self._finish(slot)
             self._n_steps += 1
             self._n_tokens += len(emitted)
-            self._step_time_s += _time.perf_counter() - t0
             # host-stepped path: budgets advanced outside a pump scan,
             # so the device-carried pump state must rebuild next pump
             self._pump_state_dirty = True
@@ -3638,7 +3657,6 @@ class ContinuousBatcher:
         same inline-attention math). Returns {rid: last emitted token};
         use partials() for the full per-round stream."""
         self._check_failed()
-        t0 = _time.perf_counter()
         with self._step_lock:
             if self._paged:
                 self._advance_prefill()
@@ -3714,7 +3732,7 @@ class ContinuousBatcher:
                                 k_round = 1
             if k_round < 2:
                 # outside self._lock — _plain_step_locked reacquires it
-                return self._plain_step_locked(t0)
+                return self._plain_step_locked()
             if self._draft is not None:
                 # k-1 batched draft forwards propose for every slot at
                 # once; a draft always proposes, so there is no
@@ -3795,7 +3813,6 @@ class ContinuousBatcher:
                 self._n_spec_columns += int(
                     (toks_host[active_np, 1:] >= 0).sum()
                 )
-                self._step_time_s += _time.perf_counter() - t0
                 self._pump_state_dirty = True  # host-stepped path
                 return emitted
 
@@ -3811,10 +3828,6 @@ class ContinuousBatcher:
                 "tokens_per_step": (
                     self._n_tokens / self._n_steps if self._n_steps else 0.0
                 ),
-                "decode_tok_s": (
-                    self._n_tokens / self._step_time_s
-                    if self._step_time_s > 0 else 0.0
-                ),
                 "spec_rounds": self._n_spec_rounds,
                 "spec_accepted_tokens": self._n_spec_accepted,
                 # accepted/columns is the true per-proposal acceptance
@@ -3825,8 +3838,6 @@ class ContinuousBatcher:
                     self._n_spec_accepted / self._n_spec_columns
                     if self._n_spec_columns else 0.0
                 ),
-                "p50_ttft_ms": self._lat_p50s_locked()[0],
-                "p50_request_s": self._lat_p50s_locked()[1],
                 "slots_occupied": occupied,
                 "slots_free": self.n_slots - occupied,
                 "results_pending_pickup": len(self._done_pool),
@@ -3851,22 +3862,6 @@ class ContinuousBatcher:
                 st["request_resumes"] = self._n_resumes
             return st
 
-    def _lat_p50s_locked(self):
-        """Cached latency medians (_lock held): the auto-speculation
-        controller polls stats() every pump, so the O(n log n) sorts
-        run only when a request finished since the last call."""
-        if self._lat_cache[0] != self._lat_version:
-            ttft = (
-                sorted(self._lat_ttft)[len(self._lat_ttft) // 2] * 1000.0
-                if self._lat_ttft else 0.0
-            )
-            req_s = (
-                sorted(self._lat_req)[len(self._lat_req) // 2]
-                if self._lat_req else 0.0
-            )
-            self._lat_cache = (self._lat_version, ttft, req_s)
-        return self._lat_cache[1], self._lat_cache[2]
-
     def _pin(self, x):
         """Keep per-slot vectors on their mesh sharding after eager
         updates, so the compiled step sees stable input shardings."""
@@ -3875,12 +3870,6 @@ class ContinuousBatcher:
     def _finish(self, slot: int) -> None:
         req = self._slots[slot]
         req.done = True
-        req.t_done = _time.perf_counter()
-        if req.t_first and req.t_submit:
-            self._lat_ttft.append(req.t_first - req.t_submit)
-        if req.t_submit:
-            self._lat_req.append(req.t_done - req.t_submit)
-        self._lat_version += 1
         self._active[slot] = False
         self._pump_state_dirty = True  # slot left the batch
         if self._paged:
@@ -4105,7 +4094,6 @@ class ContinuousBatcher:
                     top_p=d["top_p"], stop_token=d["stop_token"],
                     key=np.asarray(d["key"], np.uint32),
                     prompt=np.asarray(d["prompt"], np.int32),
-                    t_submit=_time.perf_counter(),
                 )
                 req.tokens = list(d["tokens"])
                 req.fill0 = int(d["fill0"])

@@ -189,6 +189,11 @@ METRIC_CATALOG: Dict[str, str] = {
         "from an on-disk span checkpoint after a restart) (counter; "
         "docs/llm-serving.md)"
     ),
+    "nns_request_queue_ms": (
+        "per-request wait for the head of the paged batcher's prefill "
+        "queue, submit → first prefill chunk, milliseconds (histogram; "
+        "the part of TTFT that is queueing — docs/llm-serving.md)"
+    ),
     "nns_request_ttft_ms": (
         "per-request time to first token, submit → first token "
         "materialized, milliseconds (histogram; the admission SLO — "
@@ -197,6 +202,13 @@ METRIC_CATALOG: Dict[str, str] = {
     "nns_request_tpot_ms": (
         "per-request mean time per output token after the first, "
         "milliseconds (histogram; the decode SLO — docs/llm-serving.md)"
+    ),
+    "nns_llm_setup_seconds": (
+        "what starting a tensor_llm_serversink cost, by phase label, each "
+        "set once: weights (the zoo model opened), batcher (the "
+        "ContinuousBatcher built), first_token (batcher built → the "
+        "first token of any request: program builds or cache reads, "
+        "first prefill and pump), seconds (gauge)"
     ),
     "nns_transfer_bytes_total": (
         "bytes crossing the host<->device boundary through the "

@@ -29,6 +29,14 @@ that capability in-tree:
 - ``device_profile()``: wraps ``jax.profiler.trace`` — the XPlane/
   TensorBoard capture for on-device (TPU) timing, the XLA-world analogue
   of GstShark's proctime tracer.
+- ``span()`` / ``instant()`` (module level): the program's own spans on
+  the DEVICE trace's clock. Each enters a ``jax.profiler.TraceAnnotation``,
+  so while any profiler session runs (``device_profile``, ``nns-launch
+  --profile``, the benchmark's ``--trace 1`` window) the span is an event
+  on the trace's host plane beside the device planes, its attributes the
+  event's stats; with a ``Tracer`` enabled the same call also records the
+  chrome "X"/"i" event. With neither, an annotation the profiler ignores
+  (under a microsecond). ``SPAN_CATALOG`` lists every ``nns.*`` name.
 
 Enable via ``trace.enable()`` / ``nns-launch --trace out.json``; env knob
 ``NNS_TRACE`` (path) mirrors the reference's GST_DEBUG_DUMP_DOT_DIR-style
@@ -43,7 +51,7 @@ import os
 import threading
 import time
 from collections import deque
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 _lock = threading.Lock()
 _tracer: Optional["Tracer"] = None
@@ -314,6 +322,165 @@ def get() -> Optional[Tracer]:
             if os.environ.get("NNS_TRACE"):
                 t = enable()
     return t
+
+
+# -- program spans on the device trace's clock -------------------------------
+
+# name → (layer, the interval or moment it covers, attributes). Held to the
+# code and to docs/observability.md by analysis/selfcheck.span_self_check;
+# the names are what benchmark/lib/host_spans.py and its readers look for.
+SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
+    "nns.llm.submit": (
+        "elements and executor",
+        "_LlmServer.submit, whole call on the sink's thread: arrival at the "
+        "element to slot claimed, the back-pressure pumps included",
+        "prompt_tokens",
+    ),
+    "nns.llm.admitted": (
+        "elements and executor",
+        "instant at the end of a submit that claimed a slot",
+        "rid, slot_wait_ms (arrival to slot claimed), retries (submits "
+        "refused for want of a free slot)",
+    ),
+    "nns.llm.pump": (
+        "batcher",
+        "_LlmServer.pump, whole call: one batcher pump and the harvest of "
+        "its tokens into the out queue",
+        "pending (requests in flight at entry)",
+    ),
+    "nns.llm.harvest": (
+        "elements and executor",
+        "in pump, the locked block that streams partials and finished "
+        "requests into the out queue",
+        "",
+    ),
+    "nns.llm.emit": (
+        "elements and executor",
+        "LlmServerSrc.generate: first pop that returns a frame after a pump "
+        "to the pop that finds the queue empty; one span per burst, held "
+        "open across generate calls, so it covers the executor's push of "
+        "every frame downstream",
+        "frames (length of the out queue when the burst opens)",
+    ),
+    "nns.pump": (
+        "batcher",
+        "ContinuousBatcher.step / step_pump / spec_step / spec_pump, whole "
+        "call",
+        "n_steps (tokens per slot this launch may emit), active (slots live "
+        "at entry), prefill_q (jobs waiting at entry)",
+    ),
+    "nns.pump.prefill": (
+        "batcher",
+        "_advance_prefill: the HOST side of chunked prefill (the programs "
+        "it dispatches run asynchronously)",
+        "prefill_q (jobs waiting at entry)",
+    ),
+    "nns.pump.admit": (
+        "batcher",
+        "_apply_pending: the packed read of the queued admissions' first "
+        "tokens and their splice into the slot state",
+        "",
+    ),
+    "nns.pump.prepare": (
+        "batcher",
+        "step_pump's locked block that builds the launch's arguments "
+        "(decode room, pump state, block tables)",
+        "",
+    ),
+    "nns.pump.launch": (
+        "batcher",
+        "the call of the jitted decode program (dispatch only)",
+        "active (slots live at the launch)",
+    ),
+    "nns.pump.wait": (
+        "batcher",
+        "the one [B, n] device-to-host read of the emitted tokens: the "
+        "host waits here while the device decodes",
+        "",
+    ),
+    "nns.pump.harvest": (
+        "batcher",
+        "commit of the carried state and _harvest_rows_locked, to return",
+        "",
+    ),
+    "nns.req.submit": (
+        "batcher",
+        "instant: SLOLedger.submit, the request has a slot and a record",
+        "rid",
+    ),
+    "nns.req.prefill_start": (
+        "batcher",
+        "instant: SLOLedger.prefilling, the request reached the head of "
+        "the prefill queue (again after a preemption)",
+        "rid, queue_ms (submit to here; the first time only)",
+    ),
+    "nns.req.admitted": (
+        "batcher",
+        "instant: SLOLedger.admitted, prefill done and the slot active",
+        "rid, prefill_ms (prefill start, or submit, to here)",
+    ),
+    "nns.req.first_token": (
+        "batcher",
+        "instant: SLOLedger.first_token",
+        "rid, ttft_ms",
+    ),
+    "nns.req.done": (
+        "batcher",
+        "instant: SLOLedger.finished",
+        "rid, tokens, tpot_ms, preemptions",
+    ),
+}
+
+_annotation = None  # jax.profiler.TraceAnnotation, imported at first use
+
+
+def _annotate(name: str, attrs: Dict):
+    global _annotation
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation(name, **attrs)
+
+
+class span:
+    """``with trace.span("nns.pump", n_steps=8): ...`` — one interval on
+    the profiler's host plane (and in the chrome trace when a ``Tracer``
+    is on). Attributes are ints, floats or short strings known when the
+    span opens and computed from host state only: reading a device array
+    for one would be a sync on the hot path. A span held open across
+    calls (``nns.llm.emit``) uses ``__enter__`` and ``close`` directly,
+    both on the same thread."""
+
+    __slots__ = ("_name", "_attrs", "_ann", "_t0")
+
+    def __init__(self, name: str, **attrs) -> None:
+        self._name, self._attrs = name, attrs
+
+    def __enter__(self) -> "span":
+        self._ann = _annotate(self._name, self._attrs)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter() if get() is not None else None
+        return self
+
+    def close(self) -> None:
+        self._ann.__exit__(None, None, None)
+        t = get()
+        if t is not None and self._t0 is not None:
+            t.complete(self._name, "span", self._t0,
+                       time.perf_counter() - self._t0, self._attrs or None)
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+def instant(name: str, **attrs) -> None:
+    """One moment on the same two timelines as :class:`span`."""
+    with _annotate(name, attrs):
+        pass
+    t = get()
+    if t is not None:
+        t.instant(name, cat="span", **attrs)
 
 
 @contextlib.contextmanager

@@ -600,7 +600,10 @@ def test_stats_surface(params):
     s = cb.stats()
     assert s["steps"] == 4  # first token came from prefill
     assert s["tokens_emitted"] == 4
-    assert s["decode_tok_s"] > 0
+    assert s["tokens_per_step"] == 1.0
+    row = cb.requests()[rid]
+    assert row["state"] == "done" and row["tokens"] == 5
+    assert row["tpot_ms"] > 0
     assert s["slots_free"] == 2
     assert s["results_pending_pickup"] == 1
 
@@ -765,15 +768,16 @@ def test_mesh_with_draft_speculation_matches_unsharded(params):
 
 
 def test_latency_telemetry_surface(params):
-    """stats() reports p50 TTFT and p50 request wall time from bounded
-    per-request windows — the serving analogue of the pipeline's
-    wall-stamped p50-e2e cell (BASELINE 'p50 e2e tracked')."""
+    """requests() reports every request's queue, TTFT and per-token time
+    from the SLO ledger — the one latency bookkeeping the batcher has."""
     cb = ContinuousBatcher(params, N_HEADS, n_slots=2, max_len=48,
                            prompt_len=16)
     rids = [cb.submit(_prompt(5 + i, 900 + i), 4) for i in range(2)]
     while any(cb.result(r) is None for r in rids):
         cb.step_pump(4)
-    st = cb.stats()
-    assert st["p50_ttft_ms"] > 0.0
-    assert st["p50_request_s"] > 0.0
-    assert st["p50_request_s"] * 1000.0 >= st["p50_ttft_ms"]
+    rows = cb.requests()
+    for rid in rids:
+        row = rows[rid]
+        assert row["state"] == "done" and row["tokens"] == 4
+        assert row["ttft_ms"] > 0.0 and row["tpot_ms"] > 0.0
+        assert row["ttft_ms"] >= row["queue_ms"] >= 0.0

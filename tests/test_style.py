@@ -54,6 +54,17 @@ def test_obs_metric_catalog_covers_code():
     assert not problems, "\n".join(problems)
 
 
+def test_span_catalog_covers_code():
+    """Span self-check: every ``nns.*`` name the package passes to
+    trace.span / trace.instant is in trace.SPAN_CATALOG, every cataloged
+    span has an emitter, and docs/observability.md documents every name
+    (the benchmark's readers select trace events by these names)."""
+    from nnstreamer_tpu.analysis.selfcheck import span_self_check
+
+    problems = span_self_check()
+    assert not problems, "\n".join(problems)
+
+
 def test_san_diagnostic_catalog_covers_code():
     """nns-san --self-check: every emitted code is cataloged, every
     cataloged code has an emitter, slugs stay unique, and the sanitizer

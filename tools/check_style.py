@@ -93,17 +93,21 @@ def run_self_check() -> list:
 
 
 def run_obs_self_check() -> list:
-    """Run the nns-obs metric-catalog self-check in-process: a metric
-    emitted but uncataloged (or cataloged but undocumented) is invisible
-    to dashboards and to docs/observability.md readers."""
+    """Run the nns-obs metric-catalog and span-catalog self-checks
+    in-process: a metric or span emitted but uncataloged (or cataloged
+    but undocumented) is invisible to dashboards, to the benchmark's
+    readers and to docs/observability.md readers."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in sys.path:
         sys.path.insert(0, repo)
     try:
-        from nnstreamer_tpu.analysis.selfcheck import obs_self_check
+        from nnstreamer_tpu.analysis.selfcheck import (
+            obs_self_check,
+            span_self_check,
+        )
     except Exception as exc:  # pragma: no cover - broken tree
         return [f"obs self-check could not run: {exc}"]
-    return [f"obs: {p}" for p in obs_self_check()]
+    return [f"obs: {p}" for p in obs_self_check() + span_self_check()]
 
 
 def run_race_lint_gate() -> list:
