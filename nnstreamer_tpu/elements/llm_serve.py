@@ -138,7 +138,7 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
                    max_len: int, prompt_len: int, speculate: int,
                    speculate_model: str, kv_layout: str, block_size: int,
                    kv_blocks: int, cache_dtype: str, prefill_chunks: int,
-                   kv_attn: str, attn_impl: str = "xla"):
+                   kv_attn: str, attn_impl: str = ""):
     """Open the zoo model (+ optional draft) and build the
     ContinuousBatcher — shared by the private-server path and the
     LlmPlane opener (serving_plane/llm.py), so through-plane serving
@@ -195,7 +195,7 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
     cb = ContinuousBatcher(
         m.params, n_heads, n_slots=n_slots, max_len=max_len,
         prompt_len=prompt_len, cache_dtype=cache_dtype,
-        attn_impl=attn_impl or "xla",
+        attn_impl=attn_impl,
         **kv_kw, **draft_kw,
     )
     reg = _obs_metrics.get()
@@ -217,7 +217,7 @@ class _LlmServer:
                  kv_layout: str = "slot", block_size: int = 16,
                  kv_blocks: int = 0, cache_dtype: str = "auto",
                  prefill_chunks: int = 1, kv_attn: str = "auto",
-                 attn_impl: str = "xla",
+                 attn_impl: str = "",
                  plane: str = "", plane_weight: float = 1.0,
                  srv_id: str = "0", migrate_to: str = "",
                  checkpoint_every_tokens: int = 0,
@@ -329,7 +329,7 @@ class _LlmServer:
                 model, tuple(sorted(options.items())), n_slots, max_len,
                 prompt_len, kv_layout, block_size, kv_blocks,
                 cache_dtype, prefill_chunks, kv_attn or "auto",
-                attn_impl or "xla",
+                attn_impl,
                 max(1, int(pump_tokens)),
             )
             self._plane = llm_plane.acquire(
@@ -1074,9 +1074,11 @@ class LlmServerSink(Sink):
         "cache-dtype": PropSpec("str", "auto", desc="auto | int8"),
         "attn-impl": PropSpec(
             "str", "",
-            desc="decode attention kernel: xla | pallas ([llm] "
-            "attn_impl default; a pallas request the kernel registry "
-            "would degrade is flagged by nns-lint NNS-W129)",
+            desc="decode attention: xla | pallas; unset takes [llm] "
+            "attn_impl, else the block-table kernel for kv-layout=paged "
+            "on a TPU and xla elsewhere (stats: attn_impl); a pallas "
+            "request the kernel registry would degrade is flagged by "
+            "nns-lint NNS-W129",
         ),
         "prefill-chunks": PropSpec(
             "int", 0, desc="prefill buckets per pump (paged; 0=[llm])"
@@ -1193,7 +1195,7 @@ class LlmServerSink(Sink):
             prefill_chunks=prefill_chunks,
             kv_attn=kv_attn,
             attn_impl=str(self.get_property("attn-impl", "")).strip() or (
-                cfg.get("llm", "attn_impl", "xla")
+                cfg.get("llm", "attn_impl", "")
             ),
             plane=str(self.get_property("plane", "") or ""),
             plane_weight=float(self.get_property("plane-weight", 1.0)),

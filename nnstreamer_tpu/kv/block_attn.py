@@ -148,22 +148,29 @@ def batched_decode_step_block(
 
     tok/pos/active [B] as in the slot step; ``arena`` is the kv.gather
     arena tree (leaves [L, N, bs, ...]), ``tables`` [B, nb] int32 →
-    (logits [B, V] f32, arena', pos'). Per layer, the attention view is
-    taken through the tables and the pending token's K/V is written into
-    it with the EXACT expressions the gathered path used — bitwise
-    parity with the gather oracle by construction — while the arena
-    write itself is deferred to one :func:`write_fresh_window` scatter
-    after the layer scan (in place under donation; no ``scatter_window``,
-    no carried view). ``attn_fn(q, k_entry, v_entry, tables, pos,
-    (fresh_k, fresh_v)) -> [B,1,H,Dh]`` overrides the inline read with a
-    block-table kernel (ops/pallas/paged_attention.py) that never
-    materializes the view at all."""
+    (logits [B, V] f32, arena', pos'). The arena write is deferred to
+    one :func:`write_fresh_window` scatter after the layer scan (in
+    place under donation; no ``scatter_window``, no carried view).
+
+    The attention read has two formulations. Inline (``attn_fn`` None,
+    the XLA oracle): per layer the view is taken through the tables and
+    the pending token's K/V is written into it with the EXACT
+    expressions the gathered path used — bitwise parity with the gather
+    oracle by construction. With ``attn_fn(q, k_entry, v_entry, tables,
+    fill, (fresh_k, fresh_v), layer=li) -> [B,1,H,Dh]`` (the block-table
+    kernel, ops/pallas/paged_attention.py) no view exists at all: the
+    layer scan carries only the layer INDEX and every layer reads the
+    same WHOLE arena leaves through the tables, live blocks only
+    (``fill`` is ``pos`` on active lanes and 0 elsewhere, so a finished
+    lane's stale table is never walked) — nothing layer-sized is sliced
+    or copied in front of the kernel."""
     quantized = isinstance(arena[0], tuple)
     first = arena[0][0] if quantized else arena[0]
     bs_blk = first.shape[2]
     max_len = tables.shape[1] * bs_blk
     x = tfm.embed_lookup(params["embed"], tok, compute_dtype)[:, None, :]
     gate = active[:, None, None, None]
+    fill = jnp.where(active, pos, 0)
 
     def write(c, new):
         return _write_view(c, new, pos, gate)
@@ -173,10 +180,7 @@ def batched_decode_step_block(
 
     def body(carry, layer):
         x = carry
-        if quantized:
-            blk, ka, ksc, va, vsc = layer
-        else:
-            blk, ka, va = layer
+        blk, *kv = layer
         bsz, _, d = x.shape
         with jax.named_scope("nns.attn"):
             q, k, v = tfm.block_qkv(x, blk, n_heads, pos[:, None])
@@ -184,7 +188,20 @@ def batched_decode_step_block(
                 k8, ks = quantize_kv(k)
                 v8, vs = quantize_kv(v)
                 fresh = (k8, ks, v8, vs)
-                if attn_fn is None:
+            else:
+                fresh = (k, v)
+            if attn_fn is not None:
+                (li,) = kv
+                fresh_kv = (
+                    (dequantize_kv(k8, ks), dequantize_kv(v8, vs))
+                    if quantized else fresh
+                )
+                o = attn_fn(
+                    q, arena[0], arena[1], tables, fill, fresh_kv, layer=li
+                )
+            else:
+                if quantized:
+                    ka, ksc, va, vsc = kv
                     ck = dequantize_kv(
                         write(_take_layer(ka, tables), k8),
                         write_scale(_take_layer(ksc, tables), ks),
@@ -193,21 +210,10 @@ def batched_decode_step_block(
                         write(_take_layer(va, tables), v8),
                         write_scale(_take_layer(vsc, tables), vs),
                     )
-                    o = None
                 else:
-                    o = attn_fn(
-                        q, (ka, ksc), (va, vsc), tables, pos,
-                        (dequantize_kv(k8, ks), dequantize_kv(v8, vs)),
-                    )
-            else:
-                fresh = (k, v)
-                if attn_fn is None:
+                    ka, va = kv
                     ck = write(_take_layer(ka, tables), k)
                     cv = write(_take_layer(va, tables), v)
-                    o = None
-                else:
-                    o = attn_fn(q, ka, va, tables, pos, (k, v))
-            if o is None:
                 mask = jnp.arange(max_len)[None, :] <= pos[:, None]
                 o = tfm.cache_attention(q, ck, cv, mask[:, None, :])
             o = o.astype(x.dtype).reshape(bsz, 1, -1)
@@ -216,7 +222,9 @@ def batched_decode_step_block(
             x = tfm.block_ffn(x, blk)
         return x, fresh
 
-    if quantized:
+    if attn_fn is not None:
+        xs = (params["blocks"], jnp.arange(first.shape[0], dtype=jnp.int32))
+    elif quantized:
         (ka, ksc), (va, vsc) = arena
         xs = (params["blocks"], ka, ksc, va, vsc)
     else:

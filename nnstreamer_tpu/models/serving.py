@@ -1027,7 +1027,7 @@ class ContinuousBatcher:
         max_len: int = 256,
         prompt_len: int = 64,
         compute_dtype=jnp.float32,
-        attn_impl: str = "xla",
+        attn_impl: str = "",
         keep_results: int = 1024,
         cache_dtype: str = "auto",
         mesh=None,
@@ -1046,6 +1046,15 @@ class ContinuousBatcher:
         run in the fixed [max_len] cache, each token attending the
         previous max_len (Mistral-style sliding-window attention — the
         time-axis sibling of tensor_aggregator's bounded windows).
+
+        ``attn_impl`` picks the decode attention: ``"xla"``,
+        ``"pallas"`` (the decode kernels of ops/pallas), or unset
+        (``""``): the block-table kernel under the block-native paged
+        layout on a TPU backend where the registry passes the arena
+        dtype — the rule of ``kv.block_attn.block_attention
+        (impl="auto")`` — and ``"xla"`` everywhere else, so off-TPU the
+        default stays the bit-pinned XLA formulation. ``stats()``
+        reports what was resolved as ``attn_impl``.
 
         The full feature matrix composes: attn_impl="pallas" works with
         cache_dtype="int8" (the kernel takes the scale operands and
@@ -1103,7 +1112,7 @@ class ContinuousBatcher:
                 (windowed, "windowed (ring) caches"),
                 (mesh is not None, "mesh-sharded slots"),
                 (draft_params is not None, "draft models"),
-                (attn_impl not in ("xla", "pallas"),
+                (attn_impl not in ("", "xla", "pallas"),
                  f"attn_impl={attn_impl!r}"),
                 (attn_impl == "pallas" and self._kv_attn == "gather",
                  "attn_impl='pallas' with kv_attn='gather' (the paged "
@@ -1134,6 +1143,16 @@ class ContinuousBatcher:
         paged_attn_fn = None
         from nnstreamer_tpu.ops.dispatch import record as _record_dispatch
 
+        if not attn_impl:
+            # unset: the block-table kernel where it is the measured
+            # fast path (PERF.md, PR 26) — block-native paged decode on
+            # a TPU backend — subject to the registry gate below; the
+            # XLA formulation everywhere else
+            attn_impl = (
+                "pallas"
+                if self._kv_attn == "block" and jax.default_backend() == "tpu"
+                else "xla"
+            )
         if attn_impl == "pallas":
             # registry dtype/env gate (_compat.pallas_ok): a request the
             # kernels can't serve degrades to the XLA step with a logged
@@ -3261,6 +3280,20 @@ class ContinuousBatcher:
             self._tables_dirty = False
         return self._tables_dev
 
+    def _live_blocks_locked(self) -> int:
+        """Arena blocks the next decode step's attention must read: the
+        sum over active slots of ceil(fill / block_size), from the host's
+        own count of each slot's history — what a launch's attention
+        time should go with, whatever the tables' reach (the
+        ``live_blocks`` attribute of ``nns.pump.launch``). Caller holds
+        _lock."""
+        bs = self.block_size
+        return sum(
+            -(-(req.fill0 + len(req.tokens) - 1) // bs)
+            for s, req in enumerate(self._slots)
+            if req is not None and self._active[s]
+        )
+
     def _note_gather_dispatch_locked(self) -> None:
         """Count a paged step/pump/spec launch that ran the
         gather→contiguous-view→scatter oracle (``kv_attn="gather"``)
@@ -3305,8 +3338,10 @@ class ContinuousBatcher:
                     for s, req in enumerate(self._slots)
                 )
                 budget_dev, stop_dev, active_dev = self._pump_state_locked()
+                live_blocks = 0
                 if self._paged:
                     self._note_gather_dispatch_locked()
+                    live_blocks = self._live_blocks_locked()
                     args = (
                         self._tok, self._pos, active_dev, self._cache,
                         self._tables_device_locked(), self._hist,
@@ -3324,7 +3359,8 @@ class ContinuousBatcher:
             fn = self._pump_sampling if sampling else self._pump_greedy
             try:
                 with _trace.span("nns.pump.launch",
-                                 active=int(active_np.sum())):
+                                 active=int(active_np.sum()),
+                                 live_blocks=live_blocks):
                     if self._paged:
                         emits, tok, pos, act, cache, hist, budget = fn(
                             *args, n_steps=int(n)
@@ -3855,6 +3891,10 @@ class ContinuousBatcher:
                 # materialized-view oracle) and how many launches paid
                 # the gather round trip — 0 forever under kv_attn=block
                 st["kv_attn"] = self._kv_attn
+                # the decode attention that serves it, as resolved at
+                # construction (an unset attn_impl and a registry
+                # refusal both land here): "pallas" | "xla"
+                st["attn_impl"] = self._attn_impl
                 st["kv_gather_dispatches"] = self._n_gather_dispatch
                 st["kv_migrations_out"] = self._n_migrations_out
                 st["kv_migrations_in"] = self._n_migrations_in

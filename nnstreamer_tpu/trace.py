@@ -390,7 +390,9 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     "nns.pump.launch": (
         "batcher",
         "the call of the jitted decode program (dispatch only)",
-        "active (slots live at the launch)",
+        "active (slots live at the launch), live_blocks (paged: arena "
+        "blocks its attention reads per step and layer, the sum over "
+        "active slots of ceil(fill / block-size); 0 under the slot layout)",
     ),
     "nns.pump.wait": (
         "batcher",

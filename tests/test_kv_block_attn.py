@@ -227,6 +227,30 @@ def test_kernel_interpret_parity_int8_scales():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def _paged_spec():
+    from nnstreamer_tpu.ops.pallas import paged_attention  # noqa: F401
+    from nnstreamer_tpu.ops.pallas import registry
+
+    return registry.get("paged_decode_attention")
+
+
+@pytest.mark.parametrize(
+    "case", [c.name for c in _paged_spec().tier1_cases()]
+)
+def test_kernel_registry_tier1_case(case):
+    """Every tier-1 ``ShapeCase`` the kernel registers, against
+    ``paged_attention_ref`` in interpret mode: the chunked grid (several
+    blocks a grid step, a ragged last chunk), fills 0 / part of a block
+    / a chunk's edge / the whole table, a finished lane whose stale
+    table points at NaN blocks, 4 queries a KV head over 8 KV heads,
+    int8 scales, a layer of a whole arena leaf."""
+    spec = _paged_spec()
+    params = next(c.params for c in spec.cases if c.name == case)
+    got, want, atol = spec.run_case(dict(params))
+    assert not np.isnan(np.asarray(got)).any()
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=atol)
+
+
 def test_block_attention_impl_dispatch():
     from nnstreamer_tpu.kv import block_attn as kvb
 
@@ -240,6 +264,99 @@ def test_block_attention_impl_dispatch():
     )
     with pytest.raises(ValueError, match="impl"):
         kvb.block_attention(q, ck, cv, tables, pos, (fk, fv), impl="cuda")
+
+
+# -- the kernel behind the batcher, and the default rule ---------------------
+
+def test_pump_stream_pallas_equals_xla(params):
+    """One greedy batch through ``step_pump(8)``: the block-table kernel
+    (interpret mode here; the arena leaf whole, the layer index scanned,
+    fill 0 on lanes that finish mid-pump) emits the XLA view path's
+    tokens. Three requests on three slots of four: one lane stays
+    inactive with a zero table from the first launch on, one finishes
+    inside a pump and idles out while the others run."""
+    subs = [(_prompt(5, 11), 12), (_prompt(16, 12), 19), (_prompt(9, 13), 3)]
+    streams = {}
+    for impl in ("xla", "pallas"):
+        cb = _mk(params, n_slots=4, attn_impl=impl)
+        assert cb.stats()["attn_impl"] == impl
+        rids = [cb.submit(p, n) for p, n in subs]
+        while any(cb.result(r) is None for r in rids):
+            cb.step_pump(8)
+        streams[impl] = [cb.result(r) for r in rids]
+    assert streams["pallas"] == streams["xla"]
+    assert [len(t) for t in streams["xla"]] == [12, 19, 3]
+
+
+@pytest.mark.parametrize(
+    "backend,kw,want",
+    [
+        ("tpu", dict(), "pallas"),
+        ("cpu", dict(), "xla"),
+        ("tpu", dict(attn_impl="xla"), "xla"),
+        ("cpu", dict(attn_impl="pallas"), "pallas"),
+        ("tpu", dict(kv_attn="gather"), "xla"),
+        ("tpu", dict(kv_layout="slot", block_size=16), "xla"),
+        ("tpu", dict(cache_dtype="int8"), "pallas"),
+    ],
+    ids=["tpu-unset", "cpu-unset", "tpu-xla", "cpu-pallas", "tpu-gather",
+         "tpu-slot", "tpu-int8"],
+)
+def test_default_attn_impl_rule(params, monkeypatch, backend, kw, want):
+    """``attn_impl`` unset resolves by the rule of
+    ``block_attention(impl="auto")``: the block-table kernel for the
+    block-native paged layout where ``jax.default_backend()`` is a TPU
+    and the registry passes the arena dtype, the XLA formulation
+    everywhere else; an explicit value keeps its meaning. The backend is
+    steered HERE (construction traces and compiles nothing), not through
+    an option of the program."""
+    from nnstreamer_tpu.ops import dispatch
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    before = dispatch.tally.snapshot().get(("serving_attention", want), 0)
+    cb = _mk(params, **kw)
+    assert cb._attn_impl == want
+    if kw.get("kv_layout") != "slot":
+        assert cb.stats()["attn_impl"] == want
+    after = dispatch.tally.snapshot()[("serving_attention", want)]
+    assert after == before + 1
+
+
+def test_default_rule_honours_registry_gate(params, monkeypatch):
+    """On a TPU the unset default still asks the registry: with the
+    process-wide fallback drill set it degrades to XLA, as an explicit
+    ``pallas`` request does."""
+    from nnstreamer_tpu.ops.pallas._compat import DISABLE_ENV
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert _mk(params).stats()["attn_impl"] == "pallas"
+    monkeypatch.setenv(DISABLE_ENV, "1")
+    assert _mk(params).stats()["attn_impl"] == "xla"
+
+
+def test_launch_span_counts_live_blocks(params):
+    """``nns.pump.launch`` carries ``live_blocks``: the sum over active
+    slots of ceil(fill / block_size) from the host's own positions."""
+    from nnstreamer_tpu import trace as nns_trace
+
+    cb = _mk(params, n_slots=4)
+    seen = []
+    real = nns_trace.span
+
+    def spy(name, **attrs):
+        if name == "nns.pump.launch":
+            seen.append(attrs)
+        return real(name, **attrs)
+
+    rids = [cb.submit(_prompt(5, 21), 20), cb.submit(_prompt(16, 22), 20)]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(nns_trace, "span", spy)
+        while any(cb.result(r) is None for r in rids):
+            cb.step_pump(8)
+    # one admission a pump, 8 tokens a launch: fills (5), (13, 16),
+    # (21, 24), then the second request alone at 32
+    assert [a["live_blocks"] for a in seen] == [1, 1 + 1, 2 + 2, 2]
+    assert [a["active"] for a in seen] == [1, 2, 2, 1]
 
 
 # -- configuration / lint ---------------------------------------------------

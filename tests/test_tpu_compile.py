@@ -21,7 +21,9 @@ collects the same tests and only the one that is handed this file loads
 the library.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -140,6 +142,103 @@ def test_paged_attention(one_chip, h, d, variant):
             one_chip, q, arena, arena, tables, pos, fresh, fresh,
         )
     _assert_kernel(text)
+
+
+#: the benchmark's cells (BENCHMARK.json): slots, heads, KV heads,
+#: layers, arena blocks, model width, FFN width, vocabulary; 64 table
+#: entries of 16 tokens, head dim 128, float32
+CELLS = {
+    "olmo-1b": (16, 16, 16, 16, 1025, 2048, 8192, 50304),
+    "mistral-7b": (32, 32, 8, 8, 2049, 4096, 14336, 32000),
+}
+NB, BS, HD = 64, 16, 128
+
+
+def _big_moves(text, floor, tail):
+    """Result shapes of every ``copy`` / ``dynamic-slice`` (and their
+    async starts) in a compiled text that is arena-shaped (its dims end
+    with ``tail``: block size, KV heads, head dim) and holds at least
+    ``floor`` elements."""
+    found = []
+    for m in re.finditer(
+        r"= \(?\w+\[([\d,]+)\][^=\n]*? (copy|copy-start|dynamic-slice)\(",
+        text,
+    ):
+        n = math.prod(int(x) for x in m.group(1).split(","))
+        if n >= floor and m.group(1).endswith(tail):
+            found.append((m.group(2), m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_paged_attention_cell_shapes(one_chip, cell):
+    """The kernel as the cells launch it: the arena leaves whole, the
+    layer a traced scalar."""
+    from nnstreamer_tpu.ops.pallas.paged_attention import (
+        paged_decode_attention,
+    )
+
+    b, h, kv, layers, n, *_ = CELLS[cell]
+    arena = ((layers, n, BS, kv, HD), f32)
+    q, fresh = ((b, 1, h, HD), f32), ((b, 1, kv, HD), f32)
+    text = _compile(
+        lambda q, k, v, t, p, fk, fv, li: paged_decode_attention(
+            q, k, v, t, p, fk, fv, layer=li
+        ),
+        one_chip, q, arena, arena, ((b, NB), i32), ((b,), i32), fresh,
+        fresh, ((), i32),
+    )
+    _assert_kernel(text)
+    assert not _big_moves(text, n * BS * kv * HD, f",{BS},{kv},{HD}")
+
+
+@pytest.mark.parametrize("cell", list(CELLS))
+def test_paged_decode_step_cell_shapes(one_chip, cell):
+    """The decode program around the kernel (two steps of the pump's
+    scan over ``batched_decode_step_block``, arena donated): the kernel
+    is in it, and nothing the size of ONE layer's arena leaf (134 MB) is
+    copied or sliced anywhere — the layer scan feeds the kernel the
+    whole leaf and an index."""
+    from nnstreamer_tpu.kv.block_attn import batched_decode_step_block
+    from nnstreamer_tpu.models import transformer as tfm
+    from nnstreamer_tpu.ops.pallas.paged_attention import (
+        make_paged_attention,
+    )
+
+    b, h, kv, layers, n, d_model, d_ff, vocab = CELLS[cell]
+    attn = make_paged_attention(interpret=False)
+
+    def pump(params, tok, pos, active, ak, av, tables):
+        def body(carry, _):
+            tok, pos, arena = carry
+            logits, arena, pos = batched_decode_step_block(
+                params, tok, pos, active, arena, tables, h, attn_fn=attn
+            )
+            return (jnp.argmax(logits, -1).astype(i32), pos, arena), tok
+
+        return jax.lax.scan(body, (tok, pos, (ak, av)), None, length=2)
+
+    params = jax.eval_shape(
+        lambda: tfm.init_params(
+            jax.random.PRNGKey(0), vocab, d_model, h, layers, d_ff=d_ff,
+            n_kv_heads=kv,
+        )
+    )
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        (
+            params,
+            *(jax.ShapeDtypeStruct(s, d) for s, d in (
+                ((b,), i32), ((b,), i32), ((b,), jnp.bool_),
+                ((layers, n, BS, kv, HD), f32),
+                ((layers, n, BS, kv, HD), f32), ((b, NB), i32),
+            )),
+        ),
+    )
+    text = jax.jit(pump, donate_argnums=(4, 5)).lower(*args).compile(
+    ).as_text()
+    _assert_kernel(text)
+    assert not _big_moves(text, n * BS * kv * HD, f",{BS},{kv},{HD}")
 
 
 def test_nms_ssd_anchors(one_chip):
