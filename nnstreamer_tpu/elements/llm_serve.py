@@ -195,7 +195,7 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
     cb = ContinuousBatcher(
         m.params, n_heads, n_slots=n_slots, max_len=max_len,
         prompt_len=prompt_len, cache_dtype=cache_dtype,
-        attn_impl=attn_impl,
+        attn_impl=attn_impl, family=m.family,
         **kv_kw, **draft_kw,
     )
     reg = _obs_metrics.get()
@@ -358,6 +358,24 @@ class _LlmServer:
                 speculate_model, kv_layout, block_size, kv_blocks,
                 cache_dtype, prefill_chunks, kv_attn, attn_impl,
             )
+        # properties the served block family does not carry refuse here,
+        # by name (models/family.py): no silent fallback, no half path
+        unsupported = self.cb._family.unsupported
+        for prop, feature, on in (
+            ("speculate", "speculate", bool(speculate)),
+            ("speculate-model", "draft model", bool(speculate_model)),
+            ("role", "migration", bool(role)),
+            ("decode-peers", "migration", bool(decode_peers)),
+            ("migrate-to", "migration", bool(migrate_to)),
+            ("checkpoint-every-tokens", "snapshot",
+             bool(checkpoint_every_tokens)),
+            ("checkpoint-dir", "snapshot", bool(checkpoint_dir)),
+        ):
+            if on and feature in unsupported:
+                raise ElementError(
+                    f"tensor_llm_serversink: {prop} is not supported by "
+                    f"the {self.cb._family.name} block family ({model})"
+                )
         # until the first token of any request: what is left of set-up
         # once the batcher exists (nns_llm_setup_seconds{first_token})
         self._t_built: Optional[float] = _time.perf_counter()

@@ -84,7 +84,7 @@ def _take_layer(layer, tables):
 
 
 def write_fresh_window(arena, tables, fresh, pos, width: int, active,
-                       quantized: bool):
+                       quantized: bool, per_layer: bool = False):
     """Land freshly computed K/V straight into its owning arena blocks.
 
     ``fresh`` holds the per-layer stacked chunk values —
@@ -97,7 +97,14 @@ def write_fresh_window(arena, tables, fresh, pos, width: int, active,
     scratch block 0 and write its init values (zero payload, unit
     scales), so scratch stays pristine; active lanes' windows lie in
     privately-owned blocks (copy-on-write discipline), so shared blocks
-    are untouched by construction."""
+    are untouched by construction.
+
+    ``per_layer`` lands each cache layer with a scatter of its own. A
+    program that READS single layers of a whole leaf (``leaf[li, tables]``,
+    the latent family's unrolled step) needs it: one scatter across the
+    layer dim made the TPU compiler carry the arena layer-minor through
+    the pump's loop and copy the whole leaf back to layer-major at every
+    step (3.2 + 0.8 ms a step on a 1.3 GB arena, PERF.md section 6, PR 27)."""
     first = arena[0][0] if quantized else arena[0]
     bs = first.shape[2]
     nb = tables.shape[1]
@@ -117,6 +124,10 @@ def write_fresh_window(arena, tables, fresh, pos, width: int, active,
         keep = valid.reshape((1, -1) + (1,) * (rows.ndim - 2))
         rows = jnp.where(keep, rows.astype(a.dtype),
                          jnp.asarray(fill, a.dtype))
+        if per_layer:
+            for li in range(a.shape[0]):
+                a = a.at[li, phys, off].set(rows[li])
+            return a
         return a.at[:, phys, off].set(rows)
 
     if quantized:

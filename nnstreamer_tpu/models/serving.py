@@ -1040,6 +1040,7 @@ class ContinuousBatcher:
         kv_blocks: Optional[int] = None,
         prefill_chunks: int = 1,
         kv_attn: str = "auto",
+        family=None,
     ):
         """``windowed=True`` makes max_len a sliding attention window
         over a ring-buffer cache: generations AND prompts of any length
@@ -1081,8 +1082,28 @@ class ContinuousBatcher:
         debugging/parity at the cost of a transient HBM doubling.
         Both are bitwise identical to the slot layout. Paged composes
         with ``attn_impl="pallas"`` via the block-table kernel
-        (ops/pallas/paged_attention.py) — block-native only."""
+        (ops/pallas/paged_attention.py) — block-native only.
+
+        ``family`` is the block family the paged path serves
+        (models/family.py): what a token leaves in the cache and how a
+        step computes. Unset is the dense block of models/transformer.py
+        (``params`` + ``n_heads``); another family brings its own arena
+        leaves, prefill and decode programs, and refuses by name what it
+        does not carry."""
         ensure_compile_cache()
+        from nnstreamer_tpu.models.family import DenseFamily, refuse_unsupported
+
+        if family is None:
+            family = DenseFamily(params, n_heads, prompt_len, compute_dtype)
+        refuse_unsupported(family, {
+            "kv-layout=slot": kv_layout != "paged",
+            "cache-dtype=int8": cache_dtype == "int8",
+            "windowed": windowed,
+            "mesh": mesh is not None,
+            "draft model": draft_params is not None,
+            "kv-attn=gather": kv_attn == "gather",
+        })
+        self._family = family
         if prompt_len > max_len:
             raise ValueError("prompt_len must be ≤ max_len")
         if cache_dtype not in ("auto", "int8"):
@@ -1151,6 +1172,7 @@ class ContinuousBatcher:
             attn_impl = (
                 "pallas"
                 if self._kv_attn == "block" and jax.default_backend() == "tpu"
+                and family.decode_kernel is not None
                 else "xla"
             )
         if attn_impl == "pallas":
@@ -1160,12 +1182,12 @@ class ContinuousBatcher:
             from nnstreamer_tpu.ops.pallas._compat import pallas_ok
 
             kernel = (
-                "paged_decode_attention" if self._paged
+                family.decode_kernel if self._paged
                 else "decode_attention"
             )
-            ok, _ = pallas_ok(
-                kernel, "int8" if quantized_cache else compute_dtype
-            )
+            ok = kernel is not None and pallas_ok(
+                kernel, "int8" if quantized_cache else family.dtype
+            )[0]
             if not ok:
                 attn_impl = "xla"
         _record_dispatch(
@@ -1179,11 +1201,7 @@ class ContinuousBatcher:
                 # gathered view (ops/pallas/paged_attention.py); the
                 # spec verify keeps inline XLA attention exactly like
                 # the slot layout's Pallas batchers
-                from nnstreamer_tpu.ops.pallas.paged_attention import (
-                    make_paged_attention,
-                )
-
-                paged_attn_fn = make_paged_attention()
+                paged_attn_fn = family.make_attention()
                 attn_fn = None
             else:
                 from nnstreamer_tpu.ops.pallas.decode_attention import (
@@ -1225,10 +1243,10 @@ class ContinuousBatcher:
 
         self._slo = SLOLedger(keep=keep_results, obs_registry=self._obs_reg)
 
-        L, d = params["blocks"]["ln1"].shape
-        hd = d // n_heads
-        kv = tfm.n_kv_heads_of(params["blocks"]["wqkv"], d, n_heads)
-        shape = (L, n_slots, max_len, kv, hd)
+        dense = isinstance(family, DenseFamily)
+        if dense:
+            L, hd, kv = family.n_layers, family.head_dim, family.n_kv_heads
+            shape = (L, n_slots, max_len, kv, hd)
         if self._paged:
             from nnstreamer_tpu.kv import block_attn as _kvb
             from nnstreamer_tpu.kv import gather as _kvg
@@ -1253,9 +1271,8 @@ class ContinuousBatcher:
             )
             # self._cache IS the block arena in paged mode: every
             # donated-launch/commit/failure-latch path stays identical
-            self._cache = _kvg.init_arena(
-                L, int(kv_blocks), block_size, kv, hd, quantized_cache,
-                compute_dtype,
+            self._cache = family.arena(
+                int(kv_blocks), block_size, quantized_cache
             )
             self._tables = np.zeros(
                 (n_slots, self._blocks_per_slot), np.int32
@@ -1372,11 +1389,10 @@ class ContinuousBatcher:
         def wjit(fn, **kw):
             return _weights_jit(fn, weights, **kw)
 
+        # the prompt and chunk programs are the family's (the dense family's
+        # are dec.prefill / dec.verify_chunk, as ever)
         self._prefill = wjit(
-            lambda w, toks: dec.prefill(
-                w[0], toks, n_heads, prompt_len,
-                compute_dtype=compute_dtype,
-            ),
+            lambda w, toks: family.prefill(w[0], toks),
             name="nns_prefill",
         )
         # chunked-prefill programs (prompts longer than the bucket): a
@@ -1384,7 +1400,6 @@ class ContinuousBatcher:
         # bucket so chunk starts NOT aligned to the bucket (the prefix-
         # caching path) still fit their full-width writes
         self._stage_len = (-(-max_len // prompt_len) + 1) * prompt_len
-        self._stage_shape = (L, 1, self._stage_len, kv, hd)
         if self._paged:
             # coalesced admission staging (kv/gather.make_staging_ops):
             # prefix seeding and block landing as ONE program each —
@@ -1394,22 +1409,18 @@ class ContinuousBatcher:
                 self._kvg.make_staging_ops(quantized_cache, compute_dtype)
             )
         self._prefill_chunk = wjit(
-            lambda w, toks, cpos, cache: dec.verify_chunk(
-                w[0], toks, cpos, cache, n_heads,
-                compute_dtype=compute_dtype,
-            ),
+            lambda w, toks, cpos, cache: family.chunk(w[0], toks, cpos, cache),
             donate_argnums=2, name="nns_prefill_chunk",
         )
         self._advance_chunk = wjit(
-            lambda w, toks, cpos, cache: dec.verify_chunk(
-                w[0], toks, cpos, cache, n_heads,
-                compute_dtype=compute_dtype, return_logits=False,
+            lambda w, toks, cpos, cache: family.chunk(
+                w[0], toks, cpos, cache, return_logits=False,
             )[1],
             donate_argnums=2, name="nns_prefill_chunk_nologits",
         )
         # windowed (ring) chunked-prefill programs: exact sliding-window
         # prefill for prompts of ANY length in the fixed W ring
-        self._ring_shape = (L, 1, max_len, kv, hd)
+        self._ring_shape = (L, 1, max_len, kv, hd) if dense else None
         self._wchunk = wjit(
             lambda w, toks, cpos, n, cache: dec.windowed_chunk(
                 w[0], toks, cpos, n, cache, n_heads,
@@ -1501,9 +1512,9 @@ class ContinuousBatcher:
             def block_step(sampling):
                 def impl(w, tok, pos, active, arena, tables, hist, temp,
                          topk, topp, keys):
-                    logits, arena, pos2 = _kvb.batched_decode_step_block(
+                    logits, arena, pos2, _ = family.decode_step(
                         w[0], tok, pos, active, arena, tables,
-                        n_heads, compute_dtype, attn_fn=_pg_attn,
+                        attn_fn=_pg_attn,
                     )
                     with jax.named_scope("nns.sample"):
                         if sampling:
@@ -1631,6 +1642,7 @@ class ContinuousBatcher:
 
                     def body(carry, _):
                         tok, pos, active, arena, hist, budget = carry
+                        aux = None
                         if _gather_pump:
                             view = _kvg.gather_cache(arena, tables)
                             logits, view, pos2 = batched_decode_step(
@@ -1638,12 +1650,9 @@ class ContinuousBatcher:
                                 compute_dtype, attn_fn=attn_fn,
                             )
                         else:
-                            logits, arena, pos2 = (
-                                _kvb.batched_decode_step_block(
-                                    params, tok, pos, active, arena,
-                                    tables, n_heads, compute_dtype,
-                                    attn_fn=_pg_attn,
-                                )
+                            logits, arena, pos2, aux = family.decode_step(
+                                params, tok, pos, active, arena, tables,
+                                attn_fn=_pg_attn,
                             )
                         with jax.named_scope("nns.sample"):
                             if sampling:
@@ -1669,13 +1678,21 @@ class ContinuousBatcher:
                         )
                         return (
                             new, pos2, active, arena, hist, budget,
-                        ), emit
+                        ), (emit if aux is None else (emit, aux))
 
                     carry, emits = jax.lax.scan(
                         body, (tok, pos, active, arena, hist, budget),
                         None, length=n_steps,
                     )
                     tok, pos, active, arena, hist, budget = carry
+                    if family.aux_names:
+                        # the family's counters ride the one readback:
+                        # [B * n tokens ‖ counters summed over the steps]
+                        emits, aux = emits
+                        emits = jnp.concatenate(
+                            [emits.T.reshape(-1), jnp.sum(aux, axis=0)]
+                        )
+                        return emits, tok, pos, active, arena, hist, budget
                     return emits.T, tok, pos, active, arena, hist, budget
 
                 return impl
@@ -2022,12 +2039,10 @@ class ContinuousBatcher:
         # tests/test_kv_block_attn.py); mirrored to the
         # nns_kv_gather_dispatch_total obs counter
         self._n_gather_dispatch = 0
+        self._aux_totals: Dict[str, int] = {}
 
     def _empty_stage(self):
-        return (
-            jnp.zeros(self._stage_shape, self.compute_dtype),
-            jnp.zeros(self._stage_shape, self.compute_dtype),
-        )
+        return self._family.stage(self._stage_len)
 
     def _chunk_step(self, tokens, pos: int, stage, want_logits: bool):
         """ONE prompt_len bucket of chunked prefill at absolute ``pos``.
@@ -2039,7 +2054,7 @@ class ContinuousBatcher:
         Returns (logits or None, advanced stage, tokens consumed)."""
         P = self.prompt_len
         n = min(P, int(tokens.shape[0]))
-        chunk = np.zeros((1, P), np.int32)
+        chunk = np.full((1, P), self._family.pad_id, np.int32)
         chunk[0, :n] = tokens[:n]
         args = (jnp.asarray(chunk), jnp.asarray(pos, jnp.int32), stage)
         if want_logits:
@@ -2627,7 +2642,7 @@ class ContinuousBatcher:
                 # bucket-sized fresh prompt: the SAME single fast-path
                 # program the slot layout admits through (bitwise parity
                 # with contiguous admission)
-                padded = np.zeros((1, P), np.int32)
+                padded = np.full((1, P), self._family.pad_id, np.int32)
                 padded[0, :t] = ctx
                 logits, (ks, vs), _ = self._prefill(jnp.asarray(padded))
                 job.logits_row = logits[0, t - 1]
@@ -2873,6 +2888,7 @@ class ContinuousBatcher:
                 "request migration needs kv_layout='paged'"
             )
         self._check_failed()
+        self._refuse("migration")
         with self._step_lock:
             self._apply_pending()
             with self._lock:
@@ -2991,6 +3007,7 @@ class ContinuousBatcher:
                 "request migration needs kv_layout='paged'"
             )
         self._check_failed()
+        self._refuse("migration")
         bs = self.block_size
         if span.block_size != bs:
             raise SpanFormatError(
@@ -3120,6 +3137,7 @@ class ContinuousBatcher:
                 "request migration needs kv_layout='paged'"
             )
         self._check_failed()
+        self._refuse("migration")
         if span.fill0 + span.budget > self.max_len:
             raise SpanCapacityError(
                 f"span needs fill0+budget={span.fill0 + span.budget} "
@@ -3176,6 +3194,12 @@ class ContinuousBatcher:
         if self._failed is None:
             self._failed = exc
 
+    def _refuse(self, feature: str) -> None:
+        """A feature the batcher's family does not carry refuses by name."""
+        from nnstreamer_tpu.models.family import refuse_unsupported
+
+        refuse_unsupported(self._family, {feature: True})
+
     def _check_failed(self) -> None:
         if self._failed is not None:
             raise BatcherFailedError(
@@ -3193,6 +3217,10 @@ class ContinuousBatcher:
         steppers. Slots admitted while a step is in flight join at the
         next step."""
         self._check_failed()
+        if self._family.aux_names:
+            # a family whose step carries counters home has one decode
+            # program, the pump: a single step is a pump of one
+            return {rid: t[0] for rid, t in self.step_pump(1).items()}
         with self._pump_span(1), self._step_lock:
             return self._plain_step_locked()
 
@@ -3371,6 +3399,12 @@ class ContinuousBatcher:
                          dcache) = fn(*args, n_steps=int(n))
                 with _trace.span("nns.pump.wait"):
                     emits_np = np.asarray(emits)  # ONE [B, n] transfer
+                aux_np = None
+                if self._family.aux_names:
+                    aux_np = emits_np[self.n_slots * int(n):]
+                    emits_np = emits_np[: self.n_slots * int(n)].reshape(
+                        self.n_slots, int(n)
+                    )
             except Exception as exc:
                 # the launch donated _cache/_hist (and the draft cache):
                 # a raise here leaves them consumed — latch the failure
@@ -3393,7 +3427,18 @@ class ContinuousBatcher:
                 )
                 self._n_steps += int(n)
                 self._n_tokens += n_em
+                if aux_np is not None:
+                    self._note_aux_locked(aux_np)
                 return out
+
+    def _note_aux_locked(self, aux_np) -> None:
+        """One harvested pump's family counters (``aux_names``, summed on
+        the device over the pump's steps and layers): totals for stats(),
+        and the family's own metrics and trace instant."""
+        got = {k: int(v) for k, v in zip(self._family.aux_names, aux_np)}
+        for k, v in got.items():
+            self._aux_totals[k] = self._aux_totals.get(k, 0) + v
+        self._family.note_aux(got, self._obs_reg)
 
     def spec_pump(
         self, rounds: int = 8, k: int = 4, ngram: int = 2
@@ -3417,6 +3462,7 @@ class ContinuousBatcher:
         its own XLA program — quantization bounds the program variants
         to log2(rounds) instead of one per tail length."""
         self._check_failed()
+        self._refuse("speculate")
         k = max(2, int(k))
         if self._draft is not None and self.windowed:
             return self._spec_fallback_rounds(int(rounds), k, ngram)
@@ -3693,6 +3739,7 @@ class ContinuousBatcher:
         same inline-attention math). Returns {rid: last emitted token};
         use partials() for the full per-round stream."""
         self._check_failed()
+        self._refuse("speculate")
         with self._step_lock:
             if self._paged:
                 self._advance_prefill()
@@ -3900,6 +3947,9 @@ class ContinuousBatcher:
                 st["kv_migrations_in"] = self._n_migrations_in
                 st["kv_prefill_chunks"] = self._n_prefill_chunk_programs
                 st["request_resumes"] = self._n_resumes
+            st["family"] = self._family.name
+            for k, v in self._aux_totals.items():
+                st[self._family.aux_prefix + k] = v
             return st
 
     def _pin(self, x):
@@ -3985,6 +4035,7 @@ class ContinuousBatcher:
         drained its prefill queue (pump until ``kv_prefill_queue`` is 0)
         so no half-staged prompt is lost."""
         self._check_failed()
+        self._refuse("snapshot")
         with self._step_lock:
             self._apply_pending()
             with self._lock:
@@ -4073,6 +4124,7 @@ class ContinuousBatcher:
         SAME configuration: decoding continues exactly where the
         snapshot stopped (same streams, same block tables, same prefix
         index — the remembered sharing survives the restart)."""
+        self._refuse("snapshot")
         want = "paged" if self._paged else "slot"
         if snap.get("layout") != want:
             raise ValueError(

@@ -30,6 +30,9 @@ class ZooModel:
     # sharded filters (custom="mesh:dp2tp4") — closed-over params would be
     # baked into the jaxpr as replicated constants, defeating TP sharding
     apply: Optional[Callable] = None
+    # the block family tensor_llm_serversink's paged batcher serves the
+    # model through (models/family.py); None is the dense transformer block
+    family: Optional[object] = None
 
 
 _FACTORIES: Dict[str, Callable[..., ZooModel]] = {}
@@ -509,6 +512,32 @@ def _transformer_lm(**options) -> ZooModel:
         TensorSpec((batch, seqlen), DType.from_any("int32"), name="tokens")
     )
     return ZooModel("transformer_lm", fn, spec, params, apply_fn)
+
+
+@model_factory("longcat_flash_lm")
+def _longcat_flash_lm(**options) -> ZooModel:
+    """LongCat-Flash (models/longcat.py): latent attention, the
+    shortcut-connected double layer, one chip's share of the routed experts
+    with identity experts. ``custom=`` gives the widths by their short names
+    (published by default), ``n_layers``, ``experts_held``, ``expert_offset``,
+    ``vocab``, ``seed`` and the storage ``dtype`` (bfloat16 by default:
+    weights drawn in float32 from the seed and rounded once).
+    fn: int32 tokens [B,T] -> logits [B,T,V]."""
+    from nnstreamer_tpu.models import longcat
+
+    cfg = longcat.config_from_options(options)
+    dtype = jnp.dtype(options.get("dtype", "bfloat16"))
+    params = longcat.init_params(cfg, int(options.get("seed", 0)), dtype)
+
+    def apply_fn(p, tokens):
+        return longcat.apply(p, tokens, cfg)
+
+    spec = TensorsSpec.of(TensorSpec(
+        (int(options.get("batch", 1)), int(options.get("seqlen", 128))),
+        DType.from_any("int32"), name="tokens"))
+    return ZooModel("longcat_flash_lm", lambda tokens: apply_fn(params, tokens),
+                    spec, params, apply_fn,
+                    family=longcat.LongcatFamily(cfg, dtype))
 
 
 @model_factory("vit")

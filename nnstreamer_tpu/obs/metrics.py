@@ -147,6 +147,24 @@ METRIC_CATALOG: Dict[str, str] = {
         "of re-prefilled — shared system prompts count once, not per "
         "request (counter; docs/llm-serving.md)"
     ),
+    "nns_moe_tokens_total": (
+        "token x expert-layer evaluations of live slots in harvested decode "
+        "pumps of a routed-expert family (counter; docs/llm-serving.md)"
+    ),
+    "nns_moe_local_pairs_total": (
+        "(token, expert) pairs that fell on the experts this chip holds, "
+        "summed over expert layers and decode steps (counter)"
+    ),
+    "nns_moe_experts_hit_total": (
+        "distinct held experts with at least one token, summed over expert "
+        "layers and decode steps: the expert weights a step streamed (counter)"
+    ),
+    "nns_moe_zero_picks_total": (
+        "router picks that chose an identity (zero-compute) expert (counter)"
+    ),
+    "nns_moe_picks_total": (
+        "router picks of live tokens: tokens x top-k (counter)"
+    ),
     "nns_kv_gather_dispatch_total": (
         "paged step/pump/spec launches that ran the gather→contiguous-"
         "view→scatter oracle (kv_attn=gather) instead of the "

@@ -58,6 +58,7 @@ KNOWN_OPS = (
     "crop_and_resize",
     "decode_attention",
     "flash_attention",
+    "mla_attention",
     "nms",
     "resize_bilinear",
     "serving_attention",

@@ -21,6 +21,9 @@ from nnstreamer_tpu.ops.pallas.image_kernels import (  # noqa: F401
     crop_and_resize,
     resize_bilinear,
 )
+from nnstreamer_tpu.ops.pallas.mla_attention import (  # noqa: F401
+    mla_paged_decode_attention,
+)
 from nnstreamer_tpu.ops.pallas.nms import nms  # noqa: F401
 from nnstreamer_tpu.ops.pallas.paged_attention import (  # noqa: F401
     paged_decode_attention,

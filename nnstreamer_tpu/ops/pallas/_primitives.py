@@ -48,20 +48,23 @@ def online_softmax_init(m_ref, l_ref, acc_ref):
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
-def online_softmax_update(s, v, m_prev, l_prev, acc_prev):
+def online_softmax_update(s, v, m_prev, l_prev, acc_prev, p_dtype=None):
     """One block of the online-softmax recurrence.
 
     s [m, n] f32 scores, v [n, d] f32 values; (m_prev [m], l_prev [m],
     acc_prev [m, d]) the running (max, denominator, accumulator) →
     the updated triple. The ``<= NEG_INF`` guards pin fully-masked
-    prefixes to weight exactly zero (exp(NEG_INF - NEG_INF) would be 1)."""
+    prefixes to weight exactly zero (exp(NEG_INF - NEG_INF) would be 1).
+    ``p_dtype`` rounds the weights to the values' storage dtype for the
+    p·V contraction (a bfloat16 cache: one MXU pass, float32 accumulation)."""
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
     alpha = jnp.where(m_prev <= NEG_INF, 0.0, jnp.exp(m_prev - m_new))
     m2 = m_new[:, None]
     p = jnp.where(m2 <= NEG_INF, 0.0, jnp.exp(s - m2))
     l_new = l_prev * alpha + jnp.sum(p, axis=1)
     acc_new = acc_prev * alpha[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p if p_dtype is None else p.astype(p_dtype), v,
+        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     return m_new, l_new, acc_new
 

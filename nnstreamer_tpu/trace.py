@@ -405,6 +405,17 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
         "commit of the carried state and _harvest_rows_locked, to return",
         "",
     ),
+    "nns.moe.routing": (
+        "expert layer",
+        "instant per harvested decode pump of a routed-expert family "
+        "(models/longcat.py): the router's counters, summed on the device "
+        "over the pump's steps and expert layers and carried home by the "
+        "pump's one readback",
+        "tokens (live token x layer evaluations), local_pairs (pairs on the "
+        "experts held here), experts_hit (distinct held experts with a "
+        "token, per layer and step), zero_picks (identity experts chosen), "
+        "picks (tokens x top-k)",
+    ),
     "nns.req.submit": (
         "batcher",
         "instant: SLOLedger.submit, the request has a slot and a record",

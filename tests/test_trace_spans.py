@@ -98,7 +98,10 @@ def traced(tmp_path_factory):
     return events, modules, streams
 
 
-@pytest.mark.parametrize("name", sorted(trace.SPAN_CATALOG))
+# ``nns.moe.routing`` is written by the routed-expert family only; this run
+# serves the dense block (tests/test_longcat.py traces the other)
+@pytest.mark.parametrize(
+    "name", sorted(set(trace.SPAN_CATALOG) - {"nns.moe.routing"}))
 def test_every_cataloged_span_is_on_the_profilers_timeline(traced, name):
     events, _, _ = traced
     assert any(e["name"] == name for e in events), (
