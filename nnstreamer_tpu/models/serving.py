@@ -801,7 +801,7 @@ class _PendingInsert:
     fill: int  # cache fill level (= absolute position count)
     req: _Request
     draft_kv: Optional[Tuple[jax.Array, jax.Array]] = None
-    hist_row: Optional[np.ndarray] = None  # device n-gram context seed
+    hist_row: np.ndarray = None  # [max_len] device n-gram context seed
     blocks: Optional[List[int]] = None  # paged: the slot's block table
     resumed: bool = False  # paged: re-admission after preemption
 
@@ -837,6 +837,57 @@ def _weights_jit(fn, weights, donate_argnums=(), name=None, **kw):
         fn, donate_argnums=tuple(i + 1 for i in donate_argnums), **kw
     )
     return functools.partial(jitted, weights)
+
+
+# columns of an admission row after the history's ``max_len``: the packed
+# int32 buffer _apply_batch_locked ships (float32 and uint32 words ride as
+# their bits)
+_ADMIT_COLS = 8
+(_ADM_LIVE, _ADM_TOK, _ADM_POS, _ADM_TOPK, _ADM_TEMP, _ADM_TOPP,
+ _ADM_KEY) = range(7)  # _ADM_KEY holds two words
+
+
+def _make_admit(max_len: int, vec_sh=None):
+    """The admission program: every queued request's writes to the seven
+    per-slot arrays (donated) in ONE launch, from ONE packed transfer.
+
+    ``rows`` is int32 ``[n_slots, max_len + _ADMIT_COLS]``: row ``s`` holds
+    what slot ``s`` takes (history row, then the ``_ADM_*`` columns) and
+    counts only where its ``_ADM_LIVE`` column is set, so the shape is the
+    batcher's own whatever the number admitted and nothing compiles after
+    the first admission. ``jit_nns_admit`` on a device trace. On a mesh
+    the arrays come back on ``vec_sh`` (what ``_pin`` does for eager
+    updates)."""
+    H = max_len
+
+    def admit(tok, pos, temp, topk, topp, keys, hist, rows):
+        live = rows[:, H + _ADM_LIVE] != 0
+
+        def col(c, dtype=jnp.int32, n=1):
+            v = rows[:, H + c: H + c + n]
+            if dtype != jnp.int32:
+                v = jax.lax.bitcast_convert_type(v, dtype)
+            return v if n > 1 else v[:, 0]
+
+        def put(old, new):
+            m = live if old.ndim == 1 else live[:, None]
+            return jnp.where(m, new, old)
+
+        return (
+            put(tok, col(_ADM_TOK)), put(pos, col(_ADM_POS)),
+            put(temp, col(_ADM_TEMP, jnp.float32)),
+            put(topk, col(_ADM_TOPK)),
+            put(topp, col(_ADM_TOPP, jnp.float32)),
+            put(keys, col(_ADM_KEY, jnp.uint32, 2)),
+            put(hist, rows[:, :H]),
+        )
+
+    kw = {}
+    if vec_sh is not None:
+        kw = dict(in_shardings=(vec_sh,) * 8, out_shardings=(vec_sh,) * 7)
+    return jax.jit(
+        _named(admit, "nns_admit"), donate_argnums=tuple(range(7)), **kw
+    )
 
 
 class _DraftEngine:
@@ -1739,14 +1790,19 @@ class ContinuousBatcher:
         else:
             self._pump_greedy = wjit(pump_impl(False, _wd), **_pdon)
             self._pump_sampling = wjit(pump_impl(True, _wd), **_pdon)
-        # first-token pick: same device sampler over the prefill logits
+        # first-token pick: same device sampler over the prefill logits.
+        # Its arguments are host (numpy) values shipped by the call, the
+        # request key folded with the position inside the program: no
+        # eager launch builds them
         self._sample1 = jax.jit(_named(
-            lambda logits, temp, topk, topp, key: sample_tokens(
-                logits[None, :], temp, topk, topp, key[None]
+            lambda logits, temp, topk, topp, key, fill: sample_tokens(
+                logits[None, :], temp, topk, topp,
+                jax.random.fold_in(key, fill)[None],
             )[0],
             "nns_sample_first",
         ))
         self._insert = jax.jit(insert_slot, donate_argnums=0)
+        self._admit = _make_admit(max_len, self._vec_sh)
 
         # one speculative round = verify + device-side acceptance (+ ring
         # commit of accepted columns when windowed) in ONE program; jit
@@ -2033,6 +2089,8 @@ class ContinuousBatcher:
         self._n_spec_rounds = 0
         self._n_spec_accepted = 0
         self._n_spec_columns = 0  # proposal columns offered (normalizer)
+        self._n_admit_launches = 0  # launches of the admit program
+        self._n_admitted = 0  # requests it spliced into the slot state
         # step/pump/spec launches that ran the gather/scatter oracle
         # (kv_attn="gather") instead of the block-native formulation —
         # 0 forever on a block-native batcher (the zero-gather pin in
@@ -2368,14 +2426,8 @@ class ContinuousBatcher:
             # the first token stays a DEVICE scalar: materializing it
             # here would cost one device→host read per admission on the
             # submit path; _apply_pending fetches every queued
-            # admission's first token in ONE packed transfer instead
-            first_dev = self._sample1(
-                logits_row,
-                jnp.asarray([temperature], jnp.float32),
-                jnp.asarray([top_k], jnp.int32),
-                jnp.asarray([top_p], jnp.float32),
-                jax.random.fold_in(jnp.asarray(req.key), fill),
-            )
+            # admission's first token in ONE gathered read instead
+            first_dev = self._sample_first(logits_row, req, fill)
             if max_new_tokens == 1:
                 # a one-token request finishes ON its prefill token:
                 # fetch it now so the slot frees immediately (nothing
@@ -2424,7 +2476,7 @@ class ContinuousBatcher:
             # token 0 (and any finished-at-first-token bookkeeping, e.g.
             # a stop token landing on it) materializes at the next
             # _apply_pending, where every queued admission's
-            # first token rides one packed read — submit() itself never
+            # first token rides one gathered read — submit() itself never
             # blocks on the device
             self._pending.append(
                 _PendingInsert(slot, ks, vs, first_dev, fill, req,
@@ -2432,30 +2484,49 @@ class ContinuousBatcher:
             )
         return rid
 
+    def _sample_first(self, logits_row, req: _Request, fill: int):
+        """The request's first token from its prefill's last logits row,
+        as a DEVICE scalar (``_apply_pending`` reads it)."""
+        return self._sample1(
+            logits_row,
+            np.asarray([req.temperature], np.float32),
+            np.asarray([req.top_k], np.int32),
+            np.asarray([req.top_p], np.float32),
+            np.asarray(req.key, np.uint32),
+            np.int32(fill),
+        )
+
     def _apply_pending(self) -> None:
         """Splice queued admissions into the device state.
 
         Caller holds _step_lock ONLY. Every queued admission's first
         token (a device scalar from submit's prefill sampler) is
-        fetched in ONE packed transfer — the admission-path analogue
+        fetched in ONE gathered read (no launch, so nothing compiles
+        whatever the number queued) — the admission-path analogue
         of the pumps' one-readback rule — and that fetch happens
         OUTSIDE self._lock: it may wait on an in-flight chunked
         prefill, and readers (submit/result/partials/stats) must not
         stall behind it."""
-        with _trace.span("nns.pump.admit"):
+        with _trace.span("nns.pump.admit") as sp:
+            admitted = 0
             with self._lock:
                 batch = self._pending
                 self._pending = []
-            if not batch:
-                return
-            firsts = np.asarray(jnp.stack(
-                [jnp.asarray(p.first_tok).reshape(()) for p in batch]
-            )).reshape(-1)
-            with self._lock:
-                self._apply_batch_locked(batch, firsts)
+            if batch:
+                firsts = jax.device_get([p.first_tok for p in batch])
+                with self._lock:
+                    admitted = self._apply_batch_locked(batch, firsts)
+            sp.set(admitted=admitted)
 
-    def _apply_batch_locked(self, batch, firsts) -> None:
+    def _apply_batch_locked(self, batch, firsts) -> int:
+        """Host bookkeeping per queued admission, then ONE launch of the
+        admit program (``_make_admit``) for every row that joins the
+        batch: their per-slot writes ride one packed int32 buffer, row
+        ``slot`` for slot ``slot``. Returns the number of rows applied."""
         self._pump_state_dirty = True  # admission changes pump state
+        H = self.max_len
+        rows = None
+        admitted = 0
         for p, first in zip(batch, firsts):
             if self._slots[p.slot] is not p.req:
                 continue  # request vanished (defensive; cannot happen)
@@ -2480,31 +2551,42 @@ class ContinuousBatcher:
                     continue
             else:
                 self._slo.admitted(p.req.rid)
-            if p.hist_row is not None:
-                Hh = p.hist_row.shape[0]
-                if p.fill < Hh:
-                    p.hist_row[p.fill] = first
-                elif self.windowed:
-                    p.hist_row[p.fill % Hh] = first
+            if p.fill < H:
+                p.hist_row[p.fill] = first
+            elif self.windowed:
+                p.hist_row[p.fill % H] = first
             if p.blocks is None:
                 self._cache = self._insert(self._cache, p.ks, p.vs, p.slot)
-            self._tok = self._pin(self._tok.at[p.slot].set(first))
-            self._pos = self._pin(self._pos.at[p.slot].set(p.fill))
-            self._temp = self._pin(
-                self._temp.at[p.slot].set(p.req.temperature)
-            )
-            self._topk = self._pin(self._topk.at[p.slot].set(p.req.top_k))
-            self._topp = self._pin(self._topp.at[p.slot].set(p.req.top_p))
-            self._keys = self._pin(
-                self._keys.at[p.slot].set(jnp.asarray(p.req.key))
-            )
+            if rows is None:
+                rows = np.zeros((self.n_slots, H + _ADMIT_COLS), np.int32)
+            r = rows[p.slot]
+            r[:H] = p.hist_row
+            r[H + _ADM_LIVE] = 1
+            r[H + _ADM_TOK] = first
+            r[H + _ADM_POS] = p.fill
+            r[H + _ADM_TOPK] = p.req.top_k
+            r[H + _ADM_TEMP] = np.float32(p.req.temperature).view(np.int32)
+            r[H + _ADM_TOPP] = np.float32(p.req.top_p).view(np.int32)
+            r[H + _ADM_KEY: H + _ADM_KEY + 2] = np.asarray(
+                p.req.key, np.uint32
+            ).view(np.int32)
             if p.draft_kv is not None and self._draft is not None:
                 self._draft.admit(p.slot, p.draft_kv)
-            if p.hist_row is not None:
-                self._hist = self._pin(
-                    self._hist.at[p.slot].set(jnp.asarray(p.hist_row))
-                )
             self._active[p.slot] = True
+            admitted += 1
+        if rows is not None:
+            try:
+                (self._tok, self._pos, self._temp, self._topk, self._topp,
+                 self._keys, self._hist) = self._admit(
+                    self._tok, self._pos, self._temp, self._topk,
+                    self._topp, self._keys, self._hist, rows,
+                )
+            except Exception as exc:  # the seven arrays were donated
+                self._mark_failed(exc)
+                raise
+            self._n_admit_launches += 1
+            self._n_admitted += admitted
+        return admitted
 
     # -- paged KV: admission, chunked prefill, blocks, preemption ----------
     def _submit_paged(self, prompt, max_new_tokens, temperature, top_k,
@@ -2757,13 +2839,7 @@ class ContinuousBatcher:
         if job.known_first is not None:
             first_dev: Any = int(job.known_first)
         else:
-            first_dev = self._sample1(
-                job.logits_row,
-                jnp.asarray([req.temperature], jnp.float32),
-                jnp.asarray([req.top_k], jnp.int32),
-                jnp.asarray([req.top_p], jnp.float32),
-                jax.random.fold_in(jnp.asarray(req.key), t),
-            )
+            first_dev = self._sample_first(job.logits_row, req, t)
         job.logits_row = None
         hist_row = np.full((self.max_len,), -1, np.int32)
         hist_row[:t] = job.tokens[: self.max_len]
@@ -3921,6 +3997,10 @@ class ContinuousBatcher:
                     self._n_spec_accepted / self._n_spec_columns
                     if self._n_spec_columns else 0.0
                 ),
+                # one launch of the admit program per _apply_pending
+                # that had rows to splice, whatever their number
+                "admit_launches": self._n_admit_launches,
+                "admitted": self._n_admitted,
                 "slots_occupied": occupied,
                 "slots_free": self.n_slots - occupied,
                 "results_pending_pickup": len(self._done_pool),

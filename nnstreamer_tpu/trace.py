@@ -377,9 +377,10 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     ),
     "nns.pump.admit": (
         "batcher",
-        "_apply_pending: the packed read of the queued admissions' first "
-        "tokens and their splice into the slot state",
-        "",
+        "_apply_pending: the gathered read of the queued admissions' first "
+        "tokens and their splice into the slot state (one packed transfer, "
+        "one launch of jit_nns_admit)",
+        "admitted (requests spliced in this span; set as it closes)",
     ),
     "nns.pump.prepare": (
         "batcher",
@@ -459,11 +460,12 @@ def _annotate(name: str, attrs: Dict):
 class span:
     """``with trace.span("nns.pump", n_steps=8): ...`` — one interval on
     the profiler's host plane (and in the chrome trace when a ``Tracer``
-    is on). Attributes are ints, floats or short strings known when the
-    span opens and computed from host state only: reading a device array
-    for one would be a sync on the hot path. A span held open across
-    calls (``nns.llm.emit``) uses ``__enter__`` and ``close`` directly,
-    both on the same thread."""
+    is on). Attributes are ints, floats or short strings computed from
+    host state only: reading a device array for one would be a sync on
+    the hot path. Most are known when the span opens; a count of what the
+    span did (``nns.pump.admit``'s ``admitted``) is added with ``set``
+    before it closes. A span held open across calls (``nns.llm.emit``)
+    uses ``__enter__`` and ``close`` directly, both on the same thread."""
 
     __slots__ = ("_name", "_attrs", "_ann", "_t0")
 
@@ -475,6 +477,11 @@ class span:
         self._ann.__enter__()
         self._t0 = time.perf_counter() if get() is not None else None
         return self
+
+    def set(self, **attrs) -> None:
+        """More attributes for the open span, each name once."""
+        self._attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
 
     def close(self) -> None:
         self._ann.__exit__(None, None, None)

@@ -540,3 +540,154 @@ def test_random_config_matrix_pump_equivalence(params, draft_params,
     while any(b.result(r) is None for r in rb):
         b.step_pump(3)
     assert _tokens(a, ra) == _tokens(b, rb)
+
+
+# -- admission: one packed transfer, one launch (jit_nns_admit) -------------
+
+_SLOT_STATE = ("_tok", "_pos", "_temp", "_topk", "_topp", "_keys", "_hist")
+_PAGED = dict(kv_layout="paged", block_size=16)
+
+
+def _slot_state(cb):
+    return {k: np.asarray(getattr(cb, k)).copy() for k in _SLOT_STATE}
+
+
+def _queue(cb, n):
+    """Run the host side of admission until ``n`` rows wait in ``_pending``
+    (the slot layout queues in submit; the paged one activates one prefill
+    job per call while an activation is pending)."""
+    with cb._step_lock:
+        while cb._paged and len(cb._pending) < n:
+            cb._advance_prefill()
+    assert len(cb._pending) == n
+    return list(cb._pending)
+
+
+def _apply(cb):
+    """One ``_apply_pending``; -> (admit launches, requests admitted) it took."""
+    st0 = cb.stats()
+    with cb._step_lock:
+        cb._apply_pending()
+    st = cb.stats()
+    return (st["admit_launches"] - st0["admit_launches"],
+            st["admitted"] - st0["admitted"])
+
+
+def _seven_writes(state, pending, finished=()):
+    """The numpy model of admission: per queued row that joins the batch,
+    the seven per-slot writes of the eager chain this program replaced."""
+    want = {k: v.copy() for k, v in state.items()}
+    for p in pending:
+        if p.req.rid in finished:
+            continue
+        first = p.req.tokens[-1]  # the prefill's token, or a resume's
+        row = p.hist_row.copy()
+        row[p.fill] = first
+        want["_tok"][p.slot] = first
+        want["_pos"][p.slot] = p.fill
+        want["_temp"][p.slot] = np.float32(p.req.temperature)
+        want["_topk"][p.slot] = p.req.top_k
+        want["_topp"][p.slot] = np.float32(p.req.top_p)
+        want["_keys"][p.slot] = np.asarray(p.req.key, np.uint32)
+        want["_hist"][p.slot] = row
+    return want
+
+
+def _assert_bitwise(cb, want):
+    for k, v in _slot_state(cb).items():
+        assert v.dtype == want[k].dtype, k
+        assert v.tobytes() == want[k].tobytes(), k
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+@pytest.mark.parametrize("rows", [1, 3, 4])
+def test_admission_is_one_launch_of_the_seven_writes(params, layout, rows):
+    """However many admissions a pump finds queued (1, 3, n_slots), it
+    applies them with ONE launch of the admit program, and the per-slot
+    device state after it is bit for bit what seven writes per row give."""
+    b = _twin(params, **(_PAGED if layout == "paged" else {}))
+    for s in range(rows):
+        b.submit(_prompt(5 + 2 * s, 200 + s), 12, temperature=0.3 + 0.2 * s,
+                 top_k=3 + s, top_p=0.95 - 0.1 * s, seed=50 + s)
+    pending = _queue(b, rows)
+    before = _slot_state(b)
+    assert _apply(b) == (1, rows)
+    _assert_bitwise(b, _seven_writes(before, pending))
+    assert b._active.sum() == rows and not b._pending
+    assert _apply(b) == (0, 0)  # nothing queued: no launch
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_admission_batch_with_resumed_and_finished_rows(params, layout):
+    """One batch that holds a plain row, a row that ends on its first token
+    (its stop id; paged also a budget of 1, which the slot layout finishes
+    in submit) and, paged, a ``resumed`` row (re-prefill of a migrated
+    request, first token a host int): one launch, the finished rows are not
+    in it and leave their slots untouched, and the streams are exactly the
+    per-token ones."""
+    kw = _PAGED if layout == "paged" else {}
+    a, b = _twin(params, **kw), _twin(params, **kw)
+    p_plain, p_stop, p_one, p_mig = (_prompt(6 + s, 220 + s) for s in range(4))
+    ref_plain, ref_stop = a.submit(p_plain, 9), a.submit(p_stop, 9)
+    _drain_steps(a, [ref_plain, ref_stop])
+    stop_id = a.result(ref_stop)[0]
+    rids = [b.submit(p_plain, 9), b.submit(p_stop, 9, stop_token=stop_id)]
+    finished = {rids[1]}
+    want_tokens = [a.result(ref_plain), [stop_id]]
+    if layout == "paged":
+        rids.append(b.submit(p_one, 1))
+        finished.add(rids[2])
+        ref_one = a.submit(p_one, 1)
+        ref_mig = a.submit(p_mig, 9)
+        _drain_steps(a, [ref_one, ref_mig])
+        c = _twin(params, **kw)
+        mig = c.submit(p_mig, 9)
+        while len(c.partials([mig]).get(mig, [])) < 3:
+            c.step()
+        rids.append(b.resume_from_span(c.extract_request(mig)))
+        want_tokens += [a.result(ref_one), a.result(ref_mig)]
+    pending = _queue(b, len(rids))
+    assert [p.resumed for p in pending] == [False, False, False, True][
+        : len(rids)]
+    before = _slot_state(b)
+    assert _apply(b) == (1, len(rids) - len(finished))
+    _assert_bitwise(b, _seven_writes(before, pending, finished))
+    assert [b.result(r) is not None for r in rids] == [
+        r in finished for r in rids]
+    _drain_steps(b, rids)
+    assert _tokens(b, rids) == want_tokens
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_admission_compiles_once(params, layout):
+    """Beside the no-new-compiles pins above: the admit program has ONE
+    static shape per batcher and the first-token read launches nothing, so
+    after the first admission, admissions of 1, 2 and n_slots rows compile
+    nothing at all (a compile inside a measured window fails a benchmark
+    run)."""
+    import jax.monitoring as mon
+
+    b = _twin(params, **(_PAGED if layout == "paged" else {}))
+
+    def admit(n_rows, seed):
+        rids = [b.submit(_prompt(5 + s, seed + s), 3) for s in range(n_rows)]
+        _queue(b, n_rows)
+        assert _apply(b) == (1, n_rows)
+        return rids
+
+    _drain_steps(b, admit(1, 300))  # warm: every program of the path
+    compiled = []
+
+    def on_duration(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(secs)
+
+    mon.register_event_duration_secs_listener(on_duration)
+    try:
+        for n_rows in (1, 2, 4):
+            rids = admit(n_rows, 310 + 10 * n_rows)
+            assert not compiled, f"{n_rows} rows compiled {len(compiled)}"
+            _drain_steps(b, rids)
+    finally:
+        mon.unregister_event_duration_listener(on_duration)
+    assert b._admit._cache_size() == 1

@@ -241,6 +241,37 @@ def test_paged_decode_step_cell_shapes(one_chip, cell):
     assert not _big_moves(text, n * BS * kv * HD, f",{BS},{kv},{HD}")
 
 
+# (n-slots, max-len) of the per-slot state the admit program rewrites
+ADMIT_CELLS = {"olmo-1b": (16, 1024), "longcat-flash-chat": (64, 2048)}
+
+
+@pytest.mark.parametrize("cell", list(ADMIT_CELLS))
+def test_admit_program_cell_shapes(one_chip, cell):
+    """The batcher's admission program (``jit_nns_admit``) at the cells'
+    shapes: all seven per-slot arrays are donated and come back in their
+    own buffers, and the history array is rewritten in place — no copy of
+    anything its size in the compiled text."""
+    from nnstreamer_tpu.models.serving import _ADMIT_COLS, _make_admit
+
+    b, h = ADMIT_CELLS[cell]
+    u32 = jnp.uint32
+    shapes = (
+        ((b,), i32), ((b,), i32), ((b,), f32), ((b,), i32), ((b,), f32),
+        ((b, 2), u32), ((b, h), i32), ((b, h + _ADMIT_COLS), i32),
+    )
+    text = _make_admit(h).lower(*(
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes
+    )).compile().as_text()
+    assert "jit_nns_admit" in text
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    for i in range(7):
+        assert f"{{{i}}}: ({i}, {{}}" in aliased, (i, aliased)
+    copies = re.findall(
+        rf"= \(?s32\[{b},{h}\][^=\n]*? (?:copy|copy-start)\(", text
+    )
+    assert not copies, copies
+
+
 def test_nms_ssd_anchors(one_chip):
     from nnstreamer_tpu.ops.pallas.nms import nms
 

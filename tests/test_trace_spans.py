@@ -68,11 +68,9 @@ def _serve(srv_id: str):
 
 
 @pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """One traced run: (events of every ``nns.*`` name, XLA module names seen
-    on the host's XLA lines, the token streams)."""
+def trace_file(tmp_path_factory):
+    """One traced run: (path of its ``.xplane.pb``, the token streams)."""
     import jax
-    from jax.profiler import ProfileData
 
     logdir = str(tmp_path_factory.mktemp("spans"))
     opts = jax.profiler.ProfileOptions()
@@ -82,20 +80,46 @@ def traced(tmp_path_factory):
         streams = _serve("spans-traced")
     finally:
         jax.profiler.stop_trace()
-    path = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
-                                  "*.xplane.pb"))[-1]
-    events, modules = [], set()
+    return glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))[-1], streams
+
+
+def _host_events(path):
+    """(event, (plane, line index), line name) of every host event."""
+    from jax.profiler import ProfileData
+
     for plane in ProfileData.from_file(path).planes:
         for i, line in enumerate(plane.lines):
             for ev in line.events:
-                if ev.name.startswith("nns."):
-                    events.append({
-                        "name": ev.name, "line": (plane.name, i),
-                        "start": ev.start_ns, "end": ev.start_ns + ev.duration_ns,
-                        "stats": dict(ev.stats)})
-                elif line.name.startswith("tf_XLAPjRtCpuClient"):
-                    modules.add(dict(ev.stats).get("hlo_module"))
+                yield ev, (plane.name, i), line.name
+
+
+def _timed(ev, line, name):
+    return {"name": name, "line": line, "start": ev.start_ns,
+            "end": ev.start_ns + ev.duration_ns, "stats": dict(ev.stats)}
+
+
+@pytest.fixture(scope="module")
+def traced(trace_file):
+    """(events of every ``nns.*`` name, XLA module names seen on the host's
+    XLA lines, the token streams)."""
+    path, streams = trace_file
+    events, modules = [], set()
+    for ev, line, line_name in _host_events(path):
+        if ev.name.startswith("nns."):
+            events.append(_timed(ev, line, ev.name))
+        elif line_name.startswith("tf_XLAPjRtCpuClient"):
+            modules.add(dict(ev.stats).get("hlo_module"))
     return events, modules, streams
+
+
+@pytest.fixture(scope="module")
+def ops(trace_file):
+    """Every op a program ran on a host line (the CPU backend runs a small
+    program on the thread that launched it), named by its module."""
+    timed = (_timed(ev, line, None) for ev, line, _ in _host_events(trace_file[0]))
+    return [dict(e, name=e["stats"]["hlo_module"]) for e in timed
+            if "hlo_module" in e["stats"]]
 
 
 # ``nns.moe.routing`` is written by the routed-expert family only; this run
@@ -174,6 +198,26 @@ def test_attributes_round_trip(traced):
                for e in admitted)
     for a in admitted:
         assert any(_inside(a, s) for s in by("nns.llm.submit"))
+
+
+def test_admit_spans_count_what_they_spliced_in_one_launch(traced, ops):
+    """``nns.pump.admit`` says how many requests it admitted; a span that
+    admitted any holds the ops of ONE ``jit_nns_admit`` and no eager update
+    (``jit_scatter``, ``jit_convert_element_type``), one that admitted none
+    launches nothing."""
+    events, _, _ = traced
+    admits = [e for e in events if e["name"] == "nns.pump.admit"]
+    assert all(0 <= e["stats"]["admitted"] <= N_SLOTS for e in admits)
+    assert sum(e["stats"]["admitted"] for e in admits) == len(PROMPT_LENS)
+    per_span = [[o["name"] for o in ops if _inside(o, a)] for a in admits]
+    launched = [names for a, names in zip(admits, per_span)
+                if a["stats"]["admitted"]]
+    assert launched and all(
+        names and set(names) == {"jit_nns_admit"} for names in launched)
+    # the same ops each time: one launch a span, whatever it admitted
+    assert len({tuple(sorted(names)) for names in launched}) == 1
+    assert not any(names for a, names in zip(admits, per_span)
+                   if not a["stats"]["admitted"])
 
 
 def test_programs_have_names_of_their_own_and_decode_keeps_impl(traced):
