@@ -2282,8 +2282,6 @@ def _pipeline_llm(smoke: bool) -> None:
         "paged_tok_frac": (
             round(paged_tok_s / slot_tok_s, 3) if slot_tok_s else None
         ),
-        "kv_attn": paged_st.get("kv_attn"),
-        "kv_gather_dispatches": paged_st.get("kv_gather_dispatches", 0),
         "nns_kv_prefix_hits_total": paged_st.get("kv_prefix_hits", 0),
         "kv_prefix_hit_tokens": paged_st.get("kv_prefix_hit_tokens", 0),
         "kv_preemptions": paged_st.get("kv_preemptions", 0),
@@ -2301,9 +2299,7 @@ def _llm_through_plane_cell(model_kw: dict, rng) -> dict | None:
     docs/llm-serving.md): two serversink/serversrc pipeline pairs share
     ONE plane-managed paged ContinuousBatcher (``plane=`` on the
     serversink) — cross-stream admission rides the deficit-round-robin
-    scheduler, SLO ledgers stay per stream, and the block-native decode
-    path must stay gather-free (``llm_plane_gather_dispatches`` pinned
-    0 in the record)."""
+    scheduler and SLO ledgers stay per stream."""
     import threading
 
     import numpy as np
@@ -2394,8 +2390,6 @@ def _llm_through_plane_cell(model_kw: dict, rng) -> dict | None:
         "llm_plane_streams": n_streams,
         "llm_plane_requests_per_stream": n_reqs,
         "llm_plane_tok_s": _round(toks / dt if dt > 0 else 0.0, 1),
-        "llm_plane_gather_dispatches": st.get("kv_gather_dispatches", 0),
-        "llm_plane_kv_attn": st.get("kv_attn"),
         # per-stream SLO ledgers: each src reports ONLY its own rows
         "llm_plane_stream_request_rows": per_stream_reqs,
     }

@@ -144,12 +144,9 @@ print(f"K (draft model proposes): {st['tokens_emitted']} tokens, "
 # the blocks their tokens occupy, identical prompts share physical
 # blocks through a rolling prefix hash, long prompts prefill in chunks
 # interleaved with decode, and pool pressure preempts-and-re-prefills
-# instead of OOMing. Decode is BLOCK-NATIVE by default (kv_attn="auto"
-# → "block": attention reads ride the block tables straight off the
-# arena, each token writes in place into its owning block — zero
-# gather/scatter programs; kv_attn="gather" keeps the materialized-
-# view oracle for parity debugging). Streams are bitwise the slot
-# layout's either way.
+# instead of OOMing. Decode is block-native: attention reads ride the
+# block tables straight off the arena, each token writes in place into
+# its owning block. Streams are bitwise the slot layout's.
 print("\n-- paged KV cache: 12 requests in a 6-request HBM budget --")
 pg = ContinuousBatcher(params, n_heads=8, n_slots=16, max_len=128,
                        prompt_len=32, kv_layout="paged", block_size=16,

@@ -180,15 +180,6 @@ CATALOG: Dict[str, Tuple[Severity, str, str]] = {
         "no hedge — bind a fleet (hosts=h1:p1,h2:p2) or grant a "
         "retry-max budget",
     ),
-    "NNS-W117": (
-        Severity.WARNING, "paged-gather-materializes-cache",
-        "a paged LLM serving element is pinned to kv-attn=gather, whose "
-        "step programs materialize the full contiguous per-slot view "
-        "beside the block arena (a transient HBM doubling) and the "
-        "combined footprint exceeds the declared memory bound; the "
-        "block-native default (kv-attn=auto/block) attends the arena "
-        "directly through the block tables with no gathered view",
-    ),
     # -- nns-xray chain analysis (analysis/xray.py, docs/chain-analysis.md) -
     "NNS-W120": (
         Severity.WARNING, "chain-split-by-host-node",

@@ -981,17 +981,10 @@ def _plane_async_pass(pipeline: Pipeline, report: LintReport) -> None:
 
 
 def _kv_cache_pass(pipeline: Pipeline, report: LintReport) -> None:
-    """NNS-W115 + NNS-W117: KV caches that cannot fit their declared
-    memory bound (``kv-memory-bound`` prop, or ``[llm] memory_bound``).
-
-    - W115: a slot-layout cache (2 · L · n-slots · max-len · KV · Dh,
-      every slot sized for the worst case) exceeds the bound while
-      ``kv-layout=paged`` is available.
-    - W117: a PAGED element pinned to ``kv-attn=gather``, whose step
-      programs materialize the full contiguous per-slot view (slot-
-      cache-sized) BESIDE the block arena — the transient footprint
-      arena + view exceeds the bound. The block-native default has no
-      gathered view, so the fix is simply dropping the pin.
+    """NNS-W115: a slot-layout KV cache (2 · L · n-slots · max-len · KV ·
+    Dh, every slot sized for the worst case) that cannot fit its declared
+    memory bound (``kv-memory-bound`` prop, or ``[llm] memory_bound``)
+    while ``kv-layout=paged`` is available.
 
     Static estimates from the element's props and custom model options
     — no model is loaded (the sink is LINT_SKIP_NEGOTIATE for exactly
@@ -1007,6 +1000,8 @@ def _kv_cache_pass(pipeline: Pipeline, report: LintReport) -> None:
         layout = str(e.get_property("kv-layout") or "").strip() or (
             conf().get("llm", "kv_layout", "slot")
         )
+        if layout == "paged":
+            continue  # the arena is sized by kv-blocks, not the worst case
         bound_raw = str(e.get_property("kv-memory-bound") or "").strip()
         if not bound_raw:
             bound_raw = conf().get("llm", "memory_bound", "").strip()
@@ -1033,41 +1028,7 @@ def _kv_cache_pass(pipeline: Pipeline, report: LintReport) -> None:
             per_elem = 2.0 if dt == "bfloat16" else 4.0
         n_slots = int(e.get_property("n-slots") or 4)
         max_len = int(e.get_property("max-len") or 256)
-        # the slot cache — which is ALSO the gathered view's shape
         view = int(2 * n_layers * n_slots * max_len * n_kv * hd * per_elem)
-        if layout == "paged":
-            attn = str(e.get_property("kv-attn") or "").strip() or (
-                conf().get("llm", "kv_attn", "auto")
-            )
-            if attn != "gather":
-                continue  # block-native: no gathered view to flag
-            bs = int(e.get_property("block-size") or 0) or (
-                conf().get_int("llm", "block_size", 16)
-            ) or 16
-            kv_blocks = int(e.get_property("kv-blocks") or 0) or (
-                conf().get_int("llm", "kv_blocks", 0)
-            )
-            if kv_blocks <= 0:  # no-saving auto default (serving.py)
-                kv_blocks = n_slots * (-(-max_len // bs))
-            arena = int(
-                2 * n_layers * (kv_blocks + 1) * bs * n_kv * hd * per_elem
-            )
-            est = arena + view
-            if est <= bound:
-                continue
-            report.add(
-                "NNS-W117", e.name,
-                f"kv-attn=gather materializes the contiguous view ≈ "
-                f"{view / (1 << 20):.0f} MiB beside the "
-                f"{arena / (1 << 20):.0f} MiB block arena every step — "
-                f"transient ≈ {est / (1 << 20):.0f} MiB exceeds the "
-                f"declared bound {bound_raw}",
-                "drop kv-attn=gather (the block-native default attends "
-                "the arena directly through the block tables, no "
-                "gathered view — docs/llm-serving.md); keep the gather "
-                "oracle for parity debugging only",
-            )
-            continue
         if view <= bound:
             continue
         report.add(

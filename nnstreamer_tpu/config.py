@@ -83,12 +83,6 @@ _DEFAULTS: Dict[str, Dict[str, str]] = {
         # paged (block arena + per-request block tables with prefix
         # sharing, chunked prefill and preemption-by-eviction)
         "kv_layout": "slot",
-        # paged decode formulation: auto (= block) | block (attend the
-        # arena directly through the block tables, in-place token
-        # writes — the default) | gather (materialize the contiguous
-        # view per step: the debug/parity oracle, pays a transient HBM
-        # doubling — nns-lint NNS-W117 flags it against memory_bound)
-        "kv_attn": "auto",
         # tokens per KV block (paged); must divide prompt-len/max-len
         "block_size": "16",
         # total usable blocks in the arena (paged); empty = enough for

@@ -138,7 +138,7 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
                    max_len: int, prompt_len: int, speculate: int,
                    speculate_model: str, kv_layout: str, block_size: int,
                    kv_blocks: int, cache_dtype: str, prefill_chunks: int,
-                   kv_attn: str, attn_impl: str = ""):
+                   attn_impl: str = ""):
     """Open the zoo model (+ optional draft) and build the
     ContinuousBatcher — shared by the private-server path and the
     LlmPlane opener (serving_plane/llm.py), so through-plane serving
@@ -189,7 +189,6 @@ def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
             kv_layout=kv_layout, block_size=block_size,
             kv_blocks=kv_blocks or None,
             prefill_chunks=prefill_chunks,
-            kv_attn=kv_attn or "auto",
         )
     t0 = _time.perf_counter()
     cb = ContinuousBatcher(
@@ -216,8 +215,7 @@ class _LlmServer:
                  speculate_model: str = "", pump_tokens: int = 1,
                  kv_layout: str = "slot", block_size: int = 16,
                  kv_blocks: int = 0, cache_dtype: str = "auto",
-                 prefill_chunks: int = 1, kv_attn: str = "auto",
-                 attn_impl: str = "",
+                 prefill_chunks: int = 1, attn_impl: str = "",
                  plane: str = "", plane_weight: float = 1.0,
                  srv_id: str = "0", migrate_to: str = "",
                  checkpoint_every_tokens: int = 0,
@@ -328,8 +326,7 @@ class _LlmServer:
             sig = (
                 model, tuple(sorted(options.items())), n_slots, max_len,
                 prompt_len, kv_layout, block_size, kv_blocks,
-                cache_dtype, prefill_chunks, kv_attn or "auto",
-                attn_impl,
+                cache_dtype, prefill_chunks, attn_impl,
                 max(1, int(pump_tokens)),
             )
             self._plane = llm_plane.acquire(
@@ -337,8 +334,7 @@ class _LlmServer:
                 opener=lambda: _build_batcher(
                     model, options, n_slots, max_len, prompt_len,
                     speculate, speculate_model, kv_layout, block_size,
-                    kv_blocks, cache_dtype, prefill_chunks, kv_attn,
-                    attn_impl,
+                    kv_blocks, cache_dtype, prefill_chunks, attn_impl,
                 ),
                 pump_tokens=pump_tokens,
             )
@@ -356,7 +352,7 @@ class _LlmServer:
             self.cb = _build_batcher(
                 model, options, n_slots, max_len, prompt_len, speculate,
                 speculate_model, kv_layout, block_size, kv_blocks,
-                cache_dtype, prefill_chunks, kv_attn, attn_impl,
+                cache_dtype, prefill_chunks, attn_impl,
             )
         # properties the served block family does not carry refuse here,
         # by name (models/family.py): no silent fallback, no half path
@@ -1041,12 +1037,9 @@ class LlmServerSink(Sink):
     kv-layout/block-size/kv-blocks/prefill-chunks (paged KV cache:
     block-table arena with prefix sharing, chunked prefill and
     preemption-by-eviction — docs/llm-serving.md; defaults from the
-    [llm] config section), kv-attn (paged decode formulation:
-    auto/block attend the arena directly through the block tables;
-    gather keeps the materialized-view debug/parity oracle — flagged
-    by nns-lint NNS-W117 when it would breach the memory bound),
+    [llm] config section),
     cache-dtype (int8 stores the KV cache quantized), kv-memory-bound
-    (declared HBM budget consumed by nns-lint NNS-W115/W117),
+    (declared HBM budget consumed by nns-lint NNS-W115),
     migrate-to (peer host:port — drain-time live KV-span migration;
     in-flight generations continue on the peer bitwise-identically for
     greedy requests), checkpoint-every-tokens/checkpoint-dir (periodic
@@ -1083,10 +1076,6 @@ class LlmServerSink(Sink):
         # paged KV cache (nnstreamer_tpu/kv/, docs/llm-serving.md);
         # empty strings defer to the [llm] config section
         "kv-layout": PropSpec("str", "", desc="slot | paged ([llm] default)"),
-        "kv-attn": PropSpec(
-            "str", "",
-            desc="paged decode path: auto | block | gather ([llm] default)",
-        ),
         "block-size": PropSpec("int", 0, desc="tokens per KV block (paged)"),
         "kv-blocks": PropSpec("int", 0, desc="arena blocks (paged; 0=auto)"),
         "cache-dtype": PropSpec("str", "auto", desc="auto | int8"),
@@ -1180,9 +1169,6 @@ class LlmServerSink(Sink):
             # plane= means "the shared paged batcher" — an unset
             # kv-layout follows the plane rather than the slot default
             kv_layout = "paged"
-        kv_attn = str(self.get_property("kv-attn", "")).strip() or (
-            cfg.get("llm", "kv_attn", "auto")
-        )
         block_size = int(self.get_property("block-size", 0)) or (
             cfg.get_int("llm", "block_size", 16)
         )
@@ -1211,7 +1197,6 @@ class LlmServerSink(Sink):
             kv_blocks=kv_blocks,
             cache_dtype=str(self.get_property("cache-dtype", "auto")),
             prefill_chunks=prefill_chunks,
-            kv_attn=kv_attn,
             attn_impl=str(self.get_property("attn-impl", "")).strip() or (
                 cfg.get("llm", "attn_impl", "")
             ),

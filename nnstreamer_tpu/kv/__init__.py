@@ -11,16 +11,13 @@ slot. This package is the paged alternative behind
   device-resident arena per layer, ref-counted with copy-on-write, and a
   rolling-prefix-hash index so requests sharing a token prefix share
   physical blocks;
-- :mod:`block_attn` — the DEFAULT block-native decode/verify
-  formulation (``kv_attn="auto"|"block"``): attention reads ride the
-  block table straight off the arena, token writes land in place in
-  their owning block — no contiguous view in either direction, bitwise
-  identical to the slot path (tests/test_kv_block_attn.py);
-- :mod:`gather` — the admission-path block ops plus the
-  gather→view→scatter decode oracle behind ``kv_attn="gather"``
-  (bitwise parity with the contiguous slot path, pinned by
-  tests/test_kv_paged.py; pays a transient view beside the arena —
-  debugging only);
+- :mod:`block_attn` — the block-native decode/verify formulation:
+  attention reads ride the block table straight off the arena, token
+  writes land in place in their owning block — no contiguous view in
+  either direction. The slot layout is the oracle: streams are bitwise
+  identical to it (tests/test_kv_block_attn.py, tests/test_kv_paged.py);
+- :mod:`gather` — the admission-path block ops (stage↔arena), the arena
+  itself and the int8 cache entry;
 - :mod:`sched` — chunked-prefill admission jobs, watermark block
   accounting with preemption-by-eviction, and the per-request SLO
   ledger (queue/prefill/TTFT/TPOT → nns-obs).

@@ -1,31 +1,24 @@
 """Block-native paged attention: decode/verify straight off the arena.
 
-The gather formulation (:mod:`nnstreamer_tpu.kv.gather`) runs the paged
-step as ``gather_cache`` → contiguous view → slot-layout step →
-``scatter_window``: correct and bitwise-pinned, but every decode pump
-materializes the full ``[L, B, max_len, ...]`` view as a donated scan
-carry BESIDE the arena (a transient HBM doubling) and pays a
-whole-arena scatter per step — exactly the intermediate
-materialization a streaming dataflow must not pay (StreamTensor,
-PAPERS.md). This module is the block-native replacement the batcher
-selects by default (``ContinuousBatcher(kv_attn="auto"|"block")``):
+The paged step never holds a contiguous ``[L, B, max_len, ...]`` view of
+the cache: every decode pump would carry it BESIDE the arena (a transient
+HBM doubling) and scatter it back — the intermediate materialization a
+streaming dataflow must not pay (StreamTensor, PAPERS.md). The slot layout
+(``ContinuousBatcher(kv_layout="slot")``) is the oracle: block-native
+streams are bitwise identical to it, pinned by tests/test_kv_block_attn.py
+and tests/test_kv_paged.py.
 
 - the attention READ takes each layer's blocks through the block table
   *inside* that layer's body (:func:`_take_layer`, one per-layer
   transient instead of an L-deep carried view) and runs the IDENTICAL
-  masked-softmax expressions the gathered view ran — so block-native
-  streams stay bitwise identical to the gather oracle (and hence to the
-  slot layout), pinned by tests/test_kv_block_attn.py /
-  tests/test_kv_paged.py;
+  masked-softmax expressions the slot step runs on its cache;
 - the WRITE is :func:`write_fresh_window`: the freshly computed K/V of
   the pending token (or verify chunk) lands in its owning arena
-  block(s) with ONE scatter per leaf on the donated arena — the
-  width-1 dynamic block update that replaces ``scatter_window`` on the
-  decode path. Inactive lanes route to scratch block 0 carrying its
-  init values (zero payload, unit scales), so scratch stays pristine
-  and shared / copy-on-write blocks are never touched: the write
-  window lies in blocks the request owns privately (the pool's CoW
-  discipline);
+  block(s) with ONE scatter per leaf on the donated arena. Inactive
+  lanes route to scratch block 0 carrying its init values (zero
+  payload, unit scales), so scratch stays pristine and shared /
+  copy-on-write blocks are never touched: the write window lies in
+  blocks the request owns privately (the pool's CoW discipline);
 - :func:`paged_attention_ref` is the per-block ONLINE-softmax jnp
   reference of the Pallas block-table kernel
   (:mod:`nnstreamer_tpu.ops.pallas.paged_attention`): one take per
@@ -37,8 +30,8 @@ selects by default (``ContinuousBatcher(kv_attn="auto"|"block")``):
   the kernel on a real TPU backend, the reference elsewhere.
 
 The admission-path ops (``write_block`` / ``read_block`` /
-``copy_block`` and chunked-prefill staging) are shared with the gather
-formulation and stay in :mod:`nnstreamer_tpu.kv.gather`.
+``copy_block`` and chunked-prefill staging) and the int8 entry are in
+:mod:`nnstreamer_tpu.kv.gather`.
 """
 
 from __future__ import annotations
@@ -48,8 +41,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from nnstreamer_tpu.kv.gather import dequantize_kv, quantize_kv
 from nnstreamer_tpu.models import transformer as tfm
-from nnstreamer_tpu.models.serving import dequantize_kv, quantize_kv
 
 NEG_INF = -1e30
 
@@ -74,8 +67,7 @@ def _write_view_scale(sc, new, pos, gate):
 
 def _take_layer(layer, tables):
     """One layer's arena leaf ``[N, bs, ...]`` → the contiguous per-slot
-    view ``[B, nb*bs, ...]`` through ``tables`` [B, nb] — the read half
-    of ``kv.gather.gather_cache`` for a single layer, materialized
+    view ``[B, nb*bs, ...]`` through ``tables`` [B, nb], materialized
     transiently inside the layer body instead of carried (and scattered
     back) across the whole program."""
     b, nb = tables.shape
@@ -161,13 +153,13 @@ def batched_decode_step_block(
     arena tree (leaves [L, N, bs, ...]), ``tables`` [B, nb] int32 →
     (logits [B, V] f32, arena', pos'). The arena write is deferred to
     one :func:`write_fresh_window` scatter after the layer scan (in
-    place under donation; no ``scatter_window``, no carried view).
+    place under donation; no carried view).
 
     The attention read has two formulations. Inline (``attn_fn`` None,
     the XLA oracle): per layer the view is taken through the tables and
     the pending token's K/V is written into it with the EXACT
-    expressions the gathered path used — bitwise parity with the gather
-    oracle by construction. With ``attn_fn(q, k_entry, v_entry, tables,
+    expressions of the slot step — bitwise parity with the slot layout
+    by construction. With ``attn_fn(q, k_entry, v_entry, tables,
     fill, (fresh_k, fresh_v), layer=li) -> [B,1,H,Dh]`` (the block-table
     kernel, ops/pallas/paged_attention.py) no view exists at all: the
     layer scan carries only the layer INDEX and every layer reads the

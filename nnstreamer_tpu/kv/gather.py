@@ -1,43 +1,20 @@
-"""Jitted block-table gather/scatter: the paged cache's admission ops
-and the gather-formulation decode oracle.
+"""The paged cache's admission ops: what moves K/V between a contiguous
+prefill stage and the block arena, and the arena itself.
 
-Since the block-native path landed (:mod:`nnstreamer_tpu.kv.block_attn`,
-``ContinuousBatcher(kv_attn="auto"|"block")`` — the default), the
-gather/scatter pair below serves the DECODE plane only as the
-debug/parity oracle behind ``kv_attn="gather"``: bitwise identical
-streams, but every step materializes the full contiguous view beside
-the arena (a transient HBM doubling — forcing it on a bounded chip is
-what nns-lint NNS-W117 warns about) and pays a whole-arena scatter.
-The admission-path helpers at the bottom (block write/read/copy,
-arena init) are shared by BOTH formulations.
+- :func:`init_arena` — the zeroed arena tree ``[L, N + 1, bs, KV, Dh]``
+  per leaf (block 0 is scratch), float or int8 ``(payload, scale)``;
+- :func:`quantize_kv` / :func:`dequantize_kv` — the int8 cache entry
+  (per-token-per-head symmetric scales), shared by every layout so slot
+  and paged int8 payloads are bitwise identical;
+- :func:`make_paged_ops` — one block at a time: stage→block write
+  (quantizing when the arena is int8, exactly like the slot layout's
+  insert_slot), block→stage read for prefix-seeded prefill, and the
+  device side of copy-on-write;
+- :func:`make_staging_ops` — the same two directions for a whole stage
+  in one program each.
 
-Under ``kv_attn="gather"`` the step/pump/spec programs run the SAME
-attention math as the contiguous slot layout
-(models/serving.batched_decode_step and friends) — the only difference
-is where the cache bytes live:
-
-- :func:`gather_cache` materializes, inside the program, a per-slot
-  contiguous view ``[L, B, max_len, ...]`` from the block arena
-  ``[L, N, bs, ...]`` through the block table ``[B, max_len//bs]``.
-  Logical token position ``p`` lands at view column ``p`` exactly as in
-  the slot cache, so masks, RoPE positions and reduction orders are
-  identical — the bitwise-parity invariant tests/test_kv_paged.py pins.
-  Unallocated table entries point at scratch block 0; their columns are
-  masked (``> pos``) so they contribute exact zeros, same as the slot
-  cache's never-written tail.
-- :func:`scatter_window` writes the updated view's touched blocks back:
-  a ``width``-token write starting at per-slot ``pos`` spans at most
-  ``(width + bs - 2)//bs + 1`` blocks — a static, small unrolled loop.
-  Inactive lanes are routed to scratch with their unchanged content, so
-  shared (read-only) blocks are never scattered by construction: the
-  write window always lies in blocks the owning request holds privately
-  (the pool's copy-on-write discipline).
-
-Host-path helpers (:func:`write_block_fn`, :func:`read_block_fn`,
-:func:`copy_block_fn`) build the admission-time ops: stage→block
-scatter (quantizing when the arena is int8, exactly like the slot
-layout's insert_slot), block→stage gather for prefix-seeded prefill,
-and the device side of copy-on-write.
+Decode never comes through here: it reads and writes the arena in place
+through the block tables (:mod:`nnstreamer_tpu.kv.block_attn`).
 """
 
 from __future__ import annotations
@@ -45,64 +22,20 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from nnstreamer_tpu.models.serving import dequantize_kv, quantize_kv
+
+def quantize_kv(t):
+    """[..., H, Dh] float → (int8 same shape, f32 scale [..., H]).
+    Per-token-per-head symmetric scales keep the error tight without
+    storing more than 1/Dh extra floats — the cache shrinks 4× vs f32
+    (2× vs bf16), which is more live slots or longer contexts per chip."""
+    m = jnp.maximum(jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1), 1e-8)
+    scale = m / 127.0
+    q = jnp.clip(jnp.round(t.astype(jnp.float32) / scale[..., None]), -127, 127)
+    return q.astype(jnp.int8), scale
 
 
-def _tree_map(f, *trees):
-    return jax.tree_util.tree_map(f, *trees)
-
-
-def gather_cache(arena, tables):
-    """arena leaves [L, N, bs, ...] → contiguous view [L, B, nb*bs, ...]
-    through ``tables`` [B, nb] int32 (works for the fp ``(k, v)`` tree
-    and the int8 ``((k8, ksc), (v8, vsc))`` tree alike)."""
-    b, nb = tables.shape
-
-    def g(a):
-        t = jnp.take(a, tables, axis=1)  # [L, B, nb, bs, ...]
-        return t.reshape((a.shape[0], b, nb * a.shape[2]) + a.shape[3:])
-
-    return _tree_map(g, arena)
-
-
-def scatter_window(arena, tables, view, pos, width: int, active):
-    """Write the ``[pos, pos+width)`` token window of the updated
-    contiguous ``view`` back into the arena blocks the tables map.
-
-    ``width`` is static (1 for a decode step, k for a verify chunk); the
-    write can straddle at most ``(width + bs - 2)//bs + 1`` blocks, each
-    handled by one unrolled scatter. Inactive slots (and out-of-range
-    block indices) are routed to scratch block 0 carrying its own
-    unchanged content — a no-op write, duplicate-index-safe because
-    every duplicate writes identical bytes."""
-    first = jax.tree_util.tree_leaves(arena)[0]
-    blk = first.shape[2]
-    b, nb = tables.shape
-    nblk = (int(width) + blk - 2) // blk + 1
-    base = pos // blk
-
-    for j in range(nblk):
-        lb = base + j  # [B] logical block this unroll writes
-        safe = jnp.clip(lb, 0, nb - 1)
-        valid = active & (lb * blk < pos + width) & (lb < nb)
-        phys = jnp.take_along_axis(tables, safe[:, None], axis=1)[:, 0]
-        phys = jnp.where(valid, phys, 0)
-        start = safe * blk
-
-        def put(a, v, phys=phys, valid=valid, start=start):
-            # v [L, B, T, ...] → the block-wide rows [L, B, bs, ...]
-            def one(vb, s):
-                return jax.lax.dynamic_slice_in_dim(vb, s, blk, axis=1)
-
-            rows = jax.vmap(one, in_axes=(1, 0), out_axes=1)(v, start)
-            old = jnp.take(a, phys, axis=1)
-            keep = valid.reshape((1, b) + (1,) * (old.ndim - 2))
-            return a.at[:, phys].set(
-                jnp.where(keep, rows.astype(a.dtype), old)
-            )
-
-        arena = _tree_map(put, arena, view)
-    return arena
+def dequantize_kv(q, scale):
+    return q.astype(jnp.float32) * scale[..., None]
 
 
 def make_paged_ops(quantized: bool, compute_dtype):
@@ -151,7 +84,9 @@ def make_paged_ops(quantized: bool, compute_dtype):
         )
 
     def copy_block(arena, src, dst):
-        return _tree_map(lambda a: a.at[:, dst].set(a[:, src]), arena)
+        return jax.tree_util.tree_map(
+            lambda a: a.at[:, dst].set(a[:, src]), arena
+        )
 
     return (
         jax.jit(write_block, donate_argnums=0),

@@ -43,6 +43,11 @@ from typing import Tuple
 
 import jax.numpy as jnp
 
+from nnstreamer_tpu.kv import block_attn as kvb
+from nnstreamer_tpu.kv import gather as kvg
+from nnstreamer_tpu.models import decode as dec
+from nnstreamer_tpu.models import transformer as tfm
+
 
 class DenseFamily:
     name = "dense"
@@ -53,8 +58,6 @@ class DenseFamily:
     decode_kernel = "paged_decode_attention"
 
     def __init__(self, params, n_heads: int, prompt_len: int, compute_dtype):
-        from nnstreamer_tpu.models import transformer as tfm
-
         self.n_heads = n_heads
         self.prompt_len = prompt_len
         self.compute_dtype = self.dtype = compute_dtype   # the arena's dtype
@@ -63,8 +66,6 @@ class DenseFamily:
         self.n_kv_heads = tfm.n_kv_heads_of(params["blocks"]["wqkv"], d, n_heads)
 
     def arena(self, n_blocks: int, block_size: int, quantized: bool = False):
-        from nnstreamer_tpu.kv import gather as kvg
-
         return kvg.init_arena(self.n_layers, n_blocks, block_size, self.n_kv_heads,
                               self.head_dim, quantized, self.compute_dtype)
 
@@ -74,21 +75,15 @@ class DenseFamily:
                 jnp.zeros(shape, self.compute_dtype))
 
     def prefill(self, params, tokens):
-        from nnstreamer_tpu.models import decode as dec
-
         return dec.prefill(params, tokens, self.n_heads, self.prompt_len,
                            compute_dtype=self.compute_dtype)
 
     def chunk(self, params, tokens, cpos, stage, return_logits: bool = True):
-        from nnstreamer_tpu.models import decode as dec
-
         return dec.verify_chunk(params, tokens, cpos, stage, self.n_heads,
                                 compute_dtype=self.compute_dtype,
                                 return_logits=return_logits)
 
     def decode_step(self, params, tok, pos, active, arena, tables, attn_fn=None):
-        from nnstreamer_tpu.kv import block_attn as kvb
-
         return kvb.batched_decode_step_block(
             params, tok, pos, active, arena, tables, self.n_heads,
             self.compute_dtype, attn_fn=attn_fn) + (None,)

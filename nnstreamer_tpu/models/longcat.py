@@ -550,7 +550,7 @@ class LongcatFamily:
     decode_kernel = "mla_paged_decode_attention"
     # what the paged path offers and this family does not carry yet
     unsupported = ("speculate", "cache-dtype=int8", "kv-layout=slot", "windowed",
-                   "mesh", "draft model", "kv-attn=gather", "migration",
+                   "mesh", "draft model", "migration",
                    "snapshot")
 
     def __init__(self, config: LongcatConfig, dtype):

@@ -51,26 +51,22 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 from nnstreamer_tpu import trace as _trace
 from nnstreamer_tpu.compile_cache import ensure_compile_cache
+from nnstreamer_tpu.kv import block_attn as kvb
+from nnstreamer_tpu.kv import gather as kvg
+from nnstreamer_tpu.kv.blocks import BlockPool
+from nnstreamer_tpu.kv.gather import dequantize_kv, quantize_kv
+from nnstreamer_tpu.kv.sched import SLOLedger
 from nnstreamer_tpu.models import decode as dec
 from nnstreamer_tpu.models import transformer as tfm
+from nnstreamer_tpu.models.family import DenseFamily, refuse_unsupported
 from nnstreamer_tpu.models.speculative import ngram_lookup
-
-
-def quantize_kv(t):
-    """[..., H, Dh] float → (int8 same shape, f32 scale [..., H]).
-    Per-token-per-head symmetric scales keep the error tight without
-    storing more than 1/Dh extra floats — the cache shrinks 4× vs f32
-    (2× vs bf16), which is more live slots or longer contexts per chip."""
-    m = jnp.maximum(jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1), 1e-8)
-    scale = m / 127.0
-    q = jnp.clip(jnp.round(t.astype(jnp.float32) / scale[..., None]), -127, 127)
-    return q.astype(jnp.int8), scale
-
-
-def dequantize_kv(q, scale):
-    return q.astype(jnp.float32) * scale[..., None]
+from nnstreamer_tpu.obs import metrics as _obs_metrics
+from nnstreamer_tpu.ops.dispatch import record as _record_dispatch
+from nnstreamer_tpu.parallel.mesh import batch_sharding
 
 
 def batched_decode_step(
@@ -890,6 +886,430 @@ def _make_admit(max_len: int, vec_sh=None):
     )
 
 
+def nns_sample_first(logits, temp, topk, topp, key, fill):
+    """First-token pick: the step's device sampler over the prefill logits.
+    Its arguments are host (numpy) values shipped by the call, the request
+    key folded with the position inside the program: no eager launch
+    builds them."""
+    return sample_tokens(
+        logits[None, :], temp, topk, topp,
+        jax.random.fold_in(key, fill)[None],
+    )[0]
+
+
+def nns_load_prefix(stage, ks, vs):
+    return (
+        jax.lax.dynamic_update_slice(stage[0], ks, (0, 0, 0, 0, 0)),
+        jax.lax.dynamic_update_slice(stage[1], vs, (0, 0, 0, 0, 0)),
+    )
+
+
+def nns_adopt_scatter(leaf, ids, vals):
+    return leaf.at[:, ids].set(vals)
+
+
+def _prefill_programs(family, weights, windowed: bool):
+    """The prompt programs, the family's own (the dense family's are
+    dec.prefill / dec.verify_chunk, as ever): one bucket from position 0,
+    one bucket at ``cpos`` against a staging cache with and without the
+    vocab head, and — on a windowed batcher, None elsewhere — the two exact
+    sliding-window ring chunks for prompts of ANY length in the fixed W
+    ring."""
+    def wjit(fn, **kw):
+        return _weights_jit(fn, weights, **kw)
+
+    prefill = wjit(
+        lambda w, toks: family.prefill(w[0], toks), name="nns_prefill",
+    )
+    chunk = wjit(
+        lambda w, toks, cpos, cache: family.chunk(w[0], toks, cpos, cache),
+        donate_argnums=2, name="nns_prefill_chunk",
+    )
+    advance = wjit(
+        lambda w, toks, cpos, cache: family.chunk(
+            w[0], toks, cpos, cache, return_logits=False,
+        )[1],
+        donate_argnums=2, name="nns_prefill_chunk_nologits",
+    )
+    if not windowed:
+        return prefill, chunk, advance, None, None
+    ring_chunk = wjit(
+        lambda w, toks, cpos, n, cache: dec.windowed_chunk(
+            w[0], toks, cpos, n, cache, family.n_heads,
+            compute_dtype=family.compute_dtype,
+        )[:2],
+        donate_argnums=3, name="nns_prefill_ring",
+    )
+    ring_advance = wjit(
+        lambda w, toks, cpos, n, cache: dec.windowed_chunk(
+            w[0], toks, cpos, n, cache, family.n_heads,
+            compute_dtype=family.compute_dtype, return_logits=False,
+        )[1],
+        donate_argnums=3, name="nns_prefill_ring_nologits",
+    )
+    return prefill, chunk, advance, ring_chunk, ring_advance
+
+
+# ---- the decode programs: one body, three builders, over a cache layout ----
+#
+# A *layout* is where the KV cache lives and how a forward reads and writes
+# it; it is all that differs between ``kv_layout="slot"`` and ``"paged"``.
+# In a program it answers ``forward`` / ``verify`` / ``propose`` over
+# ``carried`` (the cache pytree the program carries and DONATES) and
+# ``fixed`` (what it only reads, never donated); on the host it hands the
+# batcher ``b`` those two (``args``), takes the carried tree back
+# (``commit``), advances queued prompts before a launch
+# (``advance_prefill``) and makes room for the tokens the launch may write
+# (``ensure_room``). Every decode program has the signature
+# ``(w, …per-slot vectors…, carried, hist, …, *fixed, <static sizes>)``
+# whatever the layout, so the entry points build one argument tuple and
+# unpack one result.
+
+
+def decode_token(logits, tok, pos2, active, hist, budget, stop, temp, topk,
+                 topp, keys, sampling: bool, wrap: bool):
+    """What one decoded token does to the per-slot state, after the
+    forward: pick it, keep idle lanes, record it, spend budget, stop.
+
+    logits [B, V] at fill ``pos2`` [B] → (tok' [B], emit [B] with -1 on
+    idle lanes, hist', budget', active'). ``sampling`` is static: the
+    greedy-only program compiles without the filtering/PRNG work."""
+    with jax.named_scope("nns.sample"):
+        if sampling:
+            # per-slot key = fold_in(base, fill level): token streams are
+            # deterministic per (seed, position), independent of batch
+            # composition
+            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
+            new = sample_tokens(logits, temp, topk, topp, sub)
+        else:
+            new = jnp.argmax(logits, -1).astype(jnp.int32)
+    new = jnp.where(active, new, tok)
+    emit = jnp.where(active, new, -1)
+    hist = hist_write_row(
+        hist, new[:, None], pos2, active.astype(jnp.int32), wrap=wrap
+    )
+    budget = budget - active.astype(jnp.int32)
+    active = active & (budget > 0) & ~((new == stop) & (stop >= 0))
+    return new, emit, hist, budget, active
+
+
+class _Layout:
+    """What the two layouts share: prompt-lookup proposals off the device
+    history, and a host side with nothing to do."""
+
+    windowed = False
+
+    def propose(self, w, tok, pos, active, carried, hist, k: int, g: int):
+        """k-1 proposals per slot for one speculative round →
+        (props [B, k-1], carried')."""
+        return device_ngram_propose(
+            hist, pos, k, g, wrap=self.windowed
+        ), carried
+
+    def shard(self, impl):
+        return impl
+
+    def advance_prefill(self, b) -> None:
+        pass
+
+    def ensure_room(self, b, n: int) -> None:
+        pass
+
+    def live_blocks(self, b) -> int:
+        return 0
+
+
+class _SlotLayout(_Layout):
+    """One contiguous ``[L, n_slots, max_len, KV, Dh]`` cache per K and V
+    (int8 payload + scale pairs when quantized), every slot sized for the
+    worst case; a ring over the last ``max_len`` tokens when ``windowed``.
+    Carries ``(cache, draft cache or None)``: where there is a draft model
+    its cache steps in lock-step inside the same programs."""
+
+    def __init__(self, family, n_slots: int, max_len: int, quantized: bool,
+                 windowed: bool = False, attn_fn=None,
+                 draft_n_heads: Optional[int] = None, mesh=None,
+                 slots_axis: str = "dp"):
+        self.family = family
+        self.windowed = windowed
+        self.attn_fn = attn_fn
+        self.draft_n_heads = draft_n_heads  # None: no draft model
+        self.mesh, self.slots_axis = mesh, slots_axis
+        self.quantized = quantized
+        self.shape = (family.n_layers, n_slots, max_len, family.n_kv_heads,
+                      family.head_dim)
+        self.ring_shape = self.shape[:1] + (1,) + self.shape[2:]
+
+    def init_cache(self):
+        if self.quantized:
+            return tuple(
+                (jnp.zeros(self.shape, jnp.int8),
+                 jnp.ones(self.shape[:-1], jnp.float32))
+                for _ in range(2)
+            )
+        dt = self.family.compute_dtype
+        return jnp.zeros(self.shape, dt), jnp.zeros(self.shape, dt)
+
+    def _step(self, params, n_heads, tok, pos, active, cache, attn_fn=None,
+              windowed=False):
+        return batched_decode_step(
+            params, tok, pos, active, cache, n_heads,
+            self.family.compute_dtype, attn_fn=attn_fn, windowed=windowed,
+        )
+
+    def forward(self, w, tok, pos, active, carried, fixed):
+        cache, dcache = carried
+        if self.draft_n_heads is not None:
+            # the draft ingests the pending token's K/V in lockstep with
+            # the target: a hole at a plainly decoded position would have
+            # every later propose() condition on garbage K/V there, and
+            # acceptance would silently collapse for the rest of the
+            # generation
+            _, dcache, _ = self._step(
+                w[1], self.draft_n_heads, tok, pos, active, dcache,
+                windowed=self.windowed,
+            )
+        logits, cache, pos2 = self._step(
+            w[0], self.family.n_heads, tok, pos, active, cache,
+            self.attn_fn, self.windowed,
+        )
+        return logits, (cache, dcache), pos2, None
+
+    def verify(self, w, toks, pos, active, carried, fixed):
+        """Score the chunks → (logits [B, k, V], commit); ``commit(m)``
+        gives the carried cache once the accepted counts are known (a ring
+        lands only accepted columns, so rejected proposals never clobber
+        window history; a linear cache was written by the forward)."""
+        cache, dcache = carried
+        f = self.family
+        if self.windowed:
+            logits, cks, cvs = batched_windowed_verify(
+                w[0], toks, pos, active, cache, f.n_heads, f.compute_dtype
+            )
+            return logits, lambda m: (
+                commit_ring_chunk(cache, cks, cvs, pos, m, active), dcache
+            )
+        logits, cache = batched_verify_step(
+            w[0], toks, pos, active, cache, f.n_heads, f.compute_dtype
+        )
+        return logits, lambda m: (cache, dcache)
+
+    def propose(self, w, tok, pos, active, carried, hist, k: int, g: int):
+        if self.draft_n_heads is None or self.windowed:
+            return super().propose(w, tok, pos, active, carried, hist, k, g)
+        # k greedy draft steps: k-1 proposals + the k-th write (the
+        # full-acceptance K/V invariant, _DraftEngine.propose)
+        cache, dc = carried
+        cur, p, outs = tok, pos, []
+        for _ in range(k):
+            dlg, dc, p = self._step(
+                w[1], self.draft_n_heads, cur, p, active, dc
+            )
+            cur = jnp.argmax(dlg, -1).astype(jnp.int32)
+            outs.append(cur)
+        return jnp.stack(outs[: k - 1], axis=1), (cache, dc)
+
+    def shard(self, impl):
+        """The pump over a slot-sharded mesh with the kernel inline. GSPMD
+        cannot partition the kernel's custom call over the slot-sharded
+        cache — but the scan is slot-parallel by construction, so
+        shard_map IS the partition: each device pumps its local slots."""
+        if self.mesh is None or self.attn_fn is None:
+            return impl
+        vec, cac = P(self.slots_axis), P(None, self.slots_axis)
+
+        def sharded(w, tok, pos, active, carried, hist, budget, stop, temp,
+                    topk, topp, keys, n_steps):
+            return jax.shard_map(
+                functools.partial(impl, n_steps=n_steps), mesh=self.mesh,
+                # the weights (first) stay replicated on every device
+                in_specs=(P(), vec, vec, vec, (cac, cac)) + (vec,) * 7,
+                out_specs=(vec, vec, vec, vec, (cac, cac), vec, vec),
+                check_vma=False,
+            )(w, tok, pos, active, carried, hist, budget, stop, temp, topk,
+              topp, keys)
+
+        return sharded
+
+    def args(self, b):
+        draft = b._draft._cache if b._draft is not None else None
+        return (b._cache, draft), ()
+
+    def commit(self, b, carried) -> None:
+        b._cache, dcache = carried
+        if b._draft is not None:
+            b._draft._cache = dcache
+
+
+class _PagedLayout(_Layout):
+    """The block arena behind per-slot block tables (nnstreamer_tpu/kv/):
+    the forward is the family's, straight off the arena through the
+    tables; the arena is carried and donated, the tables are only read (the
+    cached device copy is reused across pumps)."""
+
+    def __init__(self, family, attn_fn=None):
+        self.family = family
+        self.attn_fn = attn_fn
+
+    def forward(self, w, tok, pos, active, arena, fixed):
+        return self.family.decode_step(
+            w[0], tok, pos, active, arena, fixed[0], attn_fn=self.attn_fn
+        )
+
+    def verify(self, w, toks, pos, active, arena, fixed):
+        # inline XLA attention, like the slot layout's verify
+        f = self.family
+        logits, arena = kvb.batched_verify_step_block(
+            w[0], toks, pos, active, arena, fixed[0], f.n_heads,
+            f.compute_dtype,
+        )
+        return logits, lambda m: arena
+
+    def args(self, b):
+        return b._cache, (b._tables_device_locked(),)
+
+    def commit(self, b, arena) -> None:
+        b._cache = arena
+
+    def advance_prefill(self, b) -> None:
+        b._advance_prefill()
+
+    def ensure_room(self, b, n: int) -> None:
+        b._ensure_decode_room_locked(n)
+
+    def live_blocks(self, b) -> int:
+        return b._live_blocks_locked()
+
+
+# carried and hist, counted with the weights first: aliased outputs update
+# in place, so the carried state never has two live copies
+_DONATE = (4, 5)
+
+
+def make_pump(layout, sampling: bool):
+    """``n_steps`` tokens per program launch: ``lax.scan`` carries
+    (tok, pos, active, cache, hist, budget) on device, deactivates slots at
+    budget/stop-token inside the scan, and emits -1 for idle lanes — one
+    dispatch and ONE readback per pump, ``[B, n]`` tokens (flattened, with
+    the family's counters summed over the steps behind them, where the
+    family has any). Budget and the active mask ride back out so the host
+    carries them on device across pumps instead of re-shipping its state.
+    Jitted with the weights as first argument."""
+
+    def impl(w, tok, pos, active, carried, hist, budget, stop, temp, topk,
+             topp, keys, *fixed, n_steps):
+        def body(c, _):
+            tok, pos, active, carried, hist, budget = c
+            logits, carried, pos2, aux = layout.forward(
+                w, tok, pos, active, carried, fixed
+            )
+            new, emit, hist, budget, active = decode_token(
+                logits, tok, pos2, active, hist, budget, stop, temp, topk,
+                topp, keys, sampling, layout.windowed,
+            )
+            return (new, pos2, active, carried, hist, budget), (emit, aux)
+
+        c, (emits, aux) = jax.lax.scan(
+            body, (tok, pos, active, carried, hist, budget), None,
+            length=n_steps,
+        )
+        emits = emits.T
+        if aux is not None:  # the family's counters ride the one readback
+            emits = jnp.concatenate(
+                [emits.reshape(-1), jnp.sum(aux, axis=0)]
+            )
+        return (emits,) + c
+
+    # the module must stay ``jit_impl`` on a device trace: benchmark/configs/
+    # *.json select the decode launches by that name (trace_names.decode)
+    # and decode_step_ms, decode_hbm_roofline_pct, step_mfu_pct and
+    # prefill_ms_per_pump divide by their count. Renaming it is a benchmark
+    # PR's (ROADMAP S8).
+    return jax.jit(
+        _named(layout.shard(impl), "impl"), donate_argnums=_DONATE,
+        static_argnames=("n_steps",),
+    )
+
+
+def _spec_round(layout, w, toks, pos, active, carried, fixed, hist, temp,
+                topk, topp, keys, sampling: bool):
+    """One speculative round = verify + device-side acceptance (+ the
+    layout's commit of accepted columns) + the emitted row into the
+    history. Only [B] m-counts and [B] final tokens are its results —
+    never [B, k, V] logits (sampling acceptance needs the full
+    distributions, which at a 32k+ vocab must not ship per round)."""
+    logits, commit = layout.verify(w, toks, pos, active, carried, fixed)
+    m, final = spec_accept(
+        logits, toks, temp, topk, topp, keys, pos, sampling
+    )
+    m = jnp.where(active, m, 0)
+    carried = commit(m)
+    emit, hist = spec_emit_hist(
+        toks, m, final, active, hist, pos, layout.windowed
+    )
+    return m, final, carried, hist, pos + m, emit
+
+
+def make_spec_round(layout, sampling: bool):
+    """The host-proposed round (spec_step); jit caches one program per
+    distinct chunk width."""
+
+    def impl(w, toks, pos, active, carried, hist, temp, topk, topp, keys,
+             *fixed):
+        return _spec_round(
+            layout, w, toks, pos, active, carried, fixed, hist, temp, topk,
+            topp, keys, sampling,
+        )[:5]
+
+    return jax.jit(impl, donate_argnums=_DONATE)
+
+
+def make_spec_pump(layout, sampling: bool):
+    """``rounds`` whole propose→verify→accept→commit rounds per program
+    launch (proposals from the layout: the device history's n-grams, or an
+    in-scan draft model), shipping ONE packed int32 vector back:
+    [B·R·k emitted tokens ‖ accepted-count ‖ proposal-columns]. Acceptance
+    telemetry therefore costs no extra transfer."""
+
+    def impl(w, tok, pos, active, carried, hist, budget, stop, temp, topk,
+             topp, keys, *fixed, rounds, k, g):
+        def body(c, _):
+            tok, pos, active, carried, hist, budget, acc, cols = c
+            props, carried = layout.propose(
+                w, tok, pos, active, carried, hist, k, g
+            )
+            props = jnp.where(active[:, None], props, -1)
+            toks = jnp.concatenate([tok[:, None], props], axis=1)
+            m, final, carried, hist, pos2, emit = _spec_round(
+                layout, w, toks, pos, active, carried, fixed, hist, temp,
+                topk, topp, keys, sampling,
+            )
+            acc = acc + jnp.sum(jnp.maximum(m - 1, 0))
+            cols = cols + jnp.sum((props >= 0).astype(jnp.int32))
+            budget = budget - m
+            hit_stop = jnp.any(
+                (emit == stop[:, None]) & (stop[:, None] >= 0), axis=1
+            )
+            active = active & (budget > 0) & ~hit_stop
+            tok = jnp.where(m > 0, final, tok)
+            return (tok, pos2, active, carried, hist, budget, acc,
+                    cols), emit
+
+        zero = jnp.zeros((), jnp.int32)
+        c, emits = jax.lax.scan(
+            body, (tok, pos, active, carried, hist, budget, zero, zero),
+            None, length=rounds,
+        )
+        packed = jnp.concatenate([
+            jnp.transpose(emits, (1, 0, 2)).reshape(-1), jnp.stack(c[6:]),
+        ])
+        return (packed,) + c[:6]
+
+    return jax.jit(
+        impl, donate_argnums=_DONATE, static_argnames=("rounds", "k", "g"),
+    )
+
+
 class _DraftEngine:
     """Batched draft-model proposer for spec_step: ONE small model
     stepping ALL active slots greedily k-1 times per round, with its own
@@ -1036,16 +1456,6 @@ class _DraftEngine:
         self._cache = cache
         return np.stack([np.asarray(c) for c in props[: k - 1]], axis=1)
 
-    def advance_one(self, tok, pos, active) -> None:
-        """Write the pending tokens' K/V into the draft cache WITHOUT
-        proposing — the sync path for rounds the target advances by a
-        plain step (no chunk room, nothing proposed, or a direct
-        step() call on a draft batcher). Skipping it would leave
-        permanent holes at the plain-stepped positions: every later
-        propose() would condition on garbage K/V there and acceptance
-        would silently collapse for the rest of the generation."""
-        _, self._cache, _ = self._step(tok, pos, active, self._cache)
-
 
 class BatcherFailedError(RuntimeError):
     """The batcher's device state is invalid: a step/pump launch raised
@@ -1090,7 +1500,6 @@ class ContinuousBatcher:
         block_size: int = 16,
         kv_blocks: Optional[int] = None,
         prefill_chunks: int = 1,
-        kv_attn: str = "auto",
         family=None,
     ):
         """``windowed=True`` makes max_len a sliding attention window
@@ -1101,8 +1510,8 @@ class ContinuousBatcher:
 
         ``attn_impl`` picks the decode attention: ``"xla"``,
         ``"pallas"`` (the decode kernels of ops/pallas), or unset
-        (``""``): the block-table kernel under the block-native paged
-        layout on a TPU backend where the registry passes the arena
+        (``""``): the block-table kernel under the paged layout on a
+        TPU backend where the registry passes the arena
         dtype — the rule of ``kv.block_attn.block_attention
         (impl="auto")`` — and ``"xla"`` everywhere else, so off-TPU the
         default stays the bit-pinned XLA formulation. ``stats()``
@@ -1110,7 +1519,7 @@ class ContinuousBatcher:
 
         The full feature matrix composes: attn_impl="pallas" works with
         cache_dtype="int8" (the kernel takes the scale operands and
-        dequantizes in VMEM), with mesh= (the step program is wrapped in
+        dequantizes in VMEM), with mesh= (the pump is wrapped in
         shard_map over the slot axis, so each device runs the kernel on
         its local slots), and with windowed=True.
 
@@ -1124,16 +1533,12 @@ class ContinuousBatcher:
         and commits only accepted columns — the same verify-then-commit
         discipline the target uses (see _DraftEngine).
 
-        ``kv_attn`` selects the PAGED decode formulation
-        (docs/llm-serving.md): ``"auto"``/``"block"`` attend the block
-        arena directly through the block tables and write each decoded
-        token in place into its owning block (kv/block_attn.py — no
-        gathered view, the default); ``"gather"`` keeps the
-        gather→contiguous-view→scatter oracle (kv/gather.py) for
-        debugging/parity at the cost of a transient HBM doubling.
-        Both are bitwise identical to the slot layout. Paged composes
-        with ``attn_impl="pallas"`` via the block-table kernel
-        (ops/pallas/paged_attention.py) — block-native only.
+        ``kv_layout="paged"`` keeps the cache as a block arena behind
+        per-slot block tables (docs/llm-serving.md): decode attends the
+        arena through the tables and writes each token in place into its
+        owning block (kv/block_attn.py), bitwise identical to the slot
+        layout; ``attn_impl="pallas"`` there is the block-table kernel
+        (ops/pallas/paged_attention.py).
 
         ``family`` is the block family the paged path serves
         (models/family.py): what a token leaves in the cache and how a
@@ -1142,8 +1547,6 @@ class ContinuousBatcher:
         leaves, prefill and decode programs, and refuses by name what it
         does not carry."""
         ensure_compile_cache()
-        from nnstreamer_tpu.models.family import DenseFamily, refuse_unsupported
-
         if family is None:
             family = DenseFamily(params, n_heads, prompt_len, compute_dtype)
         refuse_unsupported(family, {
@@ -1152,7 +1555,6 @@ class ContinuousBatcher:
             "windowed": windowed,
             "mesh": mesh is not None,
             "draft model": draft_params is not None,
-            "kv-attn=gather": kv_attn == "gather",
         })
         self._family = family
         if prompt_len > max_len:
@@ -1162,33 +1564,21 @@ class ContinuousBatcher:
         quantized_cache = cache_dtype == "int8"
         if kv_layout not in ("slot", "paged"):
             raise ValueError(f"unknown kv_layout {kv_layout!r}")
-        if kv_attn not in ("auto", "block", "gather"):
-            raise ValueError(f"unknown kv_attn {kv_attn!r}")
+        if attn_impl not in ("", "xla", "pallas"):
+            raise ValueError(f"unknown attn_impl {attn_impl!r}")
         self._paged = kv_layout == "paged"
-        self._kv_attn = ""
         if self._paged:
             # paged KV (nnstreamer_tpu/kv/, docs/llm-serving.md): the
-            # cache is a block arena behind per-slot block tables.
-            # kv_attn selects the decode formulation: "block" (the
-            # "auto" default) attends DIRECTLY against the arena
-            # through the block table and writes the decoded token in
-            # place into its single owning block (kv/block_attn.py —
-            # no contiguous view in either direction); "gather" keeps
-            # the gather→slot-step→scatter oracle (kv/gather.py) for
-            # debugging/parity. Both are bitwise identical to the slot
-            # layout (tests/test_kv_paged.py, tests/test_kv_block_attn
-            # .py). The windowed ring, slot-sharded meshes and draft
-            # models keep the slot layout for now.
-            self._kv_attn = "block" if kv_attn == "auto" else kv_attn
+            # cache is a block arena behind per-slot block tables, which
+            # decode attends and writes in place (kv/block_attn.py),
+            # bitwise identical to the slot layout (tests/test_kv_paged.py,
+            # tests/test_kv_block_attn.py). The windowed ring,
+            # slot-sharded meshes and draft models keep the slot layout
+            # for now.
             for flag, why in (
                 (windowed, "windowed (ring) caches"),
                 (mesh is not None, "mesh-sharded slots"),
                 (draft_params is not None, "draft models"),
-                (attn_impl not in ("", "xla", "pallas"),
-                 f"attn_impl={attn_impl!r}"),
-                (attn_impl == "pallas" and self._kv_attn == "gather",
-                 "attn_impl='pallas' with kv_attn='gather' (the paged "
-                 "kernel is block-native — drop kv_attn='gather')"),
             ):
                 if flag:
                     raise ValueError(
@@ -1207,22 +1597,14 @@ class ContinuousBatcher:
                     f"prompt_len({prompt_len}) so staged prefill chunks "
                     "land on block boundaries"
                 )
-        elif kv_attn != "auto":
-            raise ValueError(
-                "kv_attn selects the paged decode formulation; the slot "
-                "layout has no block table to attend through"
-            )
-        paged_attn_fn = None
-        from nnstreamer_tpu.ops.dispatch import record as _record_dispatch
-
         if not attn_impl:
             # unset: the block-table kernel where it is the measured
-            # fast path (PERF.md, PR 26) — block-native paged decode on
-            # a TPU backend — subject to the registry gate below; the
-            # XLA formulation everywhere else
+            # fast path (PERF.md, PR 26) — paged decode on a TPU backend —
+            # subject to the registry gate below; the XLA formulation
+            # everywhere else
             attn_impl = (
                 "pallas"
-                if self._kv_attn == "block" and jax.default_backend() == "tpu"
+                if self._paged and jax.default_backend() == "tpu"
                 and family.decode_kernel is not None
                 else "xla"
             )
@@ -1241,29 +1623,21 @@ class ContinuousBatcher:
             )[0]
             if not ok:
                 attn_impl = "xla"
-        _record_dispatch(
-            "serving_attention",
-            "pallas" if attn_impl == "pallas" else "xla",
-        )
-        if attn_impl == "pallas":
-            if self._paged:
-                # the block-table kernel: attends the arena through the
-                # prefetched tables, one block per grid step, no
-                # gathered view (ops/pallas/paged_attention.py); the
-                # spec verify keeps inline XLA attention exactly like
-                # the slot layout's Pallas batchers
-                paged_attn_fn = family.make_attention()
-                attn_fn = None
-            else:
-                from nnstreamer_tpu.ops.pallas.decode_attention import (
-                    make_decode_attention,
-                )
+        _record_dispatch("serving_attention", attn_impl)
+        attn_fn = None
+        if attn_impl == "pallas" and self._paged:
+            # the block-table kernel: attends the arena through the
+            # prefetched tables, one block per grid step
+            # (ops/pallas/paged_attention.py); the spec verify keeps
+            # inline XLA attention exactly like the slot layout's Pallas
+            # batchers
+            attn_fn = family.make_attention()
+        elif attn_impl == "pallas":
+            from nnstreamer_tpu.ops.pallas.decode_attention import (
+                make_decode_attention,
+            )
 
-                attn_fn = make_decode_attention()
-        elif attn_impl == "xla":
-            attn_fn = None
-        else:
-            raise ValueError(f"unknown attn_impl {attn_impl!r}")
+            attn_fn = make_decode_attention()
         self.params = params
         self.n_heads = n_heads
         self.n_slots = n_slots
@@ -1287,24 +1661,21 @@ class ContinuousBatcher:
 
         # nns-obs: the SLO histograms + paged-pool gauges emit through
         # the registry resolved ONCE here (the FaultGate discipline)
-        from nnstreamer_tpu.obs import metrics as _obs_metrics
-
         self._obs_reg = _obs_metrics.get()
-        from nnstreamer_tpu.kv.sched import SLOLedger
-
         self._slo = SLOLedger(keep=keep_results, obs_registry=self._obs_reg)
 
-        dense = isinstance(family, DenseFamily)
-        if dense:
-            L, hd, kv = family.n_layers, family.head_dim, family.n_kv_heads
-            shape = (L, n_slots, max_len, kv, hd)
+        self._draft = (
+            _DraftEngine(
+                draft_params, draft_n_heads or n_heads, n_slots, max_len,
+                prompt_len, compute_dtype, windowed=windowed,
+            )
+            if draft_params is not None else None
+        )
+        # chunked-prefill jobs waiting for their next bucket (paged; the
+        # slot layout prefills inside submit and queues nothing)
+        self._prefill_q: deque = deque()
         if self._paged:
-            from nnstreamer_tpu.kv import block_attn as _kvb
-            from nnstreamer_tpu.kv import gather as _kvg
-            from nnstreamer_tpu.kv.blocks import BlockPool
-
-            self._kvg = _kvg
-            self._kvb = _kvb
+            self._layout = _PagedLayout(family, attn_fn)
             self.block_size = block_size
             self._blocks_per_slot = max_len // block_size
             if kv_blocks is None:
@@ -1332,42 +1703,35 @@ class ContinuousBatcher:
             self._tables_dev = jnp.asarray(self._tables)
             self._tables_dirty = False
             self._write_block, self._read_block, self._copy_block = (
-                _kvg.make_paged_ops(quantized_cache, compute_dtype)
+                kvg.make_paged_ops(quantized_cache, compute_dtype)
+            )
+            # coalesced admission staging (kv/gather.make_staging_ops):
+            # prefix seeding and block landing as ONE program each —
+            # the per-block read/write launches used to dominate paged
+            # admission latency on short decode budgets
+            self._seed_stage, self._land_stage = kvg.make_staging_ops(
+                quantized_cache, compute_dtype
             )
             # live migration (kv/migrate.py): raw per-leaf block scatter
             # — donated like every other arena mutator, and bypassing
             # the quantize/dequantize in write_block/read_block so an
             # int8 span lands the exact bytes the source held
-            self._adopt_scatter = jax.jit(
-                _named(
-                    lambda leaf, ids, vals: leaf.at[:, ids].set(vals),
-                    "nns_adopt_scatter",
-                ),
-                donate_argnums=0,
-            )
+            self._adopt_scatter = jax.jit(nns_adopt_scatter, donate_argnums=0)
             self._quantized = quantized_cache
             self._n_migrations_out = 0
             self._n_migrations_in = 0
             self._n_resumes = 0
             self._n_prefill_chunk_programs = 0
-            self._prefill_q: deque = deque()
             self._prefill_chunks = max(1, int(prefill_chunks))
             self._prefixes_paged: Dict[int, Tuple[np.ndarray, List[int]]] = {}
         else:
+            self._layout = _SlotLayout(
+                family, n_slots, max_len, quantized_cache, windowed, attn_fn,
+                self._draft.n_heads if self._draft is not None else None,
+                mesh, slots_axis,
+            )
             self._pool = None
-            if quantized_cache:
-                sshape = shape[:-1]
-                self._cache = (
-                    (jnp.zeros(shape, jnp.int8),
-                     jnp.ones(sshape, jnp.float32)),
-                    (jnp.zeros(shape, jnp.int8),
-                     jnp.ones(sshape, jnp.float32)),
-                )
-            else:
-                self._cache = (
-                    jnp.zeros(shape, compute_dtype),
-                    jnp.zeros(shape, compute_dtype),
-                )
+            self._cache = self._layout.init_cache()
         self._tok = jnp.zeros((n_slots,), jnp.int32)
         self._pos = jnp.zeros((n_slots,), jnp.int32)
         self._active = np.zeros((n_slots,), bool)
@@ -1397,15 +1761,16 @@ class ContinuousBatcher:
         self._pump_state_dirty = True
         self._host_state_builds = 0  # regression-test observable
 
+        # every program that runs a model takes the weights as its
+        # first ARGUMENT (_weights_jit) — (target, draft) — never as a
+        # closed-over constant
+        weights = (params, draft_params)
+        self._vec_sh = None
         if mesh is not None:
             # shard the slot axis over the mesh: the batched step runs
             # SPMD with each device decoding its share of the slots (the
             # data-parallel serving layout; params stay replicated, so
             # the only cross-device traffic is the host-driven admit)
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            from nnstreamer_tpu.parallel.mesh import batch_sharding
-
             n_mesh = mesh.shape[slots_axis]
             if n_slots % n_mesh:
                 raise ValueError(
@@ -1413,670 +1778,43 @@ class ContinuousBatcher:
                     f"{slots_axis!r} (size {n_mesh})"
                 )
             cache_sh = NamedSharding(mesh, P(None, slots_axis))
-            vec_sh = batch_sharding(mesh, slots_axis)
-            self._vec_sh = vec_sh
+            self._vec_sh = batch_sharding(mesh, slots_axis)
             self._cache = jax.tree_util.tree_map(
                 lambda c: jax.device_put(c, cache_sh), self._cache
             )
-            self._tok = jax.device_put(self._tok, vec_sh)
-            self._pos = jax.device_put(self._pos, vec_sh)
-            self._temp = jax.device_put(self._temp, vec_sh)
-            self._topk = jax.device_put(self._topk, vec_sh)
-            self._topp = jax.device_put(self._topp, vec_sh)
-            self._keys = jax.device_put(self._keys, vec_sh)
-            self._hist = jax.device_put(self._hist, vec_sh)
-        else:
-            self._vec_sh = None
-
-        # every program that runs a model takes the weights as its
-        # first ARGUMENT (_weights_jit) — (target, draft) here, unpacked
-        # at the top of each impl — never as a closed-over constant
-        weights = (params, draft_params)
-        if mesh is not None:
+            (self._tok, self._pos, self._temp, self._topk, self._topp,
+             self._keys, self._hist) = (
+                self._pin(x) for x in (
+                    self._tok, self._pos, self._temp, self._topk,
+                    self._topp, self._keys, self._hist,
+                )
+            )
             # replicated over the mesh ONCE, here — an uncommitted
             # pytree would be re-placed on every call
             weights = jax.device_put(weights, NamedSharding(mesh, P()))
 
-        def wjit(fn, **kw):
-            return _weights_jit(fn, weights, **kw)
-
-        # the prompt and chunk programs are the family's (the dense family's
-        # are dec.prefill / dec.verify_chunk, as ever)
-        self._prefill = wjit(
-            lambda w, toks: family.prefill(w[0], toks),
-            name="nns_prefill",
+        (self._prefill, self._prefill_chunk, self._advance_chunk,
+         self._wchunk, self._wadvance) = _prefill_programs(
+            family, weights, windowed
         )
-        # chunked-prefill programs (prompts longer than the bucket): a
-        # staging cache padded to a bucket multiple — plus one spare
-        # bucket so chunk starts NOT aligned to the bucket (the prefix-
-        # caching path) still fit their full-width writes
+        # chunked prefill (prompts longer than the bucket) stages into a
+        # cache padded to a bucket multiple — plus one spare bucket so
+        # chunk starts NOT aligned to the bucket (the prefix-caching
+        # path) still fit their full-width writes
         self._stage_len = (-(-max_len // prompt_len) + 1) * prompt_len
-        if self._paged:
-            # coalesced admission staging (kv/gather.make_staging_ops):
-            # prefix seeding and block landing as ONE program each —
-            # the per-block read/write launches used to dominate paged
-            # admission latency on short decode budgets
-            self._seed_stage, self._land_stage = (
-                self._kvg.make_staging_ops(quantized_cache, compute_dtype)
-            )
-        self._prefill_chunk = wjit(
-            lambda w, toks, cpos, cache: family.chunk(w[0], toks, cpos, cache),
-            donate_argnums=2, name="nns_prefill_chunk",
-        )
-        self._advance_chunk = wjit(
-            lambda w, toks, cpos, cache: family.chunk(
-                w[0], toks, cpos, cache, return_logits=False,
-            )[1],
-            donate_argnums=2, name="nns_prefill_chunk_nologits",
-        )
-        # windowed (ring) chunked-prefill programs: exact sliding-window
-        # prefill for prompts of ANY length in the fixed W ring
-        self._ring_shape = (L, 1, max_len, kv, hd) if dense else None
-        self._wchunk = wjit(
-            lambda w, toks, cpos, n, cache: dec.windowed_chunk(
-                w[0], toks, cpos, n, cache, n_heads,
-                compute_dtype=compute_dtype,
-            )[:2],
-            donate_argnums=3, name="nns_prefill_ring",
-        )
-        self._wadvance = wjit(
-            lambda w, toks, cpos, n, cache: dec.windowed_chunk(
-                w[0], toks, cpos, n, cache, n_heads,
-                compute_dtype=compute_dtype, return_logits=False,
-            )[1],
-            donate_argnums=3, name="nns_prefill_ring_nologits",
-        )
-
-        def step_impl(sampling):
-            def impl(w, tok, pos, active, cache, hist, temp, topk, topp,
-                     keys):
-                logits, cache, pos2 = batched_decode_step(
-                    w[0], tok, pos, active, cache, n_heads,
-                    compute_dtype, attn_fn=attn_fn, windowed=windowed,
-                )
-                with jax.named_scope("nns.sample"):
-                    if sampling:
-                        # per-slot key = fold_in(base, fill level): token
-                        # streams are deterministic per (seed, position),
-                        # independent of batch composition
-                        sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                        new = sample_tokens(logits, temp, topk, topp, sub)
-                    else:
-                        new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                new = jnp.where(active, new, tok)
-                hist = hist_write_row(
-                    hist, new[:, None], pos2, active.astype(jnp.int32),
-                    wrap=windowed,
-                )
-                return new, cache, pos2, hist
-
-            return impl
-
-        # the cache (and hist) are DONATED into every step-shaped
-        # program: aliased outputs update in place, so donation halves
-        # the cache's HBM footprint — the carried state never has two
-        # live copies
-        _don = dict(donate_argnums=(3, 4))
-        if self._paged and self._kv_attn == "gather":
-            # gather oracle (kv_attn="gather"): gather the block arena
-            # into the SAME contiguous per-slot view the slot layout
-            # carries, run the IDENTICAL step body on it, then scatter
-            # only the written token's block back (inactive lanes route
-            # to scratch). Pays a transient [L,B,max_len,...] view
-            # beside the arena plus the scatter — kept as the
-            # debug/parity reference for the block-native default.
-            # tables (arg 4) is NOT donated — it is the cached device
-            # copy reused across pumps; arena (3) and hist (5) are.
-            _kvg = self._kvg
-
-            def paged_step(sampling):
-                inner = step_impl(sampling)
-
-                def impl(w, tok, pos, active, arena, tables, hist, temp,
-                         topk, topp, keys):
-                    view = _kvg.gather_cache(arena, tables)
-                    new, view, pos2, hist = inner(
-                        w, tok, pos, active, view, hist, temp, topk,
-                        topp, keys,
-                    )
-                    arena = _kvg.scatter_window(
-                        arena, tables, view, pos, 1, active
-                    )
-                    return new, arena, pos2, hist
-
-                return impl
-
-            _pgdon = dict(donate_argnums=(3, 5))
-            self._step_greedy = wjit(paged_step(False), **_pgdon)
-            self._step_sampling = wjit(paged_step(True), **_pgdon)
-        elif self._paged:
-            # block-native (kv_attn="block", the "auto" default): the
-            # step attends DIRECTLY against the arena through the block
-            # table and lands the decoded token's K/V with one width-1
-            # in-place block write under donation — zero gather_cache /
-            # scatter_window programs on the decode path (pinned by
-            # tests/test_kv_block_attn.py), bitwise identical to the
-            # gather oracle and hence the slot layout.
-            _kvb = self._kvb
-            _pg_attn = paged_attn_fn
-
-            def block_step(sampling):
-                def impl(w, tok, pos, active, arena, tables, hist, temp,
-                         topk, topp, keys):
-                    logits, arena, pos2, _ = family.decode_step(
-                        w[0], tok, pos, active, arena, tables,
-                        attn_fn=_pg_attn,
-                    )
-                    with jax.named_scope("nns.sample"):
-                        if sampling:
-                            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                            new = sample_tokens(logits, temp, topk, topp, sub)
-                        else:
-                            new = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                    new = jnp.where(active, new, tok)
-                    hist = hist_write_row(
-                        hist, new[:, None], pos2, active.astype(jnp.int32)
-                    )
-                    return new, arena, pos2, hist
-
-                return impl
-
-            _pgdon = dict(donate_argnums=(3, 5))
-            self._step_greedy = wjit(block_step(False), **_pgdon)
-            self._step_sampling = wjit(block_step(True), **_pgdon)
-        elif mesh is not None and attn_impl == "pallas":
-            # GSPMD cannot partition the kernel's custom call over the
-            # slot-sharded cache — but the step is slot-parallel by
-            # construction, so shard_map IS the partition: each device
-            # runs the whole step (kernel included) on its local slots
-            from jax.sharding import PartitionSpec as P
-
-            ax = slots_axis
-            vec, cac = P(ax), P(None, ax)
-            specs = dict(
-                # the weights (first) stay replicated on every device
-                in_specs=(P(), vec, vec, vec, cac, vec, vec, vec, vec, vec),
-                out_specs=(vec, cac, vec, vec),
-                check_vma=False,
-            )
-            self._step_greedy = wjit(
-                jax.shard_map(step_impl(False), mesh=mesh, **specs), **_don
-            )
-            self._step_sampling = wjit(
-                jax.shard_map(step_impl(True), mesh=mesh, **specs), **_don
-            )
-        else:
-            self._step_greedy = wjit(step_impl(False), **_don)
-            self._step_sampling = wjit(step_impl(True), **_don)
-
-        # ---- multi-step pumps: N tokens per program launch ----
-        # One dispatch + ONE [B, n] readback per pump instead of a
-        # dispatch + readback per token: lax.scan carries
-        # (tok, pos, active, cache, hist, budget) on device, deactivates
-        # slots at budget/stop-token inside the scan, and emits -1 for
-        # idle lanes. This amortizes the host↔device sync over n
-        # tokens and removes n-1 dispatches. Role-match: the per-token step loop of a serving
-        # engine collapsed into the compiled program, the token-world
-        # analogue of the converter's frames-per-tensor batching.
-        def pump_impl(sampling, with_draft):
-            def impl(w, tok, pos, active, cache, hist, budget, stop,
-                     temp, topk, topp, keys, dcache, n_steps):
-                params, draft_params = w
-
-                def body(carry, _):
-                    tok, pos, active, cache, hist, budget, dcache = carry
-                    if with_draft:
-                        # mirror advance_one: the draft ingests the
-                        # pending token's K/V in lockstep so later
-                        # spec rounds condition on a hole-free cache
-                        _, dcache, _ = batched_decode_step(
-                            draft_params, tok, pos, active, dcache,
-                            draft_n_heads or n_heads, compute_dtype,
-                            windowed=windowed,
-                        )
-                    logits, cache, pos2 = batched_decode_step(
-                        params, tok, pos, active, cache, n_heads,
-                        compute_dtype, attn_fn=attn_fn, windowed=windowed,
-                    )
-                    with jax.named_scope("nns.sample"):
-                        if sampling:
-                            sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                            new = sample_tokens(logits, temp, topk, topp, sub)
-                        else:
-                            new = jnp.argmax(logits, -1).astype(jnp.int32)
-                    new = jnp.where(active, new, tok)
-                    emit = jnp.where(active, new, -1)
-                    hist = hist_write_row(
-                        hist, new[:, None], pos2, active.astype(jnp.int32),
-                        wrap=windowed,
-                    )
-                    budget = budget - active.astype(jnp.int32)
-                    active = active & (budget > 0) & ~(
-                        (new == stop) & (stop >= 0)
-                    )
-                    return (
-                        new, pos2, active, cache, hist, budget, dcache,
-                    ), emit
-
-                carry, emits = jax.lax.scan(
-                    body, (tok, pos, active, cache, hist, budget, dcache),
-                    None, length=n_steps,
-                )
-                tok, pos, active, cache, hist, budget, dcache = carry
-                # budget rides back out so the host can carry it on
-                # device across pumps instead of re-shipping host state
-                return emits.T, tok, pos, active, cache, hist, budget, dcache
-
-            return impl
-
-        _pdon = dict(
-            donate_argnums=(3, 4, 11), static_argnames=("n_steps",)
-        )
-        _wd = draft_params is not None
-        if self._paged:
-            # paged pump: the scan steps through the (static-within-a-
-            # pump) block table; budget/stop/active are the device-
-            # carried pump state like everywhere else. kv_attn="gather"
-            # gathers/scatters per step (the oracle); the block-native
-            # default reads the arena through the table and writes the
-            # token's block in place — a steady pump dispatches ZERO
-            # gather/scatter programs.
-            _kvg = self._kvg
-            _kvb = self._kvb
-            _pg_attn = paged_attn_fn
-            _gather_pump = self._kv_attn == "gather"
-
-            def paged_pump_impl(sampling):
-                def impl(w, tok, pos, active, arena, tables, hist, budget,
-                         stop, temp, topk, topp, keys, n_steps):
-                    params = w[0]
-
-                    def body(carry, _):
-                        tok, pos, active, arena, hist, budget = carry
-                        aux = None
-                        if _gather_pump:
-                            view = _kvg.gather_cache(arena, tables)
-                            logits, view, pos2 = batched_decode_step(
-                                params, tok, pos, active, view, n_heads,
-                                compute_dtype, attn_fn=attn_fn,
-                            )
-                        else:
-                            logits, arena, pos2, aux = family.decode_step(
-                                params, tok, pos, active, arena, tables,
-                                attn_fn=_pg_attn,
-                            )
-                        with jax.named_scope("nns.sample"):
-                            if sampling:
-                                sub = jax.vmap(jax.random.fold_in)(keys, pos2)
-                                new = sample_tokens(
-                                    logits, temp, topk, topp, sub
-                                )
-                            else:
-                                new = jnp.argmax(logits, -1).astype(jnp.int32)
-                        new = jnp.where(active, new, tok)
-                        emit = jnp.where(active, new, -1)
-                        if _gather_pump:
-                            arena = _kvg.scatter_window(
-                                arena, tables, view, pos, 1, active
-                            )
-                        hist = hist_write_row(
-                            hist, new[:, None], pos2,
-                            active.astype(jnp.int32),
-                        )
-                        budget = budget - active.astype(jnp.int32)
-                        active = active & (budget > 0) & ~(
-                            (new == stop) & (stop >= 0)
-                        )
-                        return (
-                            new, pos2, active, arena, hist, budget,
-                        ), (emit if aux is None else (emit, aux))
-
-                    carry, emits = jax.lax.scan(
-                        body, (tok, pos, active, arena, hist, budget),
-                        None, length=n_steps,
-                    )
-                    tok, pos, active, arena, hist, budget = carry
-                    if family.aux_names:
-                        # the family's counters ride the one readback:
-                        # [B * n tokens ‖ counters summed over the steps]
-                        emits, aux = emits
-                        emits = jnp.concatenate(
-                            [emits.T.reshape(-1), jnp.sum(aux, axis=0)]
-                        )
-                        return emits, tok, pos, active, arena, hist, budget
-                    return emits.T, tok, pos, active, arena, hist, budget
-
-                return impl
-
-            _ppdon = dict(
-                donate_argnums=(3, 5), static_argnames=("n_steps",)
-            )
-            self._pump_greedy = wjit(paged_pump_impl(False), **_ppdon)
-            self._pump_sampling = wjit(paged_pump_impl(True), **_ppdon)
-        elif mesh is not None and attn_impl == "pallas":
-            # same shard_map partition as the single step: the scan is
-            # slot-parallel, each device pumps its local slots with the
-            # kernel inline
-            import functools as _ft
-
-            from jax.sharding import PartitionSpec as P
-
-            ax = slots_axis
-            vec, cac = P(ax), P(None, ax)
-            pspecs = dict(
-                in_specs=(P(), vec, vec, vec, cac, vec, vec, vec, vec,
-                          vec, vec, vec, cac),
-                out_specs=(vec, vec, vec, vec, cac, vec, vec, cac),
-                check_vma=False,
-            )
-
-            def _pump_sm(f):
-                def g(w, tok, pos, active, cache, hist, budget, stop,
-                      temp, topk, topp, keys, dcache, n_steps):
-                    return jax.shard_map(
-                        _ft.partial(f, n_steps=n_steps), mesh=mesh,
-                        **pspecs,
-                    )(w, tok, pos, active, cache, hist, budget, stop,
-                      temp, topk, topp, keys, dcache)
-
-                return g
-
-            self._pump_greedy = wjit(
-                _pump_sm(pump_impl(False, _wd)), **_pdon
-            )
-            self._pump_sampling = wjit(
-                _pump_sm(pump_impl(True, _wd)), **_pdon
-            )
-        else:
-            self._pump_greedy = wjit(pump_impl(False, _wd), **_pdon)
-            self._pump_sampling = wjit(pump_impl(True, _wd), **_pdon)
-        # first-token pick: same device sampler over the prefill logits.
-        # Its arguments are host (numpy) values shipped by the call, the
-        # request key folded with the position inside the program: no
-        # eager launch builds them
-        self._sample1 = jax.jit(_named(
-            lambda logits, temp, topk, topp, key, fill: sample_tokens(
-                logits[None, :], temp, topk, topp,
-                jax.random.fold_in(key, fill)[None],
-            )[0],
-            "nns_sample_first",
-        ))
+        self._sample1 = jax.jit(nns_sample_first)
         self._insert = jax.jit(insert_slot, donate_argnums=0)
         self._admit = _make_admit(max_len, self._vec_sh)
-
-        # one speculative round = verify + device-side acceptance (+ ring
-        # commit of accepted columns when windowed) in ONE program; jit
-        # caches one program per distinct chunk width. Only [B] m-counts
-        # and [B] final tokens cross to the host — never [B, k, V]
-        # logits (sampling acceptance needs the full distributions,
-        # which at a 32k+ vocab must not ship per round).
-        def spec_round_core(params, toks, pos_, active, cache, hist, temp,
-                            topk, topp, keys, spec_sampling):
-            if windowed:
-                logits, cks, cvs = batched_windowed_verify(
-                    params, toks, pos_, active, cache, n_heads,
-                    compute_dtype,
-                )
-            else:
-                logits, cache = batched_verify_step(
-                    params, toks, pos_, active, cache, n_heads,
-                    compute_dtype,
-                )
-            m, final = spec_accept(
-                logits, toks, temp, topk, topp, keys, pos_, spec_sampling
-            )
-            m = jnp.where(active, m, 0)
-            if windowed:
-                cache = commit_ring_chunk(cache, cks, cvs, pos_, m, active)
-            emit, hist = spec_emit_hist(
-                toks, m, final, active, hist, pos_, windowed
-            )
-            return m, final, cache, hist, pos_ + m, emit
-
-        def spec_round_impl(spec_sampling):
-            def impl(w, toks, pos_, active, cache, hist, temp, topk, topp,
-                     keys):
-                m, final, cache, hist, pos2, _ = spec_round_core(
-                    w[0], toks, pos_, active, cache, hist, temp, topk,
-                    topp, keys, spec_sampling,
-                )
-                return m, final, cache, hist, pos2
-
-            return impl
-
-        self._spec_round_greedy = wjit(spec_round_impl(False), **_don)
-        self._spec_round_sampling = wjit(spec_round_impl(True), **_don)
-
-        # ---- speculative pump: R spec rounds per program launch ----
-        # The host spec_step pays two device reads (pos, tok) plus
-        # Python n-gram mining per round; this scans R whole
-        # propose→verify→accept→commit rounds on device (proposals from
-        # device_ngram_propose, or an in-scan draft model stepping k
-        # times like _DraftEngine.propose) and ships ONE packed int32
-        # vector back: [B·R·k emitted tokens ‖ accepted-count ‖
-        # proposal-columns]. Acceptance telemetry therefore costs no
-        # extra transfer.
-        def spec_pump_impl(spec_sampling, use_draft):
-            def impl(w, tok, pos, active, cache, hist, budget, stop, temp,
-                     topk, topp, keys, dcache, rounds, k, g):
-                params, draft_params = w
-
-                def body(carry, _):
-                    (tok, pos, active, cache, hist, budget, dcache,
-                     acc, cols) = carry
-                    if use_draft:
-                        # k greedy draft steps: k-1 proposals + the
-                        # k-th write (full-acceptance K/V invariant,
-                        # _DraftEngine.propose)
-                        cur, p, dc = tok, pos, dcache
-                        outs = []
-                        for _ in range(k):
-                            dlg, dc, p = batched_decode_step(
-                                draft_params, cur, p, active, dc,
-                                draft_n_heads or n_heads, compute_dtype,
-                            )
-                            cur = jnp.argmax(dlg, -1).astype(jnp.int32)
-                            outs.append(cur)
-                        props = jnp.stack(outs[: k - 1], axis=1)
-                        dcache = dc
-                    else:
-                        props = device_ngram_propose(
-                            hist, pos, k, g, wrap=windowed
-                        )
-                    props = jnp.where(active[:, None], props, -1)
-                    toks = jnp.concatenate([tok[:, None], props], axis=1)
-                    m, final, cache, hist, pos2, emit = spec_round_core(
-                        params, toks, pos, active, cache, hist, temp,
-                        topk, topp, keys, spec_sampling,
-                    )
-                    acc = acc + jnp.sum(jnp.maximum(m - 1, 0))
-                    cols = cols + jnp.sum((props >= 0).astype(jnp.int32))
-                    budget = budget - m
-                    hit_stop = jnp.any(
-                        (emit == stop[:, None]) & (stop[:, None] >= 0),
-                        axis=1,
-                    )
-                    active = active & (budget > 0) & ~hit_stop
-                    tok = jnp.where(m > 0, final, tok)
-                    return (tok, pos2, active, cache, hist, budget,
-                            dcache, acc, cols), emit
-
-                zero = jnp.zeros((), jnp.int32)
-                (tok, pos, active, cache, hist, budget, dcache, acc,
-                 cols), emits = jax.lax.scan(
-                    body,
-                    (tok, pos, active, cache, hist, budget, dcache,
-                     zero, zero),
-                    None, length=rounds,
-                )
-                packed = jnp.concatenate([
-                    jnp.transpose(emits, (1, 0, 2)).reshape(-1),
-                    jnp.stack([acc, cols]),
-                ])
-                return packed, tok, pos, active, cache, hist, budget, dcache
-
-            return impl
-
-        _sdon = dict(
-            donate_argnums=(3, 4, 11),
-            static_argnames=("rounds", "k", "g"),
-        )
-        _use_draft = draft_params is not None and not windowed
-        if self._paged:
-            # paged speculative machinery: one verify round (spec_step)
-            # and the R-round device pump. The verify chunks ride the
-            # SAME formulation as the decode path: block-native reads
-            # straight off the arena by default (the k-wide window
-            # lands with one in-place multi-column block write), or the
-            # gathered-view oracle under kv_attn="gather" — so
-            # speculative and prefill-interleaved pumps drop the gather
-            # with everything else.
-
-            def paged_spec_round(spec_sampling):
-                def impl(w, toks, pos_, active, arena, tables, hist, temp,
-                         topk, topp, keys):
-                    params = w[0]
-                    if _gather_pump:
-                        view = _kvg.gather_cache(arena, tables)
-                        logits, view = batched_verify_step(
-                            params, toks, pos_, active, view, n_heads,
-                            compute_dtype,
-                        )
-                    else:
-                        logits, arena = _kvb.batched_verify_step_block(
-                            params, toks, pos_, active, arena, tables,
-                            n_heads, compute_dtype,
-                        )
-                    m, final = spec_accept(
-                        logits, toks, temp, topk, topp, keys, pos_,
-                        spec_sampling,
-                    )
-                    m = jnp.where(active, m, 0)
-                    if _gather_pump:
-                        arena = _kvg.scatter_window(
-                            arena, tables, view, pos_, toks.shape[1],
-                            active,
-                        )
-                    _, hist = spec_emit_hist(
-                        toks, m, final, active, hist, pos_, False
-                    )
-                    return m, final, arena, hist, pos_ + m
-
-                return impl
-
-            # overwrite the slot-layout rounds (jit is lazy, nothing
-            # was compiled): spec_step builds layout-matched args
-            _pgdon = dict(donate_argnums=(3, 5))
-            self._spec_round_greedy = wjit(
-                paged_spec_round(False), **_pgdon
-            )
-            self._spec_round_sampling = wjit(
-                paged_spec_round(True), **_pgdon
-            )
-
-            def paged_spec_pump_impl(spec_sampling):
-                def impl(w, tok, pos, active, arena, tables, hist, budget,
-                         stop, temp, topk, topp, keys, rounds, k, g):
-                    params = w[0]
-
-                    def body(carry, _):
-                        (tok, pos, active, arena, hist, budget, acc,
-                         cols) = carry
-                        props = device_ngram_propose(hist, pos, k, g)
-                        props = jnp.where(active[:, None], props, -1)
-                        toks = jnp.concatenate(
-                            [tok[:, None], props], axis=1
-                        )
-                        if _gather_pump:
-                            view = _kvg.gather_cache(arena, tables)
-                            logits, view = batched_verify_step(
-                                params, toks, pos, active, view,
-                                n_heads, compute_dtype,
-                            )
-                        else:
-                            logits, arena = (
-                                _kvb.batched_verify_step_block(
-                                    params, toks, pos, active, arena,
-                                    tables, n_heads, compute_dtype,
-                                )
-                            )
-                        m, final = spec_accept(
-                            logits, toks, temp, topk, topp, keys, pos,
-                            spec_sampling,
-                        )
-                        m = jnp.where(active, m, 0)
-                        if _gather_pump:
-                            arena = _kvg.scatter_window(
-                                arena, tables, view, pos, k, active
-                            )
-                        emit, hist = spec_emit_hist(
-                            toks, m, final, active, hist, pos, False
-                        )
-                        acc = acc + jnp.sum(jnp.maximum(m - 1, 0))
-                        cols = cols + jnp.sum(
-                            (props >= 0).astype(jnp.int32)
-                        )
-                        budget = budget - m
-                        hit_stop = jnp.any(
-                            (emit == stop[:, None]) & (stop[:, None] >= 0),
-                            axis=1,
-                        )
-                        active = active & (budget > 0) & ~hit_stop
-                        tok = jnp.where(m > 0, final, tok)
-                        return (tok, pos + m, active, arena, hist,
-                                budget, acc, cols), emit
-
-                    zero = jnp.zeros((), jnp.int32)
-                    (tok, pos, active, arena, hist, budget, acc,
-                     cols), emits = jax.lax.scan(
-                        body,
-                        (tok, pos, active, arena, hist, budget, zero,
-                         zero),
-                        None, length=rounds,
-                    )
-                    packed = jnp.concatenate([
-                        jnp.transpose(emits, (1, 0, 2)).reshape(-1),
-                        jnp.stack([acc, cols]),
-                    ])
-                    return packed, tok, pos, active, arena, hist, budget
-
-                return impl
-
-            _psdon = dict(
-                donate_argnums=(3, 5),
-                static_argnames=("rounds", "k", "g"),
-            )
-            self._spec_pump_greedy = wjit(
-                paged_spec_pump_impl(False), **_psdon
-            )
-            self._spec_pump_sampling = wjit(
-                paged_spec_pump_impl(True), **_psdon
-            )
-        else:
-            self._spec_pump_greedy = wjit(
-                spec_pump_impl(False, _use_draft), **_sdon
-            )
-            self._spec_pump_sampling = wjit(
-                spec_pump_impl(True, _use_draft), **_sdon
-            )
-        self._draft = (
-            _DraftEngine(
-                draft_params, draft_n_heads or n_heads, n_slots, max_len,
-                prompt_len, compute_dtype, windowed=windowed,
-            )
-            if draft_params is not None else None
-        )
-        self._load_prefix = jax.jit(
-            _named(
-                lambda stage, ks, vs: (
-                    jax.lax.dynamic_update_slice(
-                        stage[0], ks, (0, 0, 0, 0, 0)),
-                    jax.lax.dynamic_update_slice(
-                        stage[1], vs, (0, 0, 0, 0, 0)),
-                ),
-                "nns_load_prefix",
-            ),
-            donate_argnums=0,
+        self._load_prefix = jax.jit(nns_load_prefix, donate_argnums=0)
+        # the decode programs: one builder each over the layout, a greedy
+        # and a sampling variant (the greedy one compiles without the
+        # filtering/PRNG work), the weights bound as first argument
+        (self._pump_greedy, self._pump_sampling,
+         self._spec_round_greedy, self._spec_round_sampling,
+         self._spec_pump_greedy, self._spec_pump_sampling) = (
+            functools.partial(make(self._layout, sampling), weights)
+            for make in (make_pump, make_spec_round, make_spec_pump)
+            for sampling in (False, True)
         )
         # registered shared prefixes:
         # id → ((ck, cv) trimmed to plen, plen, prefix tokens)
@@ -2091,12 +1829,6 @@ class ContinuousBatcher:
         self._n_spec_columns = 0  # proposal columns offered (normalizer)
         self._n_admit_launches = 0  # launches of the admit program
         self._n_admitted = 0  # requests it spliced into the slot state
-        # step/pump/spec launches that ran the gather/scatter oracle
-        # (kv_attn="gather") instead of the block-native formulation —
-        # 0 forever on a block-native batcher (the zero-gather pin in
-        # tests/test_kv_block_attn.py); mirrored to the
-        # nns_kv_gather_dispatch_total obs counter
-        self._n_gather_dispatch = 0
         self._aux_totals: Dict[str, int] = {}
 
     def _empty_stage(self):
@@ -2151,8 +1883,8 @@ class ContinuousBatcher:
         P = self.prompt_len
         if ring is None:
             ring = (
-                jnp.zeros(self._ring_shape, self.compute_dtype),
-                jnp.zeros(self._ring_shape, self.compute_dtype),
+                jnp.zeros(self._layout.ring_shape, self.compute_dtype),
+                jnp.zeros(self._layout.ring_shape, self.compute_dtype),
             )
         else:
             # the chunk programs DONATE their ring argument — a caller's
@@ -3272,8 +3004,6 @@ class ContinuousBatcher:
 
     def _refuse(self, feature: str) -> None:
         """A feature the batcher's family does not carry refuses by name."""
-        from nnstreamer_tpu.models.family import refuse_unsupported
-
         refuse_unsupported(self._family, {feature: True})
 
     def _check_failed(self) -> None:
@@ -3285,20 +3015,9 @@ class ContinuousBatcher:
             ) from self._failed
 
     def step(self) -> Dict[int, int]:
-        """Advance every active slot one token; returns {rid: token}.
-
-        The compiled step runs OUTSIDE the state lock (admission only
-        needs the lock for its bookkeeping, so submit() never waits on an
-        in-flight device step); _step_lock serializes concurrent
-        steppers. Slots admitted while a step is in flight join at the
-        next step."""
-        self._check_failed()
-        if self._family.aux_names:
-            # a family whose step carries counters home has one decode
-            # program, the pump: a single step is a pump of one
-            return {rid: t[0] for rid, t in self.step_pump(1).items()}
-        with self._pump_span(1), self._step_lock:
-            return self._plain_step_locked()
+        """Advance every active slot one token; returns {rid: token}. A
+        pump of one: there is one decode program per family and layout."""
+        return {rid: t[0] for rid, t in self.step_pump(1).items()}
 
     def _pump_span(self, n_steps: int):
         """The ``nns.pump`` span of one entry into the decode loop, with
@@ -3306,7 +3025,7 @@ class ContinuousBatcher:
         reader of the trace, not for control)."""
         return _trace.span(
             "nns.pump", n_steps=n_steps, active=int(self._active.sum()),
-            prefill_q=len(self._prefill_q) if self._paged else 0,
+            prefill_q=len(self._prefill_q),
         )
 
     def _harvest_rows_locked(
@@ -3398,20 +3117,6 @@ class ContinuousBatcher:
             if req is not None and self._active[s]
         )
 
-    def _note_gather_dispatch_locked(self) -> None:
-        """Count a paged step/pump/spec launch that ran the
-        gather→contiguous-view→scatter oracle (``kv_attn="gather"``)
-        instead of the block-native formulation. An operator watching
-        ``nns_kv_gather_dispatch_total`` (or ``kv_gather_dispatches``
-        in stats()) sees exactly when the decode plane is paying the
-        materialized-view round trip; a block-native batcher never
-        increments it — the zero-gather steady-state regression pin."""
-        if self._kv_attn != "gather":
-            return
-        self._n_gather_dispatch += 1
-        if self._obs_reg is not None:
-            self._obs_reg.counter("nns_kv_gather_dispatch_total").inc()
-
     def step_pump(self, n: int = 8) -> Dict[int, List[int]]:
         """Advance every active slot by up to ``n`` tokens in ONE
         compiled program (lax.scan over the batched step) with ONE
@@ -3428,84 +3133,78 @@ class ContinuousBatcher:
         the token axis instead."""
         self._check_failed()
         with self._pump_span(int(n)), self._step_lock:
-            if self._paged:
-                self._advance_prefill()
-            self._apply_pending()
-            with _trace.span("nns.pump.prepare"), self._lock:
-                if not self._active.any():
-                    return {}
-                if self._paged:
-                    self._ensure_decode_room_locked(int(n))
-                active_np = self._active.copy()
-                sampling = any(
-                    req is not None and active_np[s] and req.temperature > 0
-                    for s, req in enumerate(self._slots)
+            return self._pump_locked(int(n))
+
+    def _pump_locked(self, n: int) -> Dict[int, List[int]]:
+        """step_pump's body; caller holds _step_lock. The compiled
+        program runs OUTSIDE the state lock (admission only needs the lock
+        for its bookkeeping, so submit() never waits on an in-flight
+        device step); slots admitted while a pump is in flight join at the
+        next one."""
+        self._layout.advance_prefill(self)
+        self._apply_pending()
+        with _trace.span("nns.pump.prepare"), self._lock:
+            if not self._active.any():
+                return {}
+            # before the active snapshot: making room may preempt
+            # (deactivate) a victim slot
+            self._layout.ensure_room(self, n)
+            active_np = self._active.copy()
+            sampling = self._any_sampling_locked(active_np)
+            budget_dev, stop_dev, active_dev = self._pump_state_locked()
+            live_blocks = self._layout.live_blocks(self)
+            carried, fixed = self._layout.args(self)
+            args = (
+                self._tok, self._pos, active_dev, carried, self._hist,
+                budget_dev, stop_dev, self._temp, self._topk, self._topp,
+                self._keys, *fixed,
+            )
+        fn = self._pump_sampling if sampling else self._pump_greedy
+        try:
+            with _trace.span("nns.pump.launch",
+                             active=int(active_np.sum()),
+                             live_blocks=live_blocks):
+                emits, tok, pos, act, carried, hist, budget = fn(
+                    *args, n_steps=n
                 )
-                budget_dev, stop_dev, active_dev = self._pump_state_locked()
-                live_blocks = 0
-                if self._paged:
-                    self._note_gather_dispatch_locked()
-                    live_blocks = self._live_blocks_locked()
-                    args = (
-                        self._tok, self._pos, active_dev, self._cache,
-                        self._tables_device_locked(), self._hist,
-                        budget_dev, stop_dev, self._temp, self._topk,
-                        self._topp, self._keys,
-                    )
-                else:
-                    args = (
-                        self._tok, self._pos, active_dev, self._cache,
-                        self._hist, budget_dev, stop_dev, self._temp,
-                        self._topk, self._topp, self._keys,
-                        self._draft._cache if self._draft is not None
-                        else None,
-                    )
-            fn = self._pump_sampling if sampling else self._pump_greedy
-            try:
-                with _trace.span("nns.pump.launch",
-                                 active=int(active_np.sum()),
-                                 live_blocks=live_blocks):
-                    if self._paged:
-                        emits, tok, pos, act, cache, hist, budget = fn(
-                            *args, n_steps=int(n)
-                        )
-                        dcache = None
-                    else:
-                        (emits, tok, pos, act, cache, hist, budget,
-                         dcache) = fn(*args, n_steps=int(n))
-                with _trace.span("nns.pump.wait"):
-                    emits_np = np.asarray(emits)  # ONE [B, n] transfer
-                aux_np = None
-                if self._family.aux_names:
-                    aux_np = emits_np[self.n_slots * int(n):]
-                    emits_np = emits_np[: self.n_slots * int(n)].reshape(
-                        self.n_slots, int(n)
-                    )
-            except Exception as exc:
-                # the launch donated _cache/_hist (and the draft cache):
-                # a raise here leaves them consumed — latch the failure
-                # so later calls get BatcherFailedError, not a cryptic
-                # deleted-buffer error (submit()'s rollback analogue)
-                self._mark_failed(exc)
-                raise
-            with self._lock:
-                self._cache = cache
-                self._hist = self._pin(hist)
-                self._tok = self._pin(tok)
-                self._pos = self._pin(pos)
-                # the scan's carried pump state becomes next pump's input
-                self._budget_dev = self._pin(budget)
-                self._active_dev = self._pin(act)
-                if self._draft is not None:
-                    self._draft._cache = dcache
-                out, n_em = self._harvest_rows_locked(
-                    active_np, lambda s: (emits_np[s],)
+            with _trace.span("nns.pump.wait"):
+                emits_np = np.asarray(emits)  # ONE [B, n] transfer
+            aux_np = None
+            if self._family.aux_names:
+                aux_np = emits_np[self.n_slots * n:]
+                emits_np = emits_np[: self.n_slots * n].reshape(
+                    self.n_slots, n
                 )
-                self._n_steps += int(n)
-                self._n_tokens += n_em
-                if aux_np is not None:
-                    self._note_aux_locked(aux_np)
-                return out
+        except Exception as exc:
+            # the launch donated the carried cache and _hist: a raise
+            # here leaves them consumed — latch the failure so later
+            # calls get BatcherFailedError, not a cryptic deleted-buffer
+            # error (submit()'s rollback analogue)
+            self._mark_failed(exc)
+            raise
+        with self._lock:
+            self._layout.commit(self, carried)
+            self._hist = self._pin(hist)
+            self._tok = self._pin(tok)
+            self._pos = self._pin(pos)
+            # the scan's carried pump state becomes next pump's input
+            self._budget_dev = self._pin(budget)
+            self._active_dev = self._pin(act)
+            out, n_em = self._harvest_rows_locked(
+                active_np, lambda s: (emits_np[s],)
+            )
+            self._n_steps += n
+            self._n_tokens += n_em
+            if aux_np is not None:
+                self._note_aux_locked(aux_np)
+            return out
+
+    def _any_sampling_locked(self, active_np) -> bool:
+        """Whether any live slot samples (else the greedy program runs)."""
+        return any(
+            req is not None and active_np[s] and req.temperature > 0
+            for s, req in enumerate(self._slots)
+        )
 
     def _note_aux_locked(self, aux_np) -> None:
         """One harvested pump's family counters (``aux_names``, summed on
@@ -3543,8 +3242,7 @@ class ContinuousBatcher:
         if self._draft is not None and self.windowed:
             return self._spec_fallback_rounds(int(rounds), k, ngram)
         with self._step_lock:
-            if self._paged:
-                self._advance_prefill()
+            self._layout.advance_prefill(self)
             self._apply_pending()
             with self._lock:
                 if not self._active.any():
@@ -3557,16 +3255,6 @@ class ContinuousBatcher:
                         if req is not None and self._active[s]
                     )
                     r = min(r, (self.max_len - pos_max) // k)
-                if r >= 1 and self._paged:
-                    # block room BEFORE the active snapshot: allocation
-                    # may preempt (deactivate) a victim slot, and the
-                    # launch/harvest must both see post-preemption state
-                    self._ensure_decode_room_locked(r * k)
-                active_np = self._active.copy()
-                sampling = any(
-                    req is not None and active_np[s] and req.temperature > 0
-                    for s, req in enumerate(self._slots)
-                )
                 # NOT clamped by remaining budget: slots that exhaust
                 # their budget mid-scan idle out ON DEVICE (active &=
                 # budget > 0), exactly like step_pump's fixed n_steps.
@@ -3575,49 +3263,37 @@ class ContinuousBatcher:
                 # so a warm-up drain compiled rounds=2/1 programs, the
                 # measured drain then built rounds=4 inside the timed
                 # region, and every budget tail recompiled its way down
-                # a 4→2→1 program ladder: the spec×cb throughput
-                # collapse (BENCH_CPU_FULL_r05: 8.0/4.8 vs 25.5 plain).
-                # The only static clamp that stays is write-room
-                # (cache-bounds correctness), quantized so the window
-                # tail costs log2 variants, not one per length.
+                # a 4→2→1 program ladder. The only static clamp that
+                # stays is write-room (cache-bounds correctness),
+                # quantized so the window tail costs log2 variants, not
+                # one per length.
                 if r >= 1:
+                    # room BEFORE the active snapshot: allocation may
+                    # preempt (deactivate) a victim slot, and the
+                    # launch/harvest must both see post-preemption state
+                    self._layout.ensure_room(self, r * k)
                     while r & (r - 1):  # power-of-two floor (see above)
                         r &= r - 1
+                    active_np = self._active.copy()
                     budget_dev, stop_dev, active_dev = (
                         self._pump_state_locked()
                     )
-                    if self._paged:
-                        self._note_gather_dispatch_locked()
-                        args = (
-                            self._tok, self._pos, active_dev,
-                            self._cache, self._tables_device_locked(),
-                            self._hist, budget_dev, stop_dev,
-                            self._temp, self._topk, self._topp,
-                            self._keys,
-                        )
-                    else:
-                        args = (
-                            self._tok, self._pos, active_dev,
-                            self._cache, self._hist, budget_dev,
-                            stop_dev, self._temp, self._topk,
-                            self._topp, self._keys,
-                            self._draft._cache if self._draft is not None
-                            else None,
-                        )
+                    carried, fixed = self._layout.args(self)
+                    args = (
+                        self._tok, self._pos, active_dev, carried,
+                        self._hist, budget_dev, stop_dev, self._temp,
+                        self._topk, self._topp, self._keys, *fixed,
+                    )
                     fn = (
-                        self._spec_pump_sampling if sampling
+                        self._spec_pump_sampling
+                        if self._any_sampling_locked(active_np)
                         else self._spec_pump_greedy
                     )
             if r >= 1:
                 try:
-                    if self._paged:
-                        packed, tok, pos, act, cache, hist, budget = fn(
-                            *args, rounds=r, k=k, g=int(ngram)
-                        )
-                        dcache = None
-                    else:
-                        (packed, tok, pos, act, cache, hist, budget,
-                         dcache) = fn(*args, rounds=r, k=k, g=int(ngram))
+                    packed, tok, pos, act, carried, hist, budget = fn(
+                        *args, rounds=r, k=k, g=int(ngram)
+                    )
                     packed_np = np.asarray(packed)  # ONE transfer
                 except Exception as exc:
                     self._mark_failed(exc)  # donated state consumed
@@ -3625,12 +3301,22 @@ class ContinuousBatcher:
                 acc, cols = int(packed_np[-2]), int(packed_np[-1])
                 emits_np = packed_np[:-2].reshape(self.n_slots, r, k)
                 with self._lock:
+                    self._layout.commit(self, carried)
+                    self._hist = self._pin(hist)
+                    self._tok = self._pin(tok)
+                    self._pos = self._pin(pos)
                     self._budget_dev = self._pin(budget)
                     self._active_dev = self._pin(act)
-                    return self._spec_pump_commit_locked(
-                        active_np, r, acc, cols, emits_np, tok, pos,
-                        cache, hist, dcache,
+                    out, n_em = self._harvest_rows_locked(
+                        active_np,
+                        lambda s: (emits_np[s, rnd] for rnd in range(r)),
                     )
+                    self._n_steps += r
+                    self._n_tokens += n_em
+                    self._n_spec_rounds += r
+                    self._n_spec_accepted += acc
+                    self._n_spec_columns += cols
+                    return out
         # r < 1: no verify room at any width ≥ 2 — the shrinking-k host
         # round handles the tail tokens (takes _step_lock itself)
         return self._spec_fallback_rounds(1, k, ngram)
@@ -3696,89 +3382,6 @@ class ContinuousBatcher:
             }
         return {rid: toks for rid, toks in out.items() if toks}
 
-    def _spec_pump_commit_locked(
-        self, active_np, r, acc, cols, emits_np, tok, pos, cache,
-        hist, dcache,
-    ) -> Dict[int, List[int]]:
-        """spec_pump bookkeeping; caller holds _step_lock + _lock."""
-        self._cache = cache
-        self._hist = self._pin(hist)
-        self._tok = self._pin(tok)
-        self._pos = self._pin(pos)
-        if self._draft is not None:
-            self._draft._cache = dcache
-        out, n_em = self._harvest_rows_locked(
-            active_np, lambda s: (emits_np[s, rnd] for rnd in range(r))
-        )
-        self._n_steps += r
-        self._n_tokens += n_em
-        self._n_spec_rounds += r
-        self._n_spec_accepted += acc
-        self._n_spec_columns += cols
-        return out
-
-    def _plain_step_locked(self) -> Dict[int, int]:
-        """step() body; caller holds _step_lock."""
-        if self._paged:
-            self._advance_prefill()
-        self._apply_pending()
-        with self._lock:
-            if not self._active.any():
-                return {}
-            if self._paged:
-                self._ensure_decode_room_locked(1)
-            active_np = self._active.copy()
-            sampling = any(
-                req is not None and active_np[s] and req.temperature > 0
-                for s, req in enumerate(self._slots)
-            )
-            if self._paged:
-                self._note_gather_dispatch_locked()
-                args = (
-                    self._tok, self._pos, jnp.asarray(active_np),
-                    self._cache, self._tables_device_locked(),
-                    self._hist, self._temp, self._topk, self._topp,
-                    self._keys,
-                )
-            else:
-                args = (
-                    self._tok, self._pos, jnp.asarray(active_np),
-                    self._cache, self._hist, self._temp, self._topk,
-                    self._topp, self._keys,
-                )
-        try:
-            if self._draft is not None:
-                # keep the draft cache position-synced with the target:
-                # this plain step writes the pending token's K/V on the
-                # target; the draft must mirror it (see advance_one)
-                self._draft.advance_one(args[0], args[1], args[2])
-            step_fn = self._step_sampling if sampling else self._step_greedy
-            new_tok, cache, pos, hist = step_fn(*args)
-            toks = np.asarray(new_tok)  # [B] ids — the only host transfer
-        except Exception as exc:
-            self._mark_failed(exc)  # donated state consumed
-            raise
-        with self._lock:
-            self._cache = cache
-            self._pos = pos
-            self._tok = new_tok
-            self._hist = hist
-            emitted: Dict[int, int] = {}
-            for slot, req in enumerate(self._slots):
-                if req is None or not active_np[slot]:
-                    continue
-                tok = int(toks[slot])
-                req.tokens.append(tok)
-                emitted[req.rid] = tok
-                if req.finished():
-                    self._finish(slot)
-            self._n_steps += 1
-            self._n_tokens += len(emitted)
-            # host-stepped path: budgets advanced outside a pump scan,
-            # so the device-carried pump state must rebuild next pump
-            self._pump_state_dirty = True
-            return emitted
-
     def spec_step(self, k: int = 4, ngram: int = 2) -> Dict[int, int]:
         """One SPECULATIVE round: every active slot verifies k-1 guessed
         continuation tokens in one batched forward and commits its
@@ -3817,20 +3420,15 @@ class ContinuousBatcher:
         self._check_failed()
         self._refuse("speculate")
         with self._step_lock:
-            if self._paged:
-                self._advance_prefill()
+            self._layout.advance_prefill(self)
             self._apply_pending()
             with self._lock:
                 if not self._active.any():
                     return {}
-                if self._paged:
-                    # before the active snapshot — may preempt a victim
-                    self._ensure_decode_room_locked(int(k))
+                # before the active snapshot — may preempt a victim
+                self._layout.ensure_room(self, int(k))
                 active_np = self._active.copy()
-                sampling = any(
-                    req is not None and active_np[s] and req.temperature > 0
-                    for s, req in enumerate(self._slots)
-                )
+                sampling = self._any_sampling_locked(active_np)
                 pos_np = np.asarray(self._pos)
                 if self.windowed:
                     # a ring has no end: the only bound is the window
@@ -3890,8 +3488,11 @@ class ContinuousBatcher:
                                 # bit-identical to verify)
                                 k_round = 1
             if k_round < 2:
-                # outside self._lock — _plain_step_locked reacquires it
-                return self._plain_step_locked()
+                # a pump of one, under the _step_lock already held
+                # (outside self._lock — the pump reacquires it)
+                return {
+                    rid: t[-1] for rid, t in self._pump_locked(1).items()
+                }
             if self._draft is not None:
                 # k-1 batched draft forwards propose for every slot at
                 # once; a draft always proposes, so there is no
@@ -3903,28 +3504,23 @@ class ContinuousBatcher:
                 toks_host[:, 1:] = self._draft.propose(
                     self._tok, self._pos, jnp.asarray(active_np), k_round
                 )
-            if self._paged:
-                with self._lock:
-                    self._note_gather_dispatch_locked()
-                    tables_dev = self._tables_device_locked()
-                args = (
-                    jnp.asarray(toks_host), self._pos,
-                    jnp.asarray(active_np), self._cache, tables_dev,
-                    self._hist, self._temp, self._topk, self._topp,
-                    self._keys,
-                )
-            else:
-                args = (
-                    jnp.asarray(toks_host), self._pos,
-                    jnp.asarray(active_np), self._cache, self._hist,
-                    self._temp, self._topk, self._topp, self._keys,
-                )
+            with self._lock:
+                carried, fixed = self._layout.args(self)
+            args = (
+                jnp.asarray(toks_host), self._pos, jnp.asarray(active_np),
+                carried, self._hist, self._temp, self._topk, self._topp,
+                self._keys, *fixed,
+            )
             round_fn = (
                 self._spec_round_sampling if sampling
                 else self._spec_round_greedy
             )
             try:
-                m_dev, final_dev, cache, hist, pos2 = round_fn(*args)
+                m_dev, final_dev, carried, hist, pos2 = round_fn(*args)
+                with self._lock:
+                    # before the draft's own commit: the round carried
+                    # (and donated) the draft cache too
+                    self._layout.commit(self, carried)
                 if self._draft is not None and self._draft.windowed:
                     # draft-side commit of the accepted columns (the ring
                     # discipline: nothing landed during propose)
@@ -3936,7 +3532,6 @@ class ContinuousBatcher:
                 self._mark_failed(exc)  # donated state consumed
                 raise
             with self._lock:
-                self._cache = cache
                 self._hist = hist
                 self._pos = self._pin(pos2)
                 emitted: Dict[int, int] = {}
@@ -4013,16 +3608,10 @@ class ContinuousBatcher:
                 st["kv_block_size"] = self.block_size
                 st["kv_prefill_queue"] = len(self._prefill_q)
                 st["kv_preemptions"] = self._slo.preemptions_total
-                # which decode formulation this batcher runs (block =
-                # arena attended through the tables, gather = the
-                # materialized-view oracle) and how many launches paid
-                # the gather round trip — 0 forever under kv_attn=block
-                st["kv_attn"] = self._kv_attn
                 # the decode attention that serves it, as resolved at
                 # construction (an unset attn_impl and a registry
                 # refusal both land here): "pallas" | "xla"
                 st["attn_impl"] = self._attn_impl
-                st["kv_gather_dispatches"] = self._n_gather_dispatch
                 st["kv_migrations_out"] = self._n_migrations_out
                 st["kv_migrations_in"] = self._n_migrations_in
                 st["kv_prefill_chunks"] = self._n_prefill_chunk_programs

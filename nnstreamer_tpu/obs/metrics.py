@@ -165,13 +165,6 @@ METRIC_CATALOG: Dict[str, str] = {
     "nns_moe_picks_total": (
         "router picks of live tokens: tokens x top-k (counter)"
     ),
-    "nns_kv_gather_dispatch_total": (
-        "paged step/pump/spec launches that ran the gather→contiguous-"
-        "view→scatter oracle (kv_attn=gather) instead of the "
-        "block-native arena read — a nonzero rate means the decode "
-        "plane is paying the materialized-view round trip (counter; "
-        "docs/llm-serving.md)"
-    ),
     "nns_kv_migrations_total": (
         "live request migrations through kv/migrate.py spans, by "
         "direction label: out (extracted and shipped to a peer) / in "

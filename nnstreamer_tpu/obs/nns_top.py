@@ -416,16 +416,6 @@ def render_requests(snap: dict) -> str:
             footer.append(
                 f"blocks={blocks}/{row.get('serving_kv_blocks', '?')}"
             )
-        if row.get("serving_kv_attn"):
-            # which paged decode path is live: block (arena attended
-            # through the tables) or gather (the materialized-view
-            # oracle — the dispatch count shows what it is costing)
-            footer.append(f"kv-attn={row['serving_kv_attn']}")
-            if row.get("serving_kv_gather_dispatches"):
-                footer.append(
-                    "gather-dispatches="
-                    f"{row['serving_kv_gather_dispatches']}"
-                )
         if row.get("serving_kv_prefix_hits"):
             footer.append(f"prefix-hits={row['serving_kv_prefix_hits']}")
         if pre:

@@ -26,7 +26,7 @@ the g query rows a KV head serves is one [KV, D] tile in the cache
 block's own layout; the kernel walks gi over one resident cache block.
 
 Int8 caches: pass ``k_scale``/``v_scale`` [B, S, KV] (per-token-per-head
-symmetric scales, models/serving.quantize_kv layout) and int8 cache
+symmetric scales, kv/gather.quantize_kv layout) and int8 cache
 arrays — the kernel dequantizes per block in VMEM, so HBM traffic stays
 at the int8 byte count (the whole point of quantizing the cache: 4× less
 cache streaming per decode step than f32).
@@ -227,7 +227,7 @@ def make_decode_attention(interpret: Optional[bool] = None, **kwargs):
 
     The returned ``attn(q, ck, cv, pos)`` accepts either float cache
     arrays or the serving int8 cache entries ``(ck8, k_scale)`` /
-    ``(cv8, v_scale)`` (models/serving.py quantize_kv layout). Each
+    ``(cv8, v_scale)`` (kv/gather.py quantize_kv layout). Each
     trace consults the registry's dtype support (_compat.pallas_ok) and
     degrades to :func:`decode_attention_ref` with a logged reason
     instead of a trace-time Mosaic error; the resolved choice lands in

@@ -43,7 +43,7 @@ Mechanics (grid ``(B, ceil(nb / C))``, chunk axis innermost with
   final grid step: it is position ``pos``, the highest live position,
   so the reduction order equals position order;
 - int8 arenas pass ``k_scale``/``v_scale`` ``[L, N, bs, KV]`` (the
-  per-token-per-head symmetric scales of models/serving.quantize_kv)
+  per-token-per-head symmetric scales of kv/gather.quantize_kv)
   and dequantize per block in VMEM — HBM traffic stays at the int8
   byte count.
 

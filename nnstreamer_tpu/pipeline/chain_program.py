@@ -25,8 +25,8 @@ makes the chain itself the compile unit:
   passthrough fns and collapse out of the trace; an all-identity chain
   never dispatches at all.
 
-The per-node path stays the PARITY ORACLE (exactly as ``kv_attn=gather``
-does for block attention): :meth:`ChainProgram.process_frame_fallback`
+The per-node path stays the PARITY ORACLE (as the slot KV layout does for
+block attention): :meth:`ChainProgram.process_frame_fallback`
 serves a frame through each member segment's OWN program in order —
 bitwise-identical to the member FusedNodes — and the executor's
 ``ChainNode`` latches onto it for any runtime hazard (device fault,

@@ -25,10 +25,8 @@ What each stream keeps (the plane.py contract, token-shaped):
   stream's own output deque with its meta (client_id!) intact, so each
   pipeline's serversrc emits only its own generations.
 
-The decode path itself is untouched: the shared batcher runs the PR-13
-block-native paged attention (``kv_attn="auto"|"block"``) with zero
-gather dispatches on steady decode — sharing the plane costs no
-materialized view.
+The decode path itself is untouched: the shared batcher runs the same
+block-native paged attention a solo serversink does.
 
 Lifecycle mirrors the tensor plane registry: refcounted by attached
 serversink, first :func:`acquire` builds the batcher (the opener owns

@@ -373,8 +373,8 @@ class TestBatcherFailureLatch:
         def boom(*a, **k):
             raise RuntimeError("device launch failed mid-flight")
 
-        b._step_greedy = boom
-        b._step_sampling = boom
+        b._pump_greedy = boom
+        b._pump_sampling = boom
         with pytest.raises(RuntimeError, match="mid-flight"):
             b.step()
         # donated state is gone: every later call reports the latch, not

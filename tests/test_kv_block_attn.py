@@ -2,15 +2,14 @@
 ops/pallas/paged_attention.py, docs/llm-serving.md).
 
 The load-bearing invariants on top of test_kv_paged.py's slot-parity
-matrix (which now runs the block-native default): block↔gather-oracle
-byte-identical streams, the Pallas block-table kernel against its jnp
-online-softmax reference in interpret mode (>1-block fills, int8
-scales, scratch predication), the in-place single-block write leaving
-shared/CoW blocks untouched, the zero-gather steady-state dispatch pin,
-and the NNS-W117 lint. Kept lean under the tier-1 DOTS budget: one
-tiny model, two shared batchers for every batcher-level test, greedy
-step() drains (the pump/spec/sampling compiles already ride
-test_kv_paged's block-default batchers), function-level kernel cells.
+matrix: block↔slot-oracle byte-identical streams on mixed-length
+submits, the Pallas block-table kernel against its jnp online-softmax
+reference in interpret mode (>1-block fills, int8 scales, scratch
+predication), and the in-place single-block write leaving shared/CoW
+blocks untouched. Kept lean under the tier-1 DOTS budget: one tiny
+model, two shared batchers for every batcher-level test, greedy step()
+drains (the spec/sampling compiles already ride test_kv_paged's
+batchers), function-level kernel cells.
 """
 
 import jax
@@ -32,15 +31,6 @@ def params():
     )
 
 
-@pytest.fixture(scope="module")
-def obs_reg():
-    from nnstreamer_tpu.obs import metrics as obs_metrics
-
-    reg = obs_metrics.enable()
-    yield reg
-    obs_metrics.disable()
-
-
 def _mk(params, **kw):
     base = dict(n_slots=2, max_len=64, prompt_len=16,
                 kv_layout="paged", block_size=16)
@@ -49,13 +39,15 @@ def _mk(params, **kw):
 
 
 @pytest.fixture(scope="module")
-def block_cb(params, obs_reg):
-    return _mk(params)  # kv_attn="auto" → block-native
+def block_cb(params):
+    return _mk(params)
 
 
 @pytest.fixture(scope="module")
-def gather_cb(params, obs_reg):
-    return _mk(params, kv_attn="gather")
+def slot_cb(params):
+    return ContinuousBatcher(
+        params, N_HEADS, n_slots=2, max_len=64, prompt_len=16
+    )
 
 
 def _prompt(n, seed):
@@ -63,53 +55,32 @@ def _prompt(n, seed):
 
 
 def _drain(cb, rids):
-    # per-token step() drains: the pump/spec scan programs are already
-    # block-native-covered by test_kv_paged (block is the default) —
-    # skipping them here keeps this file's compile bill inside the
-    # tier-1 budget
+    # per-token step() drains (a pump of one): the longer pumps and the
+    # spec programs are already covered by test_kv_paged — skipping them
+    # here keeps this file's compile bill inside the tier-1 budget
     while any(cb.result(r) is None for r in rids):
         cb.step()
     return [cb.result(r) for r in rids]
 
 
-# -- batcher-level parity + the zero-gather pin ----------------------------
+# -- batcher-level parity ----------------------------------------------------
 
-def test_block_vs_gather_parity(block_cb, gather_cb):
-    """Two greedy requests with multi-block prompts: the block-native
-    default and the gather oracle emit byte-identical streams. The full
-    parity matrix against the SLOT layout — sampling, int8, prefix
-    sharing, eviction — is pinned by test_kv_paged.py, whose batchers
-    run kv_attn="block" by default; this cell is the oracle↔block
-    equivalence (greedy keeps the compile bill to one step program per
-    batcher)."""
+def test_block_vs_slot_parity(block_cb, slot_cb):
+    """Two greedy requests with multi-block prompts: the paged batcher
+    and the slot layout (the oracle) emit byte-identical streams. The
+    full parity matrix — sampling, int8, prefix sharing, eviction — is
+    pinned by test_kv_paged.py; this cell is the mixed-length one (greedy
+    keeps the compile bill to one pump program per batcher)."""
     # bucket-sized prompts (≤ prompt_len) keep the chunked-prefill
     # programs out of this file's compile bill; multi-block reads and
     # the cross-boundary width-1 write still happen — lane 1 decodes
     # from fill 13 into block 2
     subs = [(_prompt(5, 1), 6), (_prompt(13, 2), 5)]
-    assert block_cb.stats()["kv_attn"] == "block"
-    assert gather_cb.stats()["kv_attn"] == "gather"
+    assert block_cb.stats()["attn_impl"] == "xla"
+    assert "kv_attn" not in block_cb.stats()
     rb = [block_cb.submit(p, n) for p, n in subs]
-    rg = [gather_cb.submit(p, n) for p, n in subs]
-    assert _drain(block_cb, rb) == _drain(gather_cb, rg)
-
-
-def test_zero_gather_dispatch_and_obs_counter(obs_reg, block_cb, gather_cb):
-    """The steady-state regression pin: a block-native batcher NEVER
-    dispatches a gather/scatter program (counter stays 0 across every
-    step/pump the parity test ran), while the oracle counts one per
-    launch — mirrored to nns_kv_gather_dispatch_total so operators see
-    when the materialized-view round trip is being paid."""
-    st_b, st_g = block_cb.stats(), gather_cb.stats()
-    assert st_b["kv_gather_dispatches"] == 0
-    assert st_g["kv_gather_dispatches"] > 0
-    c = obs_reg.find("nns_kv_gather_dispatch_total")
-    assert c is not None and c.value == st_g["kv_gather_dispatches"]
-    # and the pin survives more pumped decode on the block batcher
-    r = block_cb.submit(_prompt(4, 9), 5)
-    _drain(block_cb, [r])
-    assert block_cb.stats()["kv_gather_dispatches"] == 0
-    assert obs_reg.find("nns_kv_gather_dispatch_total").value == c.value
+    rs = [slot_cb.submit(p, n) for p, n in subs]
+    assert _drain(block_cb, rb) == _drain(slot_cb, rs)
 
 
 def test_in_place_write_leaves_shared_blocks_untouched(block_cb):
@@ -295,17 +266,16 @@ def test_pump_stream_pallas_equals_xla(params):
         ("cpu", dict(), "xla"),
         ("tpu", dict(attn_impl="xla"), "xla"),
         ("cpu", dict(attn_impl="pallas"), "pallas"),
-        ("tpu", dict(kv_attn="gather"), "xla"),
         ("tpu", dict(kv_layout="slot", block_size=16), "xla"),
         ("tpu", dict(cache_dtype="int8"), "pallas"),
     ],
-    ids=["tpu-unset", "cpu-unset", "tpu-xla", "cpu-pallas", "tpu-gather",
-         "tpu-slot", "tpu-int8"],
+    ids=["tpu-unset", "cpu-unset", "tpu-xla", "cpu-pallas", "tpu-slot",
+         "tpu-int8"],
 )
 def test_default_attn_impl_rule(params, monkeypatch, backend, kw, want):
     """``attn_impl`` unset resolves by the rule of
     ``block_attention(impl="auto")``: the block-table kernel for the
-    block-native paged layout where ``jax.default_backend()`` is a TPU
+    paged layout where ``jax.default_backend()`` is a TPU
     and the registry passes the arena dtype, the XLA formulation
     everywhere else; an explicit value keeps its meaning. The backend is
     steered HERE (construction traces and compiles nothing), not through
@@ -361,30 +331,17 @@ def test_launch_span_counts_live_blocks(params):
 
 # -- configuration / lint ---------------------------------------------------
 
-def test_kv_attn_validation(params):
-    with pytest.raises(ValueError, match="kv_attn"):
-        ContinuousBatcher(params, N_HEADS, kv_attn="virtual")
-    with pytest.raises(ValueError, match="slot"):
-        ContinuousBatcher(params, N_HEADS, kv_attn="block")  # slot layout
-    with pytest.raises(ValueError, match="block-native"):
-        _mk(params, kv_attn="gather", attn_impl="pallas")
-
-
-def test_w117_paged_gather_materializes_cache_both_ways():
+def test_kv_attn_is_gone(params):
+    """The paged decode has one formulation and no option that names
+    another: the constructor argument is a TypeError, and a launch string
+    that still carries the property is told so like any unknown one."""
     from nnstreamer_tpu.analysis import lint
 
-    head = ("tensorsrc dimensions=4 types=int32 num-frames=1 ! "
-            "tensor_llm_serversink id=92 n-slots=64 max-len=2048 "
-            "kv-layout=paged ")
-    r_bad = lint(head + "kv-attn=gather kv-memory-bound=64M")
-    assert "NNS-W117" in r_bad.codes
-    assert r_bad.exit_code == 1  # warning, not error
-    # the block-native default has no gathered view; no declared bound
-    # stays silent; a bound the arena+view fit under is fine
-    assert "NNS-W117" not in lint(head + "kv-memory-bound=64M").codes
-    assert "NNS-W117" not in lint(head + "kv-attn=gather").codes
-    assert "NNS-W117" not in lint(
-        head + "kv-attn=gather kv-memory-bound=64G"
-    ).codes
-    # and W115 never fires on a paged layout
-    assert "NNS-W115" not in r_bad.codes
+    with pytest.raises(TypeError, match="kv_attn"):
+        _mk(params, kv_attn="block")
+    r = lint("tensorsrc dimensions=4 types=int32 num-frames=1 ! "
+             "tensor_llm_serversink id=92 kv-layout=paged kv-attn=gather")
+    assert [d.code for d in r.diagnostics] == ["NNS-W101"]
+    assert "unknown property 'kv-attn' for tensor_llm_serversink" in (
+        r.diagnostics[0].message
+    )

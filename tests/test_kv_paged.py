@@ -373,7 +373,6 @@ def test_requests_view_and_nns_top_render(paged_cb):
         "serving_kv_blocks_in_use": 0,
         "serving_kv_blocks": 24,
         "serving_kv_prefix_hits": 3,
-        "serving_kv_attn": "block",
         "serving_kv_migrations_out": 2,
         "serving_kv_migrations_in": 1,
         "serving_request_resumes": 1,
@@ -382,9 +381,8 @@ def test_requests_view_and_nns_top_render(paged_cb):
     assert str(rid) in out and "done" in out and "prefix-hits=3" in out
     # migration & recovery footer (docs/llm-serving.md)
     assert "migrations=2out/1in" in out and "resumes=1" in out
-    # the footer names the active decode formulation (block-native by
-    # default; gather would additionally show its dispatch count)
-    assert "kv-attn=block" in out
+    # one paged decode formulation: the footer has nothing to name
+    assert "kv-attn" not in out and "gather" not in out
     assert "TTFT" in out.splitlines()[0]
     assert "LLM serving" in render_requests({"nodes": {}})
 

@@ -242,7 +242,7 @@ def test_served_from_a_launch_string_through_the_paged_batcher(ref):
     every served token is the reference's best at its position."""
     prompts = [_tokens(i, (n,)) for i, n in enumerate((9, 50, 32))]
     got, stats = _serve(prompts, 10)
-    assert stats["family"] == "longcat_flash" and stats["kv_attn"] == "block"
+    assert stats["family"] == "longcat_flash" and "kv_attn" not in stats
     assert stats["moe_picks"] == stats["moe_tokens"] * 3 > 0
     shape = _shape(_cfg())
     for i, p in enumerate(prompts):
@@ -257,7 +257,6 @@ def test_served_from_a_launch_string_through_the_paged_batcher(ref):
     ({"speculate": "4"}, "speculate"),
     ({"cache-dtype": "int8"}, "cache-dtype=int8"),
     ({"kv-layout": "slot"}, "kv-layout=slot"),
-    ({"kv-attn": "gather"}, "kv-attn=gather"),
     ({"role": "decode"}, "role"),
     ({"checkpoint-every-tokens": "4", "checkpoint-dir": "/tmp/nns-lc-ckpt"},
      "checkpoint-every-tokens"),

@@ -202,7 +202,7 @@ def test_spec_pump_draft_inscan_exact(params, draft_params):
 
 def test_step_pump_draft_cache_stays_synced(params, draft_params):
     """step_pump on a draft batcher advances the draft cache in-scan
-    (the pump form of advance_one): a spec_pump AFTER a step_pump still
+    (in lockstep with the target): a spec_pump AFTER a step_pump still
     produces the exact stream — no holes in the draft cache."""
     p = _prompt(6, 31)
     a = _twin(params)
@@ -691,3 +691,69 @@ def test_admission_compiles_once(params, layout):
     finally:
         mon.unregister_event_duration_listener(on_duration)
     assert b._admit._cache_size() == 1
+
+
+def _longcat_twin():
+    import jax.numpy as jnp
+
+    from nnstreamer_tpu.models import longcat as lc
+
+    cfg = lc.LongcatConfig(
+        d_model=64, n_heads=4, q_rank=16, kv_rank=8, nope=8, rope=8, v_dim=8,
+        d_ff=128, d_expert=32, n_routed=16, n_zero=8, topk=3, n_layers=2,
+        vocab=97, n_held=4, expert_offset=4,
+    )
+    return ContinuousBatcher(
+        lc.init_params(cfg, 3, jnp.float32), cfg.n_heads, n_slots=4,
+        max_len=96, prompt_len=16, kv_layout="paged",
+        family=lc.LongcatFamily(cfg, jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("kind", ["slot", "paged", "paged-longcat"])
+def test_step_is_a_pump_of_one(params, kind):
+    """``step()`` is ``step_pump(1)`` for every family and layout: the same
+    tokens, from the same program — no batcher holds a second per-token
+    decode program beside its pump."""
+    def make():
+        if kind == "paged-longcat":
+            return _longcat_twin()
+        return _twin(params, **(_PAGED if kind == "paged" else {}))
+
+    vocab = 97 if kind == "paged-longcat" else 257
+    prompts = [_prompt(5 + s, 400 + s) % vocab for s in range(3)]
+    a, b = make(), make()
+    ra = [a.submit(p, 7) for p in prompts]
+    rb = [b.submit(p, 7) for p in prompts]
+    while any(a.result(r) is None for r in ra):
+        got = a.step()
+        assert all(isinstance(t, int) for t in got.values())
+    _drain_pump(b, rb, 1)
+    assert _tokens(a, ra) == _tokens(b, rb)
+    assert not hasattr(a, "_step_greedy") and not hasattr(a, "_step_sampling")
+    # step() ran the pump, and only at n_steps=1
+    assert a._pump_greedy.func._cache_size() == 1
+    assert a.stats()["steps"] == b.stats()["steps"]
+
+
+@pytest.mark.parametrize(
+    "program,builder",
+    [("_pump", "make_pump"), ("_spec_round", "make_spec_round"),
+     ("_spec_pump", "make_spec_pump")],
+)
+def test_each_decode_program_has_one_definition(params, program, builder):
+    """The slot and the paged batcher get each kind of decode program from
+    the same module-level builder, over their layout: the greedy and the
+    sampling variant of both are that builder's ``impl``, jitted with the
+    carried cache and the history donated."""
+    from nnstreamer_tpu.models import serving
+
+    slot, paged = _twin(params), _twin(params, **_PAGED)
+    assert type(slot._layout) is not type(paged._layout)
+    code = getattr(serving, builder).__code__
+    for cb in (slot, paged):
+        for variant in ("_greedy", "_sampling"):
+            fn = getattr(cb, program + variant).func
+            impl = fn.__wrapped__
+            assert impl.__name__ == "impl"
+            assert impl.__code__ in code.co_consts, (program, variant)

@@ -194,51 +194,48 @@ def test_paged_attention_cell_shapes(one_chip, cell):
 
 @pytest.mark.parametrize("cell", list(CELLS))
 def test_paged_decode_step_cell_shapes(one_chip, cell):
-    """The decode program around the kernel (two steps of the pump's
-    scan over ``batched_decode_step_block``, arena donated): the kernel
-    is in it, and nothing the size of ONE layer's arena leaf (134 MB) is
-    copied or sliced anywhere — the layer scan feeds the kernel the
-    whole leaf and an index."""
-    from nnstreamer_tpu.kv.block_attn import batched_decode_step_block
+    """The decode program that is served — the batcher's own pump builder
+    over the paged layout, two steps, arena and history donated: the
+    kernel is in it, and nothing the size of ONE layer's arena leaf
+    (134 MB) is copied or sliced anywhere — the layer scan feeds the
+    kernel the whole leaf and an index."""
     from nnstreamer_tpu.models import transformer as tfm
+    from nnstreamer_tpu.models.family import DenseFamily
+    from nnstreamer_tpu.models.serving import _PagedLayout, make_pump
     from nnstreamer_tpu.ops.pallas.paged_attention import (
         make_paged_attention,
     )
 
     b, h, kv, layers, n, d_model, d_ff, vocab = CELLS[cell]
-    attn = make_paged_attention(interpret=False)
-
-    def pump(params, tok, pos, active, ak, av, tables):
-        def body(carry, _):
-            tok, pos, arena = carry
-            logits, arena, pos = batched_decode_step_block(
-                params, tok, pos, active, arena, tables, h, attn_fn=attn
-            )
-            return (jnp.argmax(logits, -1).astype(i32), pos, arena), tok
-
-        return jax.lax.scan(body, (tok, pos, (ak, av)), None, length=2)
-
     params = jax.eval_shape(
         lambda: tfm.init_params(
             jax.random.PRNGKey(0), vocab, d_model, h, layers, d_ff=d_ff,
             n_kv_heads=kv,
         )
     )
-    args = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
-        (
-            params,
-            *(jax.ShapeDtypeStruct(s, d) for s, d in (
-                ((b,), i32), ((b,), i32), ((b,), jnp.bool_),
-                ((layers, n, BS, kv, HD), f32),
-                ((layers, n, BS, kv, HD), f32), ((b, NB), i32),
-            )),
-        ),
+    family = DenseFamily(params, h, 512, f32)
+    pump = make_pump(
+        _PagedLayout(family, make_paged_attention(interpret=False)), False
     )
-    text = jax.jit(pump, donate_argnums=(4, 5)).lower(*args).compile(
-    ).as_text()
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    arena = sds((layers, n, BS, kv, HD), f32)
+    vec, fvec = sds((b,), i32), sds((b,), f32)
+    text = pump.lower(
+        (jax.tree.map(lambda x: sds(x.shape, x.dtype), params), None),
+        vec, vec, sds((b,), jnp.bool_), (arena, arena),  # tok pos active
+        sds((b, NB * BS), i32), vec, vec,                # hist budget stop
+        fvec, vec, fvec, sds((b, 2), jnp.uint32),        # the sampler's
+        sds((b, NB), i32),                               # tables
+        n_steps=2,
+    ).compile().as_text()
+    assert "jit_impl" in text  # the name benchmark/configs select it by
     _assert_kernel(text)
     assert not _big_moves(text, n * BS * kv * HD, f",{BS},{kv},{HD}")
+    # arena (both leaves) and history are donated and come back in place
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert aliased.count("may-alias") + aliased.count("must-alias") == 3
 
 
 # (n-slots, max-len) of the per-slot state the admit program rewrites
