@@ -89,9 +89,13 @@ _DEFAULTS: Dict[str, Dict[str, str]] = {
         # every slot at max-len (no memory saving — size it BELOW that
         # to serve more live requests at the same HBM)
         "kv_blocks": "",
-        # prefill buckets advanced per pump (paged chunked prefill):
-        # bounds how long one request's prompt can stall decoders
-        "prefill_chunks": "1",
+        # prefill buckets a pump may spend (paged chunked prefill).
+        # 0 = follow the queue: as many buckets as jobs wait at the
+        # pump's start (at least 1), so admission keeps pace with the
+        # slots that free and a decoding slot waits for the buckets of
+        # the requests queued; N >= 1 caps a pump at N buckets (the
+        # bound on the largest decode stall)
+        "prefill_chunks": "0",
         # declared KV memory bound for nns-lint NNS-W115 (bytes, K/M/G
         # suffixes); empty = lint stays silent
         "memory_bound": "",

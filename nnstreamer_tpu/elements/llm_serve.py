@@ -215,7 +215,7 @@ class _LlmServer:
                  speculate_model: str = "", pump_tokens: int = 1,
                  kv_layout: str = "slot", block_size: int = 16,
                  kv_blocks: int = 0, cache_dtype: str = "auto",
-                 prefill_chunks: int = 1, attn_impl: str = "",
+                 prefill_chunks: int = 0, attn_impl: str = "",
                  plane: str = "", plane_weight: float = 1.0,
                  srv_id: str = "0", migrate_to: str = "",
                  checkpoint_every_tokens: int = 0,
@@ -1088,7 +1088,11 @@ class LlmServerSink(Sink):
             "nns-lint NNS-W129",
         ),
         "prefill-chunks": PropSpec(
-            "int", 0, desc="prefill buckets per pump (paged; 0=[llm])"
+            "int", 0,
+            desc="prefill buckets a pump may spend (paged): N caps a pump "
+            "at N, the bound on the largest decode stall; 0=[llm] "
+            "prefill_chunks, whose 0 follows the queue (one bucket per "
+            "job waiting at the pump's start, at least 1)",
         ),
         "kv-memory-bound": PropSpec(
             "str", "", desc="declared KV HBM bound (lint NNS-W115)"
@@ -1176,7 +1180,7 @@ class LlmServerSink(Sink):
             cfg.get_int("llm", "kv_blocks", 0)
         )
         prefill_chunks = int(self.get_property("prefill-chunks", 0)) or (
-            cfg.get_int("llm", "prefill_chunks", 1)
+            cfg.get_int("llm", "prefill_chunks", 0)
         )
         self._create_kw = dict(
             model=str(self.get_property("model", "zoo:transformer_lm")),

@@ -4,10 +4,15 @@ Three serving-scheduler concerns the paged batcher delegates here:
 
 - **Chunked prefill** (:class:`PrefillJob`): a paged submit() never
   prefill-stalls the decode plane. The prompt becomes a job; each
-  step/pump advances the front job by at most ``prefill_chunks`` buckets
-  of ``prompt_len`` tokens before decoding, so a decoding request's
-  time-between-tokens is bounded by ONE chunk of someone else's prompt,
-  however long that prompt is (pinned by tests/test_kv_paged.py).
+  step/pump spends a budget of ``prompt_len`` buckets on the queue,
+  front job first, before decoding. ``prefill_chunks=0`` (the default)
+  reads the budget off the queue, max(1, jobs waiting at the pump's
+  start): every waiting request is admitted in the pump that finds it,
+  a long prompt alone still advances ONE bucket a pump (pinned by
+  tests/test_kv_paged.py), and a decoding request's wait a pump is
+  bounded by the buckets of the jobs queued, not by one bucket.
+  ``prefill_chunks=N`` caps a pump at N buckets: the operator's bound
+  on the largest stall.
 - **Watermark admission + preemption-by-eviction**: a finished prefill
   only activates when the pool can cover its blocks AND one decode-
   growth block per live request (the watermark) — otherwise it waits,

@@ -762,6 +762,25 @@ def spec_emit_hist(toks, m, final, active, hist, pos_, windowed: bool):
     return emit, hist
 
 
+def request_key(seed) -> np.ndarray:
+    """A request's base PRNG key, made on the host: bit for bit the two
+    ``uint32`` words ``np.asarray(jax.random.PRNGKey(seed))`` gives under
+    the default (threefry) key implementation — the seed's high and low
+    32 bits, the high word 0 where jax would hold the seed in 32 bits (a
+    Python int is an int64 first, as in jax; ``jax_enable_x64`` off
+    narrows every seed). ``submit`` holds the state lock when it keys a
+    request, and a device program there would queue behind the decode
+    launch in flight: this touches no device."""
+    s = np.int64(seed) if isinstance(seed, int) else np.asarray(seed)
+    if s.shape or not np.issubdtype(s.dtype, np.integer):
+        raise TypeError(f"a request seed is one integer, got {seed!r}")
+    bits = int(s) & 0xFFFFFFFFFFFFFFFF
+    wide = s.dtype.itemsize == 8 and jax.config.jax_enable_x64
+    return np.array(
+        [bits >> 32 if wide else 0, bits & 0xFFFFFFFF], np.uint32
+    )
+
+
 @dataclass
 class _Request:
     rid: int
@@ -1499,7 +1518,7 @@ class ContinuousBatcher:
         kv_layout: str = "slot",
         block_size: int = 16,
         kv_blocks: Optional[int] = None,
-        prefill_chunks: int = 1,
+        prefill_chunks: int = 0,
         family=None,
     ):
         """``windowed=True`` makes max_len a sliding attention window
@@ -1722,7 +1741,10 @@ class ContinuousBatcher:
             self._n_migrations_in = 0
             self._n_resumes = 0
             self._n_prefill_chunk_programs = 0
-            self._prefill_chunks = max(1, int(prefill_chunks))
+            self._n_prefill_pumps = 0
+            # bucket programs a pump may spend on the prefill queue:
+            # 0 = as many as jobs are queued at its start, N = at most N
+            self._prefill_chunks = max(0, int(prefill_chunks))
             self._prefixes_paged: Dict[int, Tuple[np.ndarray, List[int]]] = {}
         else:
             self._layout = _SlotLayout(
@@ -2034,9 +2056,10 @@ class ContinuousBatcher:
 
         Paged batchers (``kv_layout="paged"``) admit through the chunked
         prefill queue instead of prefilling here: submit returns
-        immediately and the prompt advances one ``prompt_len`` bucket
-        per step/pump, interleaved with decode — a long prompt can no
-        longer stall decoding slots for whole prefills
+        immediately, with no device work and no wait for a launch in
+        flight, and the next pumps spend their prefill budget on the
+        queue (``_advance_prefill``), interleaved with decode — a long
+        prompt cannot stall decoding slots for its whole prefill
         (docs/llm-serving.md).
         Prompts longer than the prompt_len bucket prefill in bucket-sized
         chunks (decode.verify_chunk; decode.windowed_chunk on a ring when
@@ -2111,9 +2134,7 @@ class ContinuousBatcher:
             req = _Request(
                 rid, max_new_tokens, temperature=temperature, top_k=top_k,
                 top_p=top_p, stop_token=stop_token,
-                key=np.asarray(
-                    jax.random.PRNGKey(rid if seed is None else seed)
-                ),
+                key=request_key(rid if seed is None else seed),
                 # spec_step's proposal context — the prefix's tokens are
                 # part of the stream the n-gram lookup should mine
                 prompt=(
@@ -2327,8 +2348,9 @@ class ContinuousBatcher:
         """Paged admission: claim a slot, match the prompt against the
         pool's prefix index (adopting shared blocks NOW so they cannot
         be reclaimed while queued), and enqueue a chunked-prefill job.
-        No device work happens here — prefill advances one bucket per
-        step/pump, interleaved with decode."""
+        No device work happens here, the request's key included
+        (``request_key``): the lock is held for host bookkeeping only,
+        and prefill advances in the pumps, interleaved with decode."""
         from nnstreamer_tpu.kv.sched import PrefillJob
 
         pfx_tokens = None
@@ -2359,9 +2381,7 @@ class ContinuousBatcher:
             req = _Request(
                 rid, max_new_tokens, temperature=temperature,
                 top_k=top_k, top_p=top_p, stop_token=stop_token,
-                key=np.asarray(
-                    jax.random.PRNGKey(rid if seed is None else seed)
-                ),
+                key=request_key(rid if seed is None else seed),
                 prompt=context,
             )
             self._slots[slot] = req
@@ -2397,37 +2417,64 @@ class ContinuousBatcher:
         job.base = 0
 
     def _advance_prefill(self) -> None:
-        """Advance the front prefill job by ≤ ``prefill_chunks`` buckets
-        and activate it when staged + block-affordable — the chunked-
-        prefill interleave: a decoding slot waits at most this many
-        chunk programs per pump, whatever someone else's prompt length.
+        """Spend this pump's prefill budget on the queue, front job first,
+        activating each job when it is staged + block-affordable.
+
+        The budget is bucket programs (one ``prompt_len`` chunk each).
+        ``prefill_chunks=0`` (the default) reads it off the queue at
+        entry: ``max(1, jobs queued)``, so every request that waits for a
+        lane is prefilled in the pump that finds it waiting and rides that
+        pump's one admit launch — a lane left empty computes nothing for
+        the whole decode launch, while the prefill program stalls whoever
+        is live for the same milliseconds in whichever pump it runs. A
+        long prompt alone in the queue is still chunked one bucket a pump,
+        and what a decoding slot waits in a pump is bounded by the buckets
+        of the jobs queued, not by one bucket. ``prefill_chunks=N >= 1``
+        is the operator's cap on the largest stall: N buckets a pump,
+        whatever is queued.
 
         The throttle exists ONLY to bound decode stalls — while nothing
-        is decoding (no active slot, no activation pending), it would
-        merely serialize admissions one bucket per pump, so an idle
+        is decoding (no active slot, no activation pending), an idle
         decode plane keeps advancing until a job activates or the queue
-        drains (the cold-start admission latency fix; the interleave
-        bound is unchanged the moment anything is live).
+        drains, whatever the budget.
         Caller holds _step_lock; _lock is taken only for bookkeeping."""
-        with _trace.span("nns.pump.prefill", prefill_q=len(self._prefill_q)):
-            budget = self._prefill_chunks
+        queued = len(self._prefill_q)
+        with _trace.span("nns.pump.prefill", prefill_q=queued) as sp:
+            budget = self._prefill_chunks or max(1, queued)
+            buckets = activated = 0
+            tail = None  # what the last programs launched here produce
             while True:
                 with self._lock:
                     job = self._prefill_q[0] if self._prefill_q else None
                     idle = not self._active.any() and not self._pending
                 if job is None or (budget <= 0 and not idle):
-                    return
+                    break
                 self._slo.prefilling(job.req.rid)
                 if not job.done_staging():
+                    # one bucket's outputs on the device at a time: a
+                    # launch allocates its logits and stage when it is
+                    # dispatched and the last one's are freed when its
+                    # readers have run, so k launches queued at once would
+                    # hold k of each (0.1-0.24 GB a bucket at the
+                    # benchmark's widths) however deep the queue is
+                    jax.block_until_ready(tail)
                     self._prefill_chunk_one(job)
+                    tail = job.stage
                     budget -= 1
+                    buckets += 1
                 if job.done_staging():
-                    if self._prefill_finalize(job):
-                        with self._lock:
-                            if self._prefill_q and self._prefill_q[0] is job:
-                                self._prefill_q.popleft()
-                    else:
-                        return  # blocks not affordable yet (watermark)
+                    if not self._prefill_finalize(job):
+                        break  # blocks not affordable yet (watermark)
+                    activated += 1
+                    with self._lock:
+                        if self._prefill_q and self._prefill_q[0] is job:
+                            self._prefill_q.popleft()
+                        # the landed arena and the sampled first token:
+                        # the last readers of that bucket's outputs
+                        tail = (self._cache, self._pending[-1].first_tok)
+            if buckets:
+                self._n_prefill_pumps += 1
+            sp.set(buckets=buckets, activated=activated)
 
     def _prefill_chunk_one(self, job) -> None:
         """One ``prompt_len`` bucket of chunked prefill for ``job``
@@ -2506,7 +2553,9 @@ class ContinuousBatcher:
         n_full = len(job.matched_full)
         fresh_needed = n_blocks - n_full  # includes the CoW copy
         with self._lock:
-            n_live = int(self._active.sum())
+            # decoding now, or finalized earlier in this pump and live at
+            # its launch: each keeps a decode-growth block of headroom
+            n_live = int(self._active.sum()) + len(self._pending)
             if fresh_needed > 0 and (
                 self._pool.available() < fresh_needed + n_live
             ):
@@ -3614,7 +3663,10 @@ class ContinuousBatcher:
                 st["attn_impl"] = self._attn_impl
                 st["kv_migrations_out"] = self._n_migrations_out
                 st["kv_migrations_in"] = self._n_migrations_in
+                # bucket programs launched, and the pumps that launched
+                # at least one: their quotient is buckets a prefilling pump
                 st["kv_prefill_chunks"] = self._n_prefill_chunk_programs
+                st["prefill_pumps"] = self._n_prefill_pumps
                 st["request_resumes"] = self._n_resumes
             st["family"] = self._family.name
             for k, v in self._aux_totals.items():

@@ -371,9 +371,13 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     ),
     "nns.pump.prefill": (
         "batcher",
-        "_advance_prefill: the HOST side of chunked prefill (the programs "
-        "it dispatches run asynchronously)",
-        "prefill_q (jobs waiting at entry)",
+        "_advance_prefill: the HOST side of chunked prefill (a bucket's "
+        "programs run asynchronously; before each further bucket of one "
+        "span the host waits for the last one's outputs, so one bucket's "
+        "logits and stage are on the device at a time)",
+        "prefill_q (jobs waiting at entry), buckets (chunk programs this "
+        "span launched), activated (jobs it finalized); the last two set as "
+        "it closes",
     ),
     "nns.pump.admit": (
         "batcher",

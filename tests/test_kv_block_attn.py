@@ -323,10 +323,10 @@ def test_launch_span_counts_live_blocks(params):
         mp.setattr(nns_trace, "span", spy)
         while any(cb.result(r) is None for r in rids):
             cb.step_pump(8)
-    # one admission a pump, 8 tokens a launch: fills (5), (13, 16),
-    # (21, 24), then the second request alone at 32
-    assert [a["live_blocks"] for a in seen] == [1, 1 + 1, 2 + 2, 2]
-    assert [a["active"] for a in seen] == [1, 2, 2, 1]
+    # both admitted by the first pump (its prefill budget follows the
+    # queue), 8 tokens a launch: fills (5, 16), (13, 24), (21, 32)
+    assert [a["live_blocks"] for a in seen] == [1 + 1, 1 + 2, 2 + 2]
+    assert [a["active"] for a in seen] == [2, 2, 2]
 
 
 # -- configuration / lint ---------------------------------------------------

@@ -554,8 +554,8 @@ def _slot_state(cb):
 
 def _queue(cb, n):
     """Run the host side of admission until ``n`` rows wait in ``_pending``
-    (the slot layout queues in submit; the paged one activates one prefill
-    job per call while an activation is pending)."""
+    (the slot layout queues in submit; the paged one spends a budget read
+    off the prefill queue per call)."""
     with cb._step_lock:
         while cb._paged and len(cb._pending) < n:
             cb._advance_prefill()
@@ -693,7 +693,228 @@ def test_admission_compiles_once(params, layout):
     assert b._admit._cache_size() == 1
 
 
-def _longcat_twin():
+# -- admission keeps pace: the prefill budget follows the queue --------------
+
+_ADMISSION_STATS = ("kv_prefill_chunks", "prefill_pumps", "admit_launches",
+                    "admitted")
+
+
+def _stat_deltas(cb, st0):
+    st = cb.stats()
+    return tuple(st[k] - st0[k] for k in _ADMISSION_STATS)
+
+
+def _sampling_kw(s):
+    """Every other request samples, on a seed of its own."""
+    return dict(temperature=0.8, top_k=5, seed=900 + s) if s % 2 else {}
+
+
+def _one_decoding_then_queue(cb, k, vocab=257):
+    """One request live and decoding, then ``k`` more submitted (each a
+    bucket-sized prompt) and waiting in the prefill queue."""
+    first = cb.submit(_prompt(7, 500) % vocab, 24)
+    cb.step_pump(2)
+    assert cb._active.sum() == 1 and not cb._prefill_q
+    rids = [cb.submit(_prompt(5 + 2 * s, 510 + s) % vocab, 6 + s,
+                      **_sampling_kw(s)) for s in range(k)]
+    assert cb.stats()["kv_prefill_queue"] == k
+    return [first] + rids
+
+
+@pytest.mark.parametrize("kind", ["paged", "paged-longcat"])
+@pytest.mark.parametrize("k", [2, 3])
+def test_pump_prefills_every_queued_job(params, kind, k):
+    """With k jobs queued and a slot decoding, ONE pump launches k bucket
+    programs and splices the k requests in with ONE launch of the admit
+    program; every stream is byte for byte the reference's (the slot
+    layout drained per token; for the latent family, which has no slot
+    layout, a batcher capped at one bucket a pump)."""
+    if kind == "paged-longcat":
+        b, ref, vocab = _longcat_twin(), _longcat_twin(prefill_chunks=1), 97
+    else:
+        b, ref, vocab = _twin(params, **_PAGED), _twin(params), 257
+    rids = _one_decoding_then_queue(b, k, vocab)
+    st0 = b.stats()
+    b.step_pump(2)
+    assert _stat_deltas(b, st0) == (k, 1, 1, k)
+    assert b._active.sum() == k + 1 and b.stats()["kv_prefill_queue"] == 0
+    assert b.result(rids[0]) is None  # it decoded through that pump
+    _drain_pump(b, rids, 2)
+    want = [ref.submit(_prompt(7, 500) % vocab, 24)]
+    want += [ref.submit(_prompt(5 + 2 * s, 510 + s) % vocab, 6 + s,
+                        **_sampling_kw(s)) for s in range(k)]
+    _drain_steps(ref, want)
+    assert _tokens(b, rids) == _tokens(ref, want)
+
+
+@pytest.mark.parametrize("cap", [1, 2])
+def test_prefill_chunks_given_caps_a_pump(params, cap):
+    """``prefill_chunks=N`` keeps its meaning: at most N bucket programs a
+    pump while anything decodes, whatever is queued (N=1: one admission a
+    pump, one admit launch each)."""
+    b = _twin(params, prefill_chunks=cap, **_PAGED)
+    rids = _one_decoding_then_queue(b, 3)
+    st0 = b.stats()
+    b.step_pump(2)
+    assert _stat_deltas(b, st0) == (cap, 1, 1, cap)
+    b.step_pump(2)
+    assert _stat_deltas(b, st0) == ((3, 2, 2, 3) if cap == 2 else (2, 2, 2, 2))
+    _drain_pump(b, rids, 2)
+    assert _stat_deltas(b, st0)[0] == 3
+
+
+def test_buckets_of_one_pump_run_one_at_a_time(params, monkeypatch):
+    """Before every bucket but a span's first the host waits for what the
+    last one's programs produce (the arena it landed in, its first token):
+    k buckets in a pump hold one bucket's logits and stage on the device,
+    not k, so peak memory does not grow with the queue."""
+    from nnstreamer_tpu.models import serving
+
+    b = _twin(params, **_PAGED)
+    rids = _one_decoding_then_queue(b, 3)
+    waited = []
+    real = jax.block_until_ready
+
+    def spy(x):
+        waited.append(x)
+        return real(x)
+
+    monkeypatch.setattr(serving.jax, "block_until_ready", spy)
+    with b._step_lock:
+        b._advance_prefill()
+    monkeypatch.undo()
+    assert waited[0] is None and len(waited) == 3
+    for cache, first in waited[1:]:
+        assert isinstance(first, jax.Array)
+        assert jax.tree_util.tree_leaves(cache)
+    _drain_pump(b, rids, 2)
+
+
+def test_lone_long_prompt_still_chunks_one_bucket_a_pump(params):
+    """The budget is max(1, jobs queued): a long prompt ALONE in the queue
+    advances one bucket a pump beside a decoding slot; with a second job
+    behind it the pump spends two, front job first."""
+    b = _twin(params, **_PAGED)
+    live = b.submit(_prompt(7, 520), 40)
+    b.step_pump(1)
+    long_ = b.submit(_rep_prompt(60, 521), 3)  # 60 tokens = 4 buckets
+    st0 = b.stats()
+    b.step_pump(1)
+    assert _stat_deltas(b, st0) == (1, 1, 0, 0)
+    short = b.submit(_prompt(5, 522), 3)
+    b.step_pump(1)
+    assert _stat_deltas(b, st0) == (3, 2, 0, 0)  # both spent on the long job
+    b.step_pump(1)  # its last bucket, then the short job's: both admitted
+    assert _stat_deltas(b, st0) == (5, 3, 1, 2)
+    _drain_pump(b, [live, long_, short], 4)
+
+
+_SEEDS = [0, 1, 2**31 - 1, 2**31, 2**32 - 1, 2**32 + 5, 2**40 + 3, 2**63 - 1,
+          -1, -2**31 - 1, -2**40, -2**63, True, np.int32(-5),
+          np.uint32(2**32 - 1), np.int64(2**40 + 3), np.uint64(2**63 + 5)]
+
+
+@pytest.mark.parametrize("x64", [False, True])
+@pytest.mark.parametrize("seed", _SEEDS, ids=[repr(s) for s in _SEEDS])
+def test_request_key_is_jax_prngkey_bit_for_bit(seed, x64):
+    """The host-made key is the array ``jax.random.PRNGKey`` gives: the
+    key's bytes are the contract every sampled stream rests on."""
+    from nnstreamer_tpu.models.serving import request_key
+
+    with jax.enable_x64(x64):
+        want = np.asarray(jax.random.PRNGKey(seed))
+        got = request_key(seed)
+    assert got.dtype == want.dtype == np.uint32 and got.shape == (2,)
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("bad", [2**63, -2**63 - 1, 1.5, (1, 2)])
+def test_request_key_refuses_what_jax_refuses(bad):
+    from nnstreamer_tpu.models.serving import request_key
+
+    with pytest.raises((OverflowError, TypeError)):
+        jax.random.PRNGKey(bad)
+    with pytest.raises((OverflowError, TypeError)):
+        request_key(bad)
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_sampled_streams_do_not_change_with_host_keys(params, layout,
+                                                      monkeypatch):
+    """Sampled streams keyed on the host (seeds given, large and negative,
+    and the rid where none is given) are the streams the device-made key
+    gave."""
+    from nnstreamer_tpu.models import serving
+
+    kw = _PAGED if layout == "paged" else {}
+    seeds = [None, 0, 2**40 + 3, -7]
+
+    def streams():
+        b = _twin(params, **kw)
+        rids = [b.submit(_prompt(6 + s, 530 + s), 8, temperature=0.9,
+                         top_k=7, top_p=0.9,
+                         **({} if sd is None else {"seed": sd}))
+                for s, sd in enumerate(seeds)]
+        _drain_pump(b, rids, 3)
+        return _tokens(b, rids)
+
+    host = streams()
+    monkeypatch.setattr(
+        serving, "request_key",
+        lambda seed: np.asarray(jax.random.PRNGKey(seed)))
+    assert host == streams()
+    assert len({tuple(t) for t in host}) == len(seeds)  # they do sample
+
+
+@pytest.mark.parametrize("layout", ["slot", "paged"])
+def test_submit_returns_while_a_pump_is_in_flight(params, layout,
+                                                  monkeypatch):
+    """``submit`` makes no key on the device (``jax.random.PRNGKey`` raises
+    here) and does not wait for the decode launch in flight: it returns
+    with its rid while the pump's program is still running."""
+    import threading
+
+    b = _twin(params, **(_PAGED if layout == "paged" else {}))
+    first = b.submit(_prompt(6, 540), 12)
+    b.step_pump(2)
+    entered, release = threading.Event(), threading.Event()
+    launch = b._pump_greedy
+
+    def slow_launch(*a, **k):
+        entered.set()
+        assert release.wait(60)
+        return launch(*a, **k)
+
+    def no_device_key(*a, **k):
+        raise AssertionError("submit made its key on the device")
+
+    monkeypatch.setattr(b, "_pump_greedy", slow_launch)
+    monkeypatch.setattr(jax.random, "PRNGKey", no_device_key)
+    got = {}
+    pump = threading.Thread(target=b.step_pump, args=(2,))
+    pump.start()
+    try:
+        assert entered.wait(60)
+        sub = threading.Thread(
+            target=lambda: got.update(rid=b.submit(_prompt(5, 541), 4,
+                                                   temperature=0.7, seed=3)))
+        sub.start()
+        sub.join(60)
+        assert not sub.is_alive() and got["rid"] is not None
+        assert pump.is_alive()  # the launch it did not wait for
+    finally:
+        release.set()
+        pump.join(60)
+    _drain_pump(b, [first, got["rid"]], 2)
+
+
+def test_llm_config_default_follows_the_queue():
+    from nnstreamer_tpu import config
+
+    assert config.Config().get_int("llm", "prefill_chunks", 1) == 0
+
+
+def _longcat_twin(**kw):
     import jax.numpy as jnp
 
     from nnstreamer_tpu.models import longcat as lc
@@ -706,7 +927,7 @@ def _longcat_twin():
     return ContinuousBatcher(
         lc.init_params(cfg, 3, jnp.float32), cfg.n_heads, n_slots=4,
         max_len=96, prompt_len=16, kv_layout="paged",
-        family=lc.LongcatFamily(cfg, jnp.float32),
+        family=lc.LongcatFamily(cfg, jnp.float32), **kw,
     )
 
 

@@ -220,6 +220,21 @@ def test_admit_spans_count_what_they_spliced_in_one_launch(traced, ops):
                    if not a["stats"]["admitted"])
 
 
+def test_prefill_spans_count_their_buckets_and_activations(traced):
+    """``nns.pump.prefill`` says how many bucket programs it launched and
+    how many jobs it finalized (set as it closes): over the run, one
+    activation a request and one bucket per ``prompt-len`` chunk of every
+    prompt; a span never launches more buckets than max(1, its
+    ``prefill_q``) unless nothing was decoding."""
+    events, _, _ = traced
+    spans = [e["stats"] for e in events if e["name"] == "nns.pump.prefill"]
+    assert all({"prefill_q", "buckets", "activated"} <= set(s) for s in spans)
+    assert sum(s["activated"] for s in spans) == len(PROMPT_LENS)
+    assert sum(s["buckets"] for s in spans) == sum(
+        -(-n // 16) for n in PROMPT_LENS)
+    assert all(s["activated"] <= max(1, s["buckets"]) for s in spans)
+
+
 def test_programs_have_names_of_their_own_and_decode_keeps_impl(traced):
     _, modules, _ = traced
     assert "jit_impl" in modules, "the decode module the benchmark finds by name"
