@@ -97,7 +97,8 @@ class BlockPool:
     counter; resolved once by the batcher at construction like every
     other emitter."""
 
-    def __init__(self, n_blocks: int, block_size: int, obs_registry=None):
+    def __init__(self, n_blocks: int, block_size: int, obs_registry=None,
+                 index: bool = True):
         if n_blocks < 1:
             raise ValueError("BlockPool needs at least one usable block")
         self.n_blocks = int(n_blocks)
@@ -115,6 +116,9 @@ class BlockPool:
         self.prefix_hit_tokens = 0
         self.cow_copies = 0
         self._obs = obs_registry
+        # False: ``register`` indexes nothing, so ``match`` finds nothing
+        # (a family whose cache cannot be adopted block by block)
+        self._indexing = bool(index)
 
     # -- capacity ----------------------------------------------------------
     def available(self) -> int:
@@ -219,6 +223,8 @@ class BlockPool:
         one entry per full block boundary (read-only shareable) plus one
         for the trailing partial block, if any (CoW-shareable). Already-
         indexed boundaries (the matched prefix itself) are skipped."""
+        if not self._indexing:
+            return
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         bs = self.block_size
         h = 0

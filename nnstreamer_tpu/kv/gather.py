@@ -109,7 +109,7 @@ def make_staging_ops(quantized: bool, compute_dtype):
       columns in one launch: the prefix-seeded prefill source
       (replaces a ``read_block`` + two dynamic-update launches per
       matched block);
-    - ``land_stage(arena, stage, ids, valid)`` — write every stage
+    - ``land_stage(arena, stage, ids, valid, slot)`` — write every stage
       block ``i`` with ``valid[i]`` to arena block ``ids[i]``
       (quantizing when int8 — per token per head, so slicing per block
       first would change nothing) in one launch; invalid lanes route
@@ -120,7 +120,14 @@ def make_staging_ops(quantized: bool, compute_dtype):
     Values are bitwise the per-block ops' — only the dispatch count
     changes (the paged admission path used to cost ~2 launches per
     block of prompt, a real tax on the `bench.py --pipeline llm`
-    equal-occupancy cell)."""
+    equal-occupancy cell).
+
+    A family's SLOT leaves (models/family.py: per-slot state that no
+    block holds) follow the two block leaves in both trees. They are not
+    in any block: ``seed_stage`` hands the stage's back as they are, and
+    ``land_stage`` takes the lane ``slot`` and writes each one's single
+    staged row ``[layers, 1, ...]`` to row ``slot`` of its arena leaf
+    (unused, and pruned from the program, where there are none)."""
 
     def seed_stage(arena, stage, ids, n_seed):
         S = ids.shape[0]
@@ -133,7 +140,7 @@ def make_staging_ops(quantized: bool, compute_dtype):
                 return dequantize_kv(t, s)
             tk, tv = taken(ka, ksc), taken(va, vsc)
         else:
-            ka, va = arena
+            ka, va = arena[:2]
             tk = jnp.take(ka, ids, axis=1)
             tv = jnp.take(va, ids, axis=1)
         bs = tk.shape[2]
@@ -148,11 +155,11 @@ def make_staging_ops(quantized: bool, compute_dtype):
             )
             return jnp.where(keep, flat, sleaf)
 
-        return place(tk, stage[0]), place(tv, stage[1])
+        return (place(tk, stage[0]), place(tv, stage[1])) + tuple(stage[2:])
 
-    def land_stage(arena, stage, ids, valid):
+    def land_stage(arena, stage, ids, valid, slot):
         S = ids.shape[0]
-        ks, vs = stage  # [L, 1, S*bs, KV, Dh] compute dtype
+        ks, vs = stage[:2]  # [L, 1, S*bs, KV, Dh] compute dtype
 
         def rows_of(s):
             return s.reshape((s.shape[0], S, -1) + s.shape[3:])
@@ -171,8 +178,11 @@ def make_staging_ops(quantized: bool, compute_dtype):
                 (put(ka, rows_of(k8)), put(ksc, rows_of(ksn), 1.0)),
                 (put(va, rows_of(v8)), put(vsc, rows_of(vsn), 1.0)),
             )
-        ka, va = arena
-        return (put(ka, rows_of(ks)), put(va, rows_of(vs)))
+        ka, va = arena[:2]
+        return (put(ka, rows_of(ks)), put(va, rows_of(vs))) + tuple(
+            a.at[:, slot].set(s[:, 0].astype(a.dtype))
+            for a, s in zip(arena[2:], stage[2:])
+        )
 
     return (
         jax.jit(seed_stage, donate_argnums=1),

@@ -5,14 +5,28 @@ admission, pumps and harvest; what differs between model families is what
 one token leaves in the cache and how a step computes. A family answers
 exactly that:
 
-``arena(n_blocks, block_size, quantized)``
-    the zeroed block arena: a pair of leaves (or int8 ``(payload, scale)``
+``arena(n_blocks, block_size, quantized, n_slots)``
+    the zeroed arena: a pair of BLOCK leaves (or int8 ``(payload, scale)``
     pairs) shaped ``[cache layers, n_blocks + 1, block_size, ...]`` — block 0
-    is scratch. ``kv.gather``'s staging ops and ``write_fresh_window`` treat
-    whatever follows the first three dims as one token's entry.
+    is scratch; ``kv.gather``'s staging ops and ``write_fresh_window`` treat
+    whatever follows the first three dims as one token's entry — then the
+    family's ``slot_leaves`` SLOT leaves ``[state layers, n_slots + 1, ...]``:
+    what a layer keeps per slot and not per token (a recurrent state), lane b
+    in row b, the last row scratch. The whole tuple is the decode programs'
+    carried, donated cache.
 ``stage(length)``
     the contiguous staging cache of chunked prefill, ``[cache layers, 1,
-    length, ...]`` per leaf.
+    length, ...]`` per block leaf, then ``[state layers, 1, ...]`` per slot
+    leaf: the state a prompt has reached, carried from bucket to bucket.
+``slot_leaves``
+    how many slot leaves follow the block leaves (0: none, and the family's
+    programs are what they were). For a slot leaf the batcher does three
+    things. Landing a finalized job writes the stage's row to the slot's row
+    (``kv.gather.make_staging_ops``). Preemption keeps nothing: blocks are
+    freed, the row is overwritten when the job, re-prefilled, lands again.
+    And since the state at a block boundary is stored nowhere, a prefix
+    cannot be adopted: such a family lists ``prefix sharing`` in
+    ``unsupported`` and its pool indexes no blocks, so nothing ever matches.
 ``prefill(params, tokens)`` / ``chunk(params, tokens, cpos, stage, return_logits)``
     one prompt bucket from position 0 / one bucket at ``cpos`` against the
     stage -> ``(logits, stage leaves, pos)``.
@@ -52,6 +66,7 @@ from nnstreamer_tpu.models import transformer as tfm
 class DenseFamily:
     name = "dense"
     pad_id = 0
+    slot_leaves = 0
     aux_names: Tuple[str, ...] = ()
     aux_prefix = ""
     unsupported: Tuple[str, ...] = ()
@@ -65,7 +80,8 @@ class DenseFamily:
         self.head_dim = d // n_heads
         self.n_kv_heads = tfm.n_kv_heads_of(params["blocks"]["wqkv"], d, n_heads)
 
-    def arena(self, n_blocks: int, block_size: int, quantized: bool = False):
+    def arena(self, n_blocks: int, block_size: int, quantized: bool = False,
+              n_slots: int = 0):
         return kvg.init_arena(self.n_layers, n_blocks, block_size, self.n_kv_heads,
                               self.head_dim, quantized, self.compute_dtype)
 

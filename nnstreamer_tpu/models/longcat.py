@@ -320,26 +320,24 @@ def grouped_ffn_ragged(xs, sizes, lp: Dict):
     return dot((gate * dot(xs, lp["e_up"])).astype(dt), lp["e_down"])
 
 
-# a bucket of more (token, pick) pairs than this tries a grouped matmul over an
-# eighth of them first: 16 of 768 router outputs are local, so a prompt's 6144
-# pairs hold about 128 local ones, and the worst case stays exact
+# a bucket of more (token, pick) pairs than this tries a grouped matmul over
+# ``few`` of them first: here 16 of 768 router outputs are local, so a prompt's
+# 6144 pairs hold about 128 local ones, an eighth covers them with room, and
+# the worst case stays exact
 MOE_FEW_PAIRS = 1024
 
 
-def moe(b, live, lp: Dict, c: LongcatConfig):
-    """The expert layer's share. b [T, d] float32 (normed), live [T] bool
-    (tokens that exist: dead slots and padding route nowhere) ->
-    (s [T, d] float32, stats [5] int32 in ``MOE_STATS`` order)."""
-    t, d = b.shape
-    k, e0, n = c.topk, c.expert_offset, c.n_held
-    idx, w = route(b, lp, c)
-    pick = live[:, None]
-    zero = pick & (idx >= c.n_routed)
-    local = pick & (idx >= e0) & (idx < e0 + n)
-    ident = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)
-    # pairs sorted by held expert; everything else is group n, the tail no
-    # group covers: the local pairs are the first sum(sizes) sorted rows
-    group = jnp.where(local, idx - e0, n).reshape(-1)
+def dispatch_held(b, w, local, group, lp: Dict, n: int, few: float = 1 / 8):
+    """The held experts' weighted outputs summed back onto their tokens.
+    b [T, d] float32; w [T, k] the picks' weights; local [T, k] the picks
+    that fall on an expert held here; group [T, k] their expert's index
+    among the ``n`` held (``n`` elsewhere) -> (s [T, d] float32, sizes [n]
+    int32 pairs per held expert). Pairs are sorted by held expert;
+    everything else is group n, the tail no group covers: the local pairs
+    are the first sum(sizes) sorted rows. ``few`` is the share of the pairs
+    a bucket of more than ``MOE_FEW_PAIRS`` tries first."""
+    t, k = w.shape
+    group = group.reshape(-1)
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(
         group[:, None] == jnp.arange(n, dtype=group.dtype)[None, :], axis=0
@@ -362,11 +360,23 @@ def moe(b, live, lp: Dict, c: LongcatConfig):
 
     pairs = t * k
     if pairs > MOE_FEW_PAIRS:
-        few = max(pairs // 8, n)
-        s = jax.lax.cond(jnp.sum(sizes) <= few,
-                         lambda: experts(few), lambda: experts(pairs))
-    else:
-        s = experts(pairs)
+        rows = max(int(pairs * few), n)
+        return jax.lax.cond(jnp.sum(sizes) <= rows,
+                            lambda: experts(rows), lambda: experts(pairs)), sizes
+    return experts(pairs), sizes
+
+
+def moe(b, live, lp: Dict, c: LongcatConfig):
+    """The expert layer's share. b [T, d] float32 (normed), live [T] bool
+    (tokens that exist: dead slots and padding route nowhere) ->
+    (s [T, d] float32, stats [5] int32 in ``MOE_STATS`` order)."""
+    k, e0, n = c.topk, c.expert_offset, c.n_held
+    idx, w = route(b, lp, c)
+    pick = live[:, None]
+    zero = pick & (idx >= c.n_routed)
+    local = pick & (idx >= e0) & (idx < e0 + n)
+    ident = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)
+    s, sizes = dispatch_held(b, w, local, jnp.where(local, idx - e0, n), lp, n)
     s = s + ident[:, None] * b
     stats = jnp.stack([
         jnp.sum(live), jnp.sum(local), jnp.sum(sizes > 0), jnp.sum(zero),
@@ -545,6 +555,7 @@ class LongcatFamily:
 
     name = "longcat_flash"
     pad_id = -1                       # padding routes nowhere (``moe``'s live mask)
+    slot_leaves = 0
     aux_names: Tuple[str, ...] = MOE_STATS
     aux_prefix = "moe_"               # stats() keys: moe_tokens, moe_local_pairs, ...
     decode_kernel = "mla_paged_decode_attention"
@@ -557,7 +568,8 @@ class LongcatFamily:
         self.config = config
         self.dtype = jnp.dtype(dtype)
 
-    def arena(self, n_blocks: int, block_size: int, quantized: bool = False):
+    def arena(self, n_blocks: int, block_size: int, quantized: bool = False,
+              n_slots: int = 0):
         c = self.config
         lead = (2 * c.n_layers, n_blocks + 1, block_size)
         return (jnp.zeros(lead + (c.kv_rank,), self.dtype),

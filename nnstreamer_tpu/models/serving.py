@@ -1037,6 +1037,9 @@ class _Layout:
     def live_blocks(self, b) -> int:
         return 0
 
+    def state_bytes(self, b) -> int:
+        return 0
+
 
 class _SlotLayout(_Layout):
     """One contiguous ``[L, n_slots, max_len, KV, Dh]`` cache per K and V
@@ -1198,6 +1201,10 @@ class _PagedLayout(_Layout):
 
     def live_blocks(self, b) -> int:
         return b._live_blocks_locked()
+
+    def state_bytes(self, b) -> int:
+        """Per-slot state (the family's slot leaves) of the live lanes."""
+        return int(b._active.sum()) * b._slot_state_bytes
 
 
 # carried and hist, counted with the weights first: aliased outputs update
@@ -1707,14 +1714,28 @@ class ContinuousBatcher:
                     f"kv_blocks({kv_blocks}) cannot hold even one "
                     f"max_len request ({self._blocks_per_slot} blocks)"
                 )
+            # a family that cannot share prefixes (per-slot state: the
+            # state at a block boundary is stored nowhere) indexes no
+            # block, so no prompt ever matches one
             self._pool = BlockPool(
-                int(kv_blocks), block_size, obs_registry=self._obs_reg
+                int(kv_blocks), block_size, obs_registry=self._obs_reg,
+                index="prefix sharing" not in family.unsupported,
             )
-            # self._cache IS the block arena in paged mode: every
+            # self._cache IS the arena in paged mode (block leaves, then
+            # the family's slot leaves, models/family.py): every
             # donated-launch/commit/failure-latch path stays identical
             self._cache = family.arena(
-                int(kv_blocks), block_size, quantized_cache
+                int(kv_blocks), block_size, quantized_cache, n_slots
             )
+            # resident bytes of one slot's row over the slot leaves
+            self._slot_state_bytes = sum(
+                leaf.nbytes // leaf.shape[1]
+                for leaf in self._cache[len(self._cache) - family.slot_leaves:]
+            )
+            if self._obs_reg is not None and family.slot_leaves:
+                self._obs_reg.gauge("nns_slot_state_bytes").set(
+                    float(self._slot_state_bytes * n_slots)
+                )
             self._tables = np.zeros(
                 (n_slots, self._blocks_per_slot), np.int32
             )
@@ -1949,6 +1970,7 @@ class ContinuousBatcher:
         sliding-window semantics)."""
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         plen = tokens.shape[0]
+        self._refuse("prefix sharing")
         if self._paged:
             # paged: prefill ONCE into pool blocks, register them in the
             # prefix index, and PIN them (the registration holds one
@@ -1979,7 +2001,7 @@ class ContinuousBatcher:
                 valid[: n_blocks] = True
                 self._cache = self._land_stage(
                     self._cache, stage, jnp.asarray(ids),
-                    jnp.asarray(valid),
+                    jnp.asarray(valid), np.int32(0),
                 )
                 with self._lock:
                     self._pool.register(tokens, blocks)
@@ -2505,9 +2527,8 @@ class ContinuousBatcher:
                 # with contiguous admission)
                 padded = np.full((1, P), self._family.pad_id, np.int32)
                 padded[0, :t] = ctx
-                logits, (ks, vs), _ = self._prefill(jnp.asarray(padded))
+                logits, job.stage, _ = self._prefill(jnp.asarray(padded))
                 job.logits_row = logits[0, t - 1]
-                job.stage = (ks, vs)
                 job.cpos = t
                 return
             stage = self._empty_stage()
@@ -2540,8 +2561,10 @@ class ContinuousBatcher:
         job.cpos += n
 
     def _prefill_finalize(self, job) -> bool:
-        """Allocate the job's blocks, land staged K/V, register its
-        prefix, and queue the activation. False = not affordable yet
+        """Allocate the job's blocks, land staged K/V (and, where the
+        family has slot leaves, the state the prompt reached into the
+        slot's row), register its prefix, and queue the activation.
+        False = not affordable yet
         under the watermark (every live request keeps one decode-growth
         block of headroom), so the job waits — admission can defer but
         never OOM the decode plane."""
@@ -2601,7 +2624,7 @@ class ContinuousBatcher:
                     valid[i] = True
                 self._cache = self._land_stage(
                     self._cache, job.stage, jnp.asarray(ids),
-                    jnp.asarray(valid),
+                    jnp.asarray(valid), np.int32(job.slot),
                 )
         elif job.matched_partial is not None and fresh:
             # fully-matched resume ending in a partial block: pure
@@ -2671,8 +2694,10 @@ class ContinuousBatcher:
                 self._tables_dirty = True
 
     def _preempt_locked(self, slot: int) -> None:
-        """Evict ``slot``'s request: free its blocks and queue a
-        re-prefill job for its full known stream (prompt + generated
+        """Evict ``slot``'s request: free its blocks (a family's per-slot
+        state is dropped with them: the row is overwritten when the job
+        lands again) and queue a re-prefill job for its full known stream
+        (prompt + generated
         tokens, pending token carried as known_first so the resumed
         stream is exactly the original — greedy AND sampled, since
         sampling keys by (seed, position))."""
@@ -3202,6 +3227,7 @@ class ContinuousBatcher:
             sampling = self._any_sampling_locked(active_np)
             budget_dev, stop_dev, active_dev = self._pump_state_locked()
             live_blocks = self._layout.live_blocks(self)
+            state_bytes = self._layout.state_bytes(self)
             carried, fixed = self._layout.args(self)
             args = (
                 self._tok, self._pos, active_dev, carried, self._hist,
@@ -3212,7 +3238,8 @@ class ContinuousBatcher:
         try:
             with _trace.span("nns.pump.launch",
                              active=int(active_np.sum()),
-                             live_blocks=live_blocks):
+                             live_blocks=live_blocks,
+                             state_bytes=state_bytes):
                 emits, tok, pos, act, carried, hist, budget = fn(
                     *args, n_steps=n
                 )

@@ -514,30 +514,48 @@ def _transformer_lm(**options) -> ZooModel:
     return ZooModel("transformer_lm", fn, spec, params, apply_fn)
 
 
-@model_factory("longcat_flash_lm")
-def _longcat_flash_lm(**options) -> ZooModel:
-    """LongCat-Flash (models/longcat.py): latent attention, the
-    shortcut-connected double layer, one chip's share of the routed experts
-    with identity experts. ``custom=`` gives the widths by their short names
-    (published by default), ``n_layers``, ``experts_held``, ``expert_offset``,
-    ``vocab``, ``seed`` and the storage ``dtype`` (bfloat16 by default:
-    weights drawn in float32 from the seed and rounded once).
+def _family_lm(name: str, mod, family_cls, options) -> ZooModel:
+    """A language model served through its own block family (models/
+    family.py): ``mod`` gives ``config_from_options``, ``init_params`` and
+    ``apply``. ``custom=`` takes the widths by their short names (published
+    by default), ``n_layers``, ``experts_held``, ``expert_offset``, ``vocab``,
+    ``seed`` and the storage ``dtype`` (bfloat16 by default: weights drawn in
+    float32 from the seed and rounded once).
     fn: int32 tokens [B,T] -> logits [B,T,V]."""
-    from nnstreamer_tpu.models import longcat
-
-    cfg = longcat.config_from_options(options)
+    cfg = mod.config_from_options(options)
     dtype = jnp.dtype(options.get("dtype", "bfloat16"))
-    params = longcat.init_params(cfg, int(options.get("seed", 0)), dtype)
+    params = mod.init_params(cfg, int(options.get("seed", 0)), dtype)
 
     def apply_fn(p, tokens):
-        return longcat.apply(p, tokens, cfg)
+        return mod.apply(p, tokens, cfg)
 
     spec = TensorsSpec.of(TensorSpec(
         (int(options.get("batch", 1)), int(options.get("seqlen", 128))),
         DType.from_any("int32"), name="tokens"))
-    return ZooModel("longcat_flash_lm", lambda tokens: apply_fn(params, tokens),
-                    spec, params, apply_fn,
-                    family=longcat.LongcatFamily(cfg, dtype))
+    return ZooModel(name, lambda tokens: apply_fn(params, tokens),
+                    spec, params, apply_fn, family=family_cls(cfg, dtype))
+
+
+@model_factory("longcat_flash_lm")
+def _longcat_flash_lm(**options) -> ZooModel:
+    """LongCat-Flash (models/longcat.py): latent attention, the
+    shortcut-connected double layer, one chip's share of the routed experts
+    with identity experts (``_family_lm`` has the options)."""
+    from nnstreamer_tpu.models import longcat
+
+    return _family_lm("longcat_flash_lm", longcat, longcat.LongcatFamily, options)
+
+
+@model_factory("kimi_linear_lm")
+def _kimi_linear_lm(**options) -> ZooModel:
+    """Kimi-Linear (models/kimi_linear.py): Kimi Delta Attention layers with a
+    per-slot recurrent state beside latent (MLA, unrotated) layers, a leading
+    dense layer, sigmoid-routed experts with a shared expert, one chip's share
+    of the routed experts (``_family_lm`` has the options)."""
+    from nnstreamer_tpu.models import kimi_linear
+
+    return _family_lm("kimi_linear_lm", kimi_linear, kimi_linear.KimiLinearFamily,
+                      options)
 
 
 @model_factory("vit")

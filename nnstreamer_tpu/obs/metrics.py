@@ -165,6 +165,16 @@ METRIC_CATALOG: Dict[str, str] = {
     "nns_moe_picks_total": (
         "router picks of live tokens: tokens x top-k (counter)"
     ),
+    "nns_slot_state_bytes": (
+        "resident bytes of the per-slot state a block family keeps beside "
+        "its block arena (recurrent state and convolution tails of every "
+        "slot; models/kimi_linear.py) (gauge; docs/llm-serving.md)"
+    ),
+    "nns_slot_state_updates_total": (
+        "(live lane, state layer) updates of per-slot recurrent state in "
+        "harvested decode pumps: each reads and writes one layer's state "
+        "of one slot (counter)"
+    ),
     "nns_kv_migrations_total": (
         "live request migrations through kv/migrate.py spans, by "
         "direction label: out (extracted and shipped to a peer) / in "

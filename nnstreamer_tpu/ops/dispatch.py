@@ -58,6 +58,7 @@ KNOWN_OPS = (
     "crop_and_resize",
     "decode_attention",
     "flash_attention",
+    "kda_recurrence",
     "mla_attention",
     "nms",
     "resize_bilinear",

@@ -397,7 +397,9 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
         "the call of the jitted decode program (dispatch only)",
         "active (slots live at the launch), live_blocks (paged: arena "
         "blocks its attention reads per step and layer, the sum over "
-        "active slots of ceil(fill / block-size); 0 under the slot layout)",
+        "active slots of ceil(fill / block-size); 0 under the slot layout), "
+        "state_bytes (resident per-slot state of the live lanes over the "
+        "family's slot leaves; 0 for a family with none)",
     ),
     "nns.pump.wait": (
         "batcher",
@@ -413,13 +415,22 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     "nns.moe.routing": (
         "expert layer",
         "instant per harvested decode pump of a routed-expert family "
-        "(models/longcat.py): the router's counters, summed on the device "
-        "over the pump's steps and expert layers and carried home by the "
-        "pump's one readback",
+        "(models/longcat.py, models/kimi_linear.py): the router's counters, "
+        "summed on the device over the pump's steps and expert layers and "
+        "carried home by the pump's one readback",
         "tokens (live token x layer evaluations), local_pairs (pairs on the "
         "experts held here), experts_hit (distinct held experts with a "
-        "token, per layer and step), zero_picks (identity experts chosen), "
-        "picks (tokens x top-k)",
+        "token, per layer and step), zero_picks (identity experts chosen; "
+        "only where the family has them), picks (tokens x top-k)",
+    ),
+    "nns.state.update": (
+        "KDA layers",
+        "instant per harvested decode pump of a family with per-slot "
+        "recurrent state (models/kimi_linear.py), beside nns.moe.routing "
+        "and from the same readback",
+        "slot_layers ((live lane, state layer) updates, summed over the "
+        "pump's steps), bytes (state those updates read and wrote: "
+        "slot_layers x 2 x heads x d_k x d_v x 4)",
     ),
     "nns.req.submit": (
         "batcher",

@@ -238,6 +238,28 @@ def test_paged_decode_step_cell_shapes(one_chip, cell):
     assert aliased.count("may-alias") + aliased.count("must-alias") == 3
 
 
+@pytest.mark.parametrize("heads", [8, 16, 32])
+def test_kda_decode_step_cell_shape(one_chip, heads):
+    """Kimi Delta Attention's decode recurrence as kimi-linear-48b-a3b's
+    cell launches it: 128 lanes, 32 heads of 128 x 128 float32 state, 7 KDA
+    layers in one leaf, the leaf donated and updated in place."""
+    from nnstreamer_tpu.ops.pallas.kda import kda_decode_step
+
+    b, h, d, layers = 128, 32, 128, 7
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((layers, b + 1, h, d, d), f32), ((b, h, d), f32), ((b, h, d), f32),
+        ((b, h, d), f32), ((b, h, d), f32), ((b, h), f32), ((b,), jnp.bool_))]
+    text = jax.jit(
+        lambda s, q, k, v, a, be, act: kda_decode_step(
+            s, q, k, v, a, be, act, layer=3, heads=heads, interpret=False),
+        donate_argnums=0,
+    ).lower(*args).compile().as_text()
+    _assert_kernel(text)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert "alias" in aliased   # the state comes back in its own buffer
+    assert not _big_moves(text, b * h * d * d, f",{h},{d},{d}")
+
+
 # (n-slots, max-len) of the per-slot state the admit program rewrites
 ADMIT_CELLS = {"olmo-1b": (16, 1024), "longcat-flash-chat": (64, 2048)}
 

@@ -122,10 +122,11 @@ def ops(trace_file):
             if "hlo_module" in e["stats"]]
 
 
-# ``nns.moe.routing`` is written by the routed-expert family only; this run
-# serves the dense block (tests/test_longcat.py traces the other)
+# ``nns.moe.routing`` is written by the routed-expert families only and
+# ``nns.state.update`` by the one with per-slot state; this run serves the
+# dense block (tests/test_longcat.py and tests/test_kimi_linear.py trace them)
 @pytest.mark.parametrize(
-    "name", sorted(set(trace.SPAN_CATALOG) - {"nns.moe.routing"}))
+    "name", sorted(set(trace.SPAN_CATALOG) - {"nns.moe.routing", "nns.state.update"}))
 def test_every_cataloged_span_is_on_the_profilers_timeline(traced, name):
     events, _, _ = traced
     assert any(e["name"] == name for e in events), (
