@@ -67,6 +67,62 @@ def prefill(
     return logits, (cache_k, cache_v), jnp.asarray(t, jnp.int32)
 
 
+def segment_attention(segment):
+    """The ``attn_fn`` of a bucket that holds several prompts: row i
+    attends row j iff both are rows of the same prompt
+    (``segment[i] == segment[j] >= 0``) and ``j <= i``. The formulation is
+    ``dense_attention``'s with that mask for its causal one (masked scores
+    are NEG_INF before the softmax in both), so a prompt's rows see what
+    they would see alone in the bucket, up to summation order."""
+    row = jnp.arange(segment.shape[0])
+    mask = (
+        (segment[:, None] == segment[None, :])
+        & (segment[:, None] >= 0)
+        & (row[None, :] <= row[:, None])
+    )
+
+    def attn(q, k, v, causal: bool = True):
+        scale = 1.0 / (q.shape[-1] ** 0.5)
+        s = jnp.einsum(
+            "bqhd,bkhd->bhqk", q.astype(jnp.float32), k.astype(jnp.float32)
+        ) * scale
+        s = jnp.where(mask[None, None], s, tfm.NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+
+    return attn
+
+
+def prefill_packed(
+    params: Dict,
+    tokens,
+    positions,
+    segment,
+    last,
+    n_heads: int,
+    compute_dtype=jnp.float32,
+):
+    """Several prompts through the model in ONE bucket.
+
+    tokens [1, P]: the prompts laid end to end (the batcher starts each on
+    a multiple of its block size), ``positions`` [P] each row's position
+    inside its own prompt (what RoPE rotates by), ``segment`` [P] int32
+    the prompt a row belongs to (-1 on padding rows), ``last`` [K] the row
+    of each prompt's last token (-1 where the bucket holds fewer) →
+    (logits [K, V] f32, (cache_k, cache_v) [L, 1, P, KV, Dh]): the K/V of
+    every row as ``prefill`` would leave them for that prompt alone, and
+    the final norm and vocabulary head over the K gathered rows only."""
+    x = tfm.embed_lookup(params["embed"], tokens, compute_dtype)
+    x, (ks, vs) = tfm.apply_layers(
+        params["blocks"], x, n_heads, positions,
+        attn_fn=segment_attention(segment), return_kv=True,
+    )
+    rows = jnp.take(x[0], jnp.maximum(last, 0), axis=0)  # [K, D]
+    rows = tfm.rmsnorm(rows, params["ln_f"])
+    logits = (rows @ tfm.wt(params["head"], rows.dtype)).astype(jnp.float32)
+    return logits, (ks.astype(compute_dtype), vs.astype(compute_dtype))
+
+
 def decode_step(
     params: Dict,
     token,

@@ -30,6 +30,14 @@ exactly that:
 ``prefill(params, tokens)`` / ``chunk(params, tokens, cpos, stage, return_logits)``
     one prompt bucket from position 0 / one bucket at ``cpos`` against the
     stage -> ``(logits, stage leaves, pos)``.
+``prefill_packed(params, tokens, positions, segment, last)`` (optional)
+    several bucket-sized prompts in one bucket, each starting on a block
+    boundary (``models/decode.prefill_packed``) -> ``(logits [K, V] of the
+    K = prompt_len // block_size possible prompts' last rows, stage
+    leaves)``. A family that has it lets a pump that finds two or more such
+    prompts queued share programs between them; one that has not (experts
+    dispatched over mixed prompts, a state to reset where a prompt starts)
+    gives every prompt a bucket of its own, always.
 ``decode_step(params, tok, pos, active, arena, tables, attn_fn)``
     one token for every live slot straight off the arena ->
     ``(logits, arena, pos', aux)``; ``aux`` is an int32 vector of
@@ -93,6 +101,11 @@ class DenseFamily:
     def prefill(self, params, tokens):
         return dec.prefill(params, tokens, self.n_heads, self.prompt_len,
                            compute_dtype=self.compute_dtype)
+
+    def prefill_packed(self, params, tokens, positions, segment, last):
+        return dec.prefill_packed(params, tokens, positions, segment, last,
+                                  self.n_heads,
+                                  compute_dtype=self.compute_dtype)
 
     def chunk(self, params, tokens, cpos, stage, return_logits: bool = True):
         return dec.verify_chunk(params, tokens, cpos, stage, self.n_heads,

@@ -821,6 +821,16 @@ class _PendingInsert:
     resumed: bool = False  # paged: re-admission after preemption
 
 
+@dataclass
+class _PrefillBin:
+    """Bucket-sized prompts that share one packed prefill program: the jobs
+    in queue order and the rows they take (a prompt starts on a block
+    boundary, so its last block's unused rows count)."""
+
+    jobs: List[Any] = field(default_factory=list)
+    rows: int = 0
+
+
 def _named(fn, name: str):
     """``fn`` under a name of its own: a jitted lambda is ``jit__lambda``
     in a device trace, indistinguishable from every other one. The name
@@ -916,6 +926,25 @@ def nns_sample_first(logits, temp, topk, topp, key, fill):
     )[0]
 
 
+# first tokens one launch of nns_sample_first_rows picks: the sampler's two
+# sorts over the vocabulary cost by the row (2.0 ms for 32 rows of 32,000,
+# 0.5 ms for one; PERF.md, PR 35) and a bin holds 1.3-1.6 prompts on average
+_FIRST_ROWS = 8
+
+
+def nns_sample_first_rows(logits, rows, temp, topk, topp, keys, fill):
+    """``nns_sample_first`` for ``rows`` of a packed bucket's logits in one
+    launch: the request of ``rows[r]`` gets the token ``nns_sample_first``
+    would give it (the same ``fold_in(key, fill)``). A tuple of device
+    scalars, so that each pending insert holds its own and no eager slice
+    is launched for it."""
+    toks = sample_tokens(
+        logits[rows], temp, topk, topp,
+        jax.vmap(jax.random.fold_in)(keys, fill),
+    )
+    return tuple(toks[r] for r in range(rows.shape[0]))
+
+
 def nns_load_prefix(stage, ks, vs):
     return (
         jax.lax.dynamic_update_slice(stage[0], ks, (0, 0, 0, 0, 0)),
@@ -931,11 +960,21 @@ def _prefill_programs(family, weights, windowed: bool):
     """The prompt programs, the family's own (the dense family's are
     dec.prefill / dec.verify_chunk, as ever): one bucket from position 0,
     one bucket at ``cpos`` against a staging cache with and without the
-    vocab head, and — on a windowed batcher, None elsewhere — the two exact
+    vocab head, — on a windowed batcher, None elsewhere — the two exact
     sliding-window ring chunks for prompts of ANY length in the fixed W
-    ring."""
+    ring, and — where the family has one, None elsewhere — the bucket that
+    holds several prompts (``family.prefill_packed``)."""
     def wjit(fn, **kw):
         return _weights_jit(fn, weights, **kw)
+
+    packed = None
+    if hasattr(family, "prefill_packed"):
+        packed = wjit(
+            lambda w, toks, positions, segment, last: family.prefill_packed(
+                w[0], toks, positions, segment, last
+            ),
+            name="nns_prefill_packed",
+        )
 
     prefill = wjit(
         lambda w, toks: family.prefill(w[0], toks), name="nns_prefill",
@@ -951,7 +990,7 @@ def _prefill_programs(family, weights, windowed: bool):
         donate_argnums=2, name="nns_prefill_chunk_nologits",
     )
     if not windowed:
-        return prefill, chunk, advance, None, None
+        return prefill, chunk, advance, None, None, packed
     ring_chunk = wjit(
         lambda w, toks, cpos, n, cache: dec.windowed_chunk(
             w[0], toks, cpos, n, cache, family.n_heads,
@@ -966,7 +1005,7 @@ def _prefill_programs(family, weights, windowed: bool):
         )[1],
         donate_argnums=3, name="nns_prefill_ring_nologits",
     )
-    return prefill, chunk, advance, ring_chunk, ring_advance
+    return prefill, chunk, advance, ring_chunk, ring_advance, packed
 
 
 # ---- the decode programs: one body, three builders, over a cache layout ----
@@ -1763,6 +1802,11 @@ class ContinuousBatcher:
             self._n_resumes = 0
             self._n_prefill_chunk_programs = 0
             self._n_prefill_pumps = 0
+            # prompt programs of any kind, the prompts they completed, and
+            # the programs among them that held a bin of prompts
+            self._n_prefill_programs = 0
+            self._n_prefill_prompts = 0
+            self._n_prefill_packed_programs = 0
             # bucket programs a pump may spend on the prefill queue:
             # 0 = as many as jobs are queued at its start, N = at most N
             self._prefill_chunks = max(0, int(prefill_chunks))
@@ -1837,8 +1881,8 @@ class ContinuousBatcher:
             weights = jax.device_put(weights, NamedSharding(mesh, P()))
 
         (self._prefill, self._prefill_chunk, self._advance_chunk,
-         self._wchunk, self._wadvance) = _prefill_programs(
-            family, weights, windowed
+         self._wchunk, self._wadvance, self._prefill_packed) = (
+            _prefill_programs(family, weights, windowed)
         )
         # chunked prefill (prompts longer than the bucket) stages into a
         # cache padded to a bucket multiple — plus one spare bucket so
@@ -1846,6 +1890,7 @@ class ContinuousBatcher:
         # path) still fit their full-width writes
         self._stage_len = (-(-max_len // prompt_len) + 1) * prompt_len
         self._sample1 = jax.jit(nns_sample_first)
+        self._sample_rows = jax.jit(nns_sample_first_rows)
         self._insert = jax.jit(insert_slot, donate_argnums=0)
         self._admit = _make_admit(max_len, self._vec_sh)
         self._load_prefix = jax.jit(nns_load_prefix, donate_argnums=0)
@@ -2459,11 +2504,21 @@ class ContinuousBatcher:
         is decoding (no active slot, no activation pending), an idle
         decode plane keeps advancing until a job activates or the queue
         drains, whatever the budget.
+
+        A pump that finds bucket-sized fresh prompts queued of which two
+        or more fit one bucket, under a family that has a packed prompt
+        program, lets them share programs (``_plan_packed``,
+        ``_advance_prefill_packed``); every other pump runs the loop below.
         Caller holds _step_lock; _lock is taken only for bookkeeping."""
         queued = len(self._prefill_q)
         with _trace.span("nns.pump.prefill", prefill_q=queued) as sp:
             budget = self._prefill_chunks or max(1, queued)
-            buckets = activated = 0
+            if queued >= 2 and self._prefill_packed is not None:
+                plan = self._plan_packed(budget)
+                if plan is not None and self._advance_prefill_packed(
+                        plan, budget, sp):
+                    return
+            buckets = prompts = activated = 0
             tail = None  # what the last programs launched here produce
             while True:
                 with self._lock:
@@ -2484,6 +2539,7 @@ class ContinuousBatcher:
                     tail = job.stage
                     budget -= 1
                     buckets += 1
+                    prompts += job.done_staging()
                 if job.done_staging():
                     if not self._prefill_finalize(job):
                         break  # blocks not affordable yet (watermark)
@@ -2494,9 +2550,230 @@ class ContinuousBatcher:
                         # the landed arena and the sampled first token:
                         # the last readers of that bucket's outputs
                         tail = (self._cache, self._pending[-1].first_tok)
-            if buckets:
-                self._n_prefill_pumps += 1
-            sp.set(buckets=buckets, activated=activated)
+            self._note_prefill(sp, buckets, prompts, activated)
+
+    def _note_prefill(self, sp, programs: int, prompts: int, activated: int,
+                      packed: int = 0) -> None:
+        """What one ``nns.pump.prefill`` span did, on the span and in
+        ``stats()``: prompt programs launched (``packed`` of them held a bin
+        of prompts), prompts whose prefill they completed, jobs activated."""
+        if programs:
+            self._n_prefill_pumps += 1
+        self._n_prefill_programs += programs
+        self._n_prefill_prompts += prompts
+        self._n_prefill_packed_programs += packed
+        sp.set(buckets=programs, programs=programs, prompts=prompts,
+               activated=activated)
+
+    def _packable_locked(self, job) -> bool:
+        """``_prefill_chunk_one``'s condition for the single fast-path
+        program, asked before the job starts staging: a fresh prompt no
+        longer than the bucket that no registered prefix matches
+        (``match`` takes no reference; a job that does match adopts in
+        ``_prefill_chunk_one``, as ever)."""
+        if (job.stage is not None or job.known_first is not None
+                or not 0 < job.fill <= self.prompt_len):
+            return False
+        return bool(
+            job.no_rematch
+            or self._pool.match(job.tokens[:-1]).n_tokens == 0
+        )
+
+    def _plan_packed(self, budget: int):
+        """What a pump that packs will run, in queue order: the bins of
+        the packable jobs (first fit over the bins open so far, at most
+        ``budget`` of them; a prompt of t tokens takes ceil(t / block_size)
+        blocks' worth of rows, so every stage block belongs to one prompt)
+        and, each in its queue place, the jobs that are not packable. A bin
+        that ends with one prompt is that job again: a lone prompt takes the
+        bucket program it always took. None where no bin holds two: the
+        caller then does what it always did. A job no bin has room for
+        stays queued."""
+        P, bs = self.prompt_len, self.block_size
+        with self._lock:
+            jobs = [(j, self._packable_locked(j)) for j in self._prefill_q]
+        plan: List[Any] = []
+        bins: List[_PrefillBin] = []
+        for job, packable in jobs:
+            if not packable:
+                plan.append(job)
+                continue
+            rows = -(-job.fill // bs) * bs
+            into = next((b for b in bins if b.rows + rows <= P), None)
+            if into is None:
+                if len(bins) >= budget:
+                    continue
+                into = _PrefillBin()
+                bins.append(into)
+                plan.append(into)
+            into.jobs.append(job)
+            into.rows += rows
+        plan = [
+            item.jobs[0]
+            if isinstance(item, _PrefillBin) and len(item.jobs) == 1
+            else item for item in plan
+        ]
+        if not any(isinstance(item, _PrefillBin) for item in plan):
+            return None
+        return plan
+
+    def _advance_prefill_packed(self, plan, budget: int, sp) -> bool:
+        """A pump's prefill where prompts share programs: ``plan``'s items
+        in order, a bin one program of the budget, a job that is not
+        packed (longer than the bucket, a prefix hit, a resume, staged
+        already, alone in its bin) through ``_prefill_chunk_one`` and
+        ``_prefill_finalize``
+        as in ``_advance_prefill``'s loop — but one the pool cannot afford
+        holds nobody else up here. The same wait between programs: one
+        program's outputs on the device at a time. Activations are queued
+        in the order the jobs stood in the queue. False where nothing could
+        be launched or activated (the pool affords none of them): nothing
+        has changed then, and the caller's loop decides, as ever, whether
+        the queue's head waits or can never be admitted."""
+        programs = packed = prompts = activated = 0
+        tail = None
+        with self._lock:
+            place = {id(j.req): i for i, j in enumerate(self._prefill_q)}
+            n_pending = len(self._pending)
+
+        def spend() -> bool:
+            # the budget bounds decode stalls only: with nothing decoding
+            # and nothing about to, go on until something activates
+            if budget - programs > 0:
+                return True
+            with self._lock:
+                return not self._active.any() and not self._pending
+
+        for item in plan:
+            if isinstance(item, _PrefillBin):
+                if not spend():
+                    continue
+                jax.block_until_ready(tail)
+                landed = self._prefill_bin(item)
+                if landed:
+                    programs += 1
+                    packed += 1
+                    prompts += landed
+                    activated += landed
+                    tail = (self._cache, self._pending[-1].first_tok)
+                continue
+            job = item
+            while not job.done_staging() and spend():
+                self._slo.prefilling(job.req.rid)
+                jax.block_until_ready(tail)
+                self._prefill_chunk_one(job)
+                tail = job.stage
+                programs += 1
+                prompts += job.done_staging()
+            if job.done_staging() and self._prefill_finalize(job):
+                activated += 1
+                with self._lock:
+                    self._unqueue_locked([job])
+                    tail = (self._cache, self._pending[-1].first_tok)
+        with self._lock:
+            self._pending[n_pending:] = sorted(
+                self._pending[n_pending:],
+                key=lambda p: place.get(id(p.req), len(place)),
+            )
+        if not programs and not activated:
+            return False
+        self._note_prefill(sp, programs, prompts, activated, packed)
+        return True
+
+    def _unqueue_locked(self, jobs) -> None:
+        """Take ``jobs`` out of the prefill queue, wherever they stand."""
+        gone = {id(j) for j in jobs}
+        keep = [j for j in self._prefill_q if id(j) not in gone]
+        self._prefill_q.clear()
+        self._prefill_q.extend(keep)
+
+    def _prefill_bin(self, b: _PrefillBin) -> int:
+        """One packed program for the bin's prompts, ONE landing of every
+        prompt's rows into its own blocks (``land_stage`` at the bucket's
+        shape: stage block i to arena block ``ids[i]``), one launch of the
+        step's sampler for the first tokens of every ``_FIRST_ROWS`` of
+        them; then each job's activation is queued, in bin order. Returns
+        the number of prompts landed.
+
+        Blocks are allocated before anything is launched, under the
+        watermark ``_prefill_finalize`` keeps (one decode-growth block of
+        headroom for every live request, the bin's own included), so what
+        is launched lands whole; a job the pool cannot afford now is left
+        out and stays queued."""
+        from nnstreamer_tpu.kv.blocks import NoBlocksError
+
+        P, bs = self.prompt_len, self.block_size
+        K = P // bs
+        got: List[Tuple[Any, List[int]]] = []
+        with self._lock:
+            n_live = int(self._active.sum()) + len(self._pending)
+            for job in b.jobs:
+                need = -(-job.fill // bs)
+                if self._pool.available() < need + n_live + len(got):
+                    continue
+                try:
+                    got.append((job, self._pool.alloc(need)))
+                except NoBlocksError:
+                    continue
+        if not got:
+            return 0
+        tokens = np.full((1, P), self._family.pad_id, np.int32)
+        positions = np.zeros((P,), np.int32)
+        segment = np.full((P,), -1, np.int32)
+        last = np.full((K,), -1, np.int32)
+        ids = np.zeros((K,), np.int32)
+        valid = np.zeros((K,), bool)
+        row = 0
+        for r, (job, blocks) in enumerate(got):
+            self._slo.prefilling(job.req.rid)
+            t = job.fill
+            tokens[0, row: row + t] = job.tokens
+            positions[row: row + t] = np.arange(t)
+            segment[row: row + t] = r
+            last[r] = row + t - 1
+            i = row // bs
+            ids[i: i + len(blocks)] = blocks
+            valid[i: i + len(blocks)] = True
+            row += len(blocks) * bs
+        self._n_prefill_chunk_programs += 1
+        logits, stage = self._prefill_packed(tokens, positions, segment, last)
+        # the dense family has no slot leaves: the lane argument is unused
+        self._cache = self._land_stage(
+            self._cache, stage, ids, valid, np.int32(0)
+        )
+        firsts: List[Any] = []
+        for r0 in range(0, len(got), _FIRST_ROWS):
+            reqs = [job.req for job, _ in got[r0: r0 + _FIRST_ROWS]]
+            n = len(reqs)
+            rows = np.zeros((_FIRST_ROWS,), np.int32)
+            temp = np.zeros((_FIRST_ROWS,), np.float32)
+            topk = np.zeros((_FIRST_ROWS,), np.int32)
+            topp = np.ones((_FIRST_ROWS,), np.float32)
+            keys = np.zeros((_FIRST_ROWS, 2), np.uint32)
+            fill = np.zeros((_FIRST_ROWS,), np.int32)
+            rows[:n] = np.arange(r0, r0 + n)
+            temp[:n] = [q.temperature for q in reqs]
+            topk[:n] = [q.top_k for q in reqs]
+            topp[:n] = [q.top_p for q in reqs]
+            keys[:n] = [q.key for q in reqs]
+            fill[:n] = [job.fill for job, _ in got[r0: r0 + n]]
+            firsts += self._sample_rows(
+                logits, rows, temp, topk, topp, keys, fill
+            )[:n]
+        with self._lock:
+            for (job, blocks), first in zip(got, firsts):
+                self._pool.register(job.tokens, blocks)
+                hist_row = np.full((self.max_len,), -1, np.int32)
+                hist_row[: job.fill] = job.tokens
+                job.req.fill0 = job.fill
+                self._pending.append(
+                    _PendingInsert(
+                        job.slot, None, None, first, job.fill, job.req,
+                        hist_row=hist_row, blocks=blocks,
+                    )
+                )
+            self._unqueue_locked([job for job, _ in got])
+        return len(got)
 
     def _prefill_chunk_one(self, job) -> None:
         """One ``prompt_len`` bucket of chunked prefill for ``job``
@@ -3694,6 +3971,15 @@ class ContinuousBatcher:
                 # at least one: their quotient is buckets a prefilling pump
                 st["kv_prefill_chunks"] = self._n_prefill_chunk_programs
                 st["prefill_pumps"] = self._n_prefill_pumps
+                # prompt programs of any kind and the prompts whose prefill
+                # they completed (their quotient is prompts a program: 1
+                # where nothing is packed, under 1 where prompts are
+                # chunked), and the programs that held a bin
+                st["prefill_programs"] = self._n_prefill_programs
+                st["prefill_prompts"] = self._n_prefill_prompts
+                st["prefill_packed_programs"] = (
+                    self._n_prefill_packed_programs
+                )
                 st["request_resumes"] = self._n_resumes
             st["family"] = self._family.name
             for k, v in self._aux_totals.items():

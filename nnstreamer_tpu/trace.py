@@ -375,9 +375,11 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
         "programs run asynchronously; before each further bucket of one "
         "span the host waits for the last one's outputs, so one bucket's "
         "logits and stage are on the device at a time)",
-        "prefill_q (jobs waiting at entry), buckets (chunk programs this "
-        "span launched), activated (jobs it finalized); the last two set as "
-        "it closes",
+        "prefill_q (jobs waiting at entry), buckets = programs (prompt "
+        "programs of any kind this span launched: bucket, chunk, packed), "
+        "prompts (jobs whose prefill those programs completed: above "
+        "programs where queued prompts shared a packed bucket), activated "
+        "(jobs it finalized); all but the first set as it closes",
     ),
     "nns.pump.admit": (
         "batcher",

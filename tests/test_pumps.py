@@ -675,7 +675,8 @@ def test_admission_compiles_once(params, layout):
         assert _apply(b) == (1, n_rows)
         return rids
 
-    _drain_steps(b, admit(1, 300))  # warm: every program of the path
+    _drain_steps(b, admit(1, 300))  # warm: every program of the path,
+    _drain_steps(b, admit(2, 305))  # and the one that queued prompts share
     compiled = []
 
     def on_duration(event, secs, **_):

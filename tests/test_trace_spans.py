@@ -229,8 +229,13 @@ def test_prefill_spans_count_their_buckets_and_activations(traced):
     ``prefill_q``) unless nothing was decoding."""
     events, _, _ = traced
     spans = [e["stats"] for e in events if e["name"] == "nns.pump.prefill"]
-    assert all({"prefill_q", "buckets", "activated"} <= set(s) for s in spans)
+    assert all({"prefill_q", "buckets", "programs", "prompts", "activated"}
+               <= set(s) for s in spans)
     assert sum(s["activated"] for s in spans) == len(PROMPT_LENS)
+    # a bucket of one block holds one prompt: every prompt's last program
+    # completes it and no program completes two
+    assert sum(s["prompts"] for s in spans) == len(PROMPT_LENS)
+    assert all(s["programs"] == s["buckets"] >= s["prompts"] for s in spans)
     assert sum(s["buckets"] for s in spans) == sum(
         -(-n // 16) for n in PROMPT_LENS)
     assert all(s["activated"] <= max(1, s["buckets"]) for s in spans)
