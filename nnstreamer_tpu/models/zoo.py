@@ -558,6 +558,19 @@ def _kimi_linear_lm(**options) -> ZooModel:
                       options)
 
 
+@model_factory("granite_hybrid_lm")
+def _granite_hybrid_lm(**options) -> ZooModel:
+    """Granite-4.0-H (models/granite_hybrid.py): Mamba-2 state-space layers
+    with a per-slot state beside grouped-query attention layers without
+    positions (the dense family's K/V blocks), every layer followed by
+    softmax-routed experts with a shared MLP, one chip's share of the routed
+    experts, a tied head (``_family_lm`` has the options)."""
+    from nnstreamer_tpu.models import granite_hybrid
+
+    return _family_lm("granite_hybrid_lm", granite_hybrid,
+                      granite_hybrid.GraniteHybridFamily, options)
+
+
 @model_factory("vit")
 def _vit(**options) -> ZooModel:
     """Vision Transformer classifier (models/vit.py): patch-embed +

@@ -168,7 +168,8 @@ METRIC_CATALOG: Dict[str, str] = {
     "nns_slot_state_bytes": (
         "resident bytes of the per-slot state a block family keeps beside "
         "its block arena (recurrent state and convolution tails of every "
-        "slot; models/kimi_linear.py) (gauge; docs/llm-serving.md)"
+        "slot; models/kimi_linear.py, models/granite_hybrid.py) (gauge; "
+        "docs/llm-serving.md)"
     ),
     "nns_slot_state_updates_total": (
         "(live lane, state layer) updates of per-slot recurrent state in "

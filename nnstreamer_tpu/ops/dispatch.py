@@ -63,6 +63,7 @@ KNOWN_OPS = (
     "nms",
     "resize_bilinear",
     "serving_attention",
+    "ssm_recurrence",
 )
 
 

@@ -3,7 +3,8 @@
 XLA fuses most of the pipeline (SURVEY.md §7 design mapping); these kernels
 cover the cases where explicit VMEM blocking beats the fusion XLA picks —
 flash attention, the serving decode kernels (contiguous and paged cache
-layouts, the latent cache, Kimi Delta Attention's recurrent state), and the
+layouts, the latent cache, Kimi Delta Attention's and Mamba-2's recurrent
+state), and the
 pre/post-processing set (docs/on-device-ops.md):
 MXU bilinear crop/resize with a fused normalize epilogue, and the greedy
 NMS suppression recurrence. Every kernel has an ``interpret=True`` path so
@@ -30,3 +31,4 @@ from nnstreamer_tpu.ops.pallas.nms import nms  # noqa: F401
 from nnstreamer_tpu.ops.pallas.paged_attention import (  # noqa: F401
     paged_decode_attention,
 )
+from nnstreamer_tpu.ops.pallas.ssm import ssm_decode_step  # noqa: F401

@@ -417,7 +417,8 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     "nns.moe.routing": (
         "expert layer",
         "instant per harvested decode pump of a routed-expert family "
-        "(models/longcat.py, models/kimi_linear.py): the router's counters, "
+        "(models/longcat.py, models/kimi_linear.py, "
+        "models/granite_hybrid.py): the router's counters, "
         "summed on the device over the pump's steps and expert layers and "
         "carried home by the pump's one readback",
         "tokens (live token x layer evaluations), local_pairs (pairs on the "
@@ -426,13 +427,15 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
         "only where the family has them), picks (tokens x top-k)",
     ),
     "nns.state.update": (
-        "KDA layers",
+        "KDA layers, SSM layers",
         "instant per harvested decode pump of a family with per-slot "
-        "recurrent state (models/kimi_linear.py), beside nns.moe.routing "
-        "and from the same readback",
+        "recurrent state (models/kimi_linear.py, models/granite_hybrid.py), "
+        "beside nns.moe.routing and from the same readback",
         "slot_layers ((live lane, state layer) updates, summed over the "
         "pump's steps), bytes (state those updates read and wrote: "
-        "slot_layers x 2 x heads x d_k x d_v x 4)",
+        "slot_layers x 2 x one layer's float32 state of one slot, from the "
+        "family's own config: heads x d_k x d_v x 4, or heads x d_head x "
+        "d_state x 4)",
     ),
     "nns.req.submit": (
         "batcher",

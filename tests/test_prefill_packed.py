@@ -347,8 +347,23 @@ def _kimi_linear(**kw):
     )
 
 
-@pytest.mark.parametrize("make", [_longcat, _kimi_linear],
-                         ids=["longcat", "kimi-linear"])
+def _granite_hybrid(**kw):
+    from nnstreamer_tpu.models import granite_hybrid as gh
+
+    cfg = gh.GraniteHybridConfig(
+        d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, ssm_heads=8,
+        ssm_head_dim=8, ssm_state=16, ssm_chunk=8, attn_layers=(2,), d_expert=32,
+        d_shared=48, n_routed=8, topk=3, n_layers=4, vocab=97, n_held=4,
+        expert_offset=4,
+    )
+    return ContinuousBatcher(
+        gh.init_params(cfg, 11, jnp.float32), cfg.n_heads,
+        family=gh.GraniteHybridFamily(cfg, jnp.float32), **{**SMALL, **kw},
+    )
+
+
+@pytest.mark.parametrize("make", [_longcat, _kimi_linear, _granite_hybrid],
+                         ids=["longcat", "kimi-linear", "granite-hybrid"])
 def test_the_latent_families_never_pack(make):
     """No packed program to ask for: k queued prompts are k buckets."""
     b = make()

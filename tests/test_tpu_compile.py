@@ -260,6 +260,78 @@ def test_kda_decode_step_cell_shape(one_chip, heads):
     assert not _big_moves(text, b * h * d * d, f",{h},{d},{d}")
 
 
+@pytest.mark.parametrize("heads", [8, 32])
+def test_ssm_decode_step_cell_shape(one_chip, heads):
+    """Mamba-2's decode scan as granite-4.0-h-small's cell launches it: 64
+    lanes, 128 heads of 64 x 128 float32 state, 9 SSM layers in one leaf, the
+    leaf donated and updated in place."""
+    from nnstreamer_tpu.ops.pallas.ssm import ssm_decode_step
+
+    b, h, p, n, layers = 64, 128, 64, 128, 9
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
+        ((layers, b + 1, h, p, n), f32), ((b, h, p), f32), ((b, n), f32),
+        ((b, n), f32), ((b, h), f32), ((b, h), f32), ((h,), f32),
+        ((b,), jnp.bool_))]
+    text = jax.jit(
+        lambda s, x, bm, cm, dt, a, d, act: ssm_decode_step(
+            s, x, bm, cm, dt, a, d, act, layer=4, heads=heads, interpret=False),
+        donate_argnums=0,
+    ).lower(*args).compile().as_text()
+    _assert_kernel(text)
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert "alias" in aliased   # the state comes back in its own buffer
+    assert not _big_moves(text, b * h * p * n, f",{h},{p},{n}")
+
+
+def test_granite_hybrid_pump_cell_shapes(one_chip, monkeypatch):
+    """The decode program granite-4.0-h-small's cell serves: the batcher's pump
+    over the paged layout at 64 slots x 2048, every published width, 10 layers
+    of which one is attention, 36 held experts, bfloat16 weights and K/V, the
+    whole arena (K/V blocks, state, tails) donated. Both kernels are in it and
+    nothing the size of the state leaf is copied."""
+    from nnstreamer_tpu.models import granite_hybrid as gh
+    from nnstreamer_tpu.models.serving import _PagedLayout, make_pump
+    from nnstreamer_tpu.ops.pallas import ssm
+    from nnstreamer_tpu.ops.pallas.paged_attention import make_paged_attention
+
+    # the program asks jax's backend, which is the CPU here: compile the kernels
+    monkeypatch.setattr(ssm, "interpret_default", lambda: False)
+    b, nb = 64, 128
+    cfg = gh.config_from_options(
+        {"n_layers": "10", "experts_held": "36", "vocab": "50176"})
+    bf16 = jnp.bfloat16
+    family = gh.GraniteHybridFamily(cfg, bf16)
+    attn = make_paged_attention(interpret=False, scale=cfg.attn_scale)
+    pump = make_pump(_PagedLayout(family, attn), False)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    described = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: sds(x.shape, x.dtype), tree)
+    params = described(jax.eval_shape(lambda: gh.init_params(cfg, 0, bf16)))
+    arena = described(jax.eval_shape(lambda: family.arena(b * nb, BS, False, b)))
+    vec, fvec = sds((b,), i32), sds((b,), f32)
+    compiled = pump.lower(
+        (params, None), vec, vec, sds((b,), jnp.bool_), arena,   # tok pos active
+        sds((b, nb * BS), i32), vec, vec,                        # hist budget stop
+        fvec, vec, fvec, sds((b, 2), jnp.uint32),                # the sampler's
+        sds((b, nb), i32),                                       # tables
+        n_steps=2,
+    ).compile()
+    text = compiled.as_text()
+    assert "jit_impl" in text  # the name benchmark/configs select it by
+    assert "ssm_decode_step" in text
+    assert text.count("tpu_custom_call") >= 10   # 9 scans and the attention
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    assert not _big_moves(text, b * h * p * n, f",{h},{p},{n}")
+    # weights 9.5 GB + arena 3.0 GB + what a step holds besides: under the chip
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    assert total < 14.5e9, total
+
+
 # (n-slots, max-len) of the per-slot state the admit program rewrites
 ADMIT_CELLS = {"olmo-1b": (16, 1024), "longcat-flash-chat": (64, 2048)}
 

@@ -123,14 +123,23 @@ def ops(trace_file):
 
 
 # ``nns.moe.routing`` is written by the routed-expert families only and
-# ``nns.state.update`` by the one with per-slot state; this run serves the
-# dense block (tests/test_longcat.py and tests/test_kimi_linear.py trace them)
+# ``nns.state.update`` by the two with per-slot state; this run serves the
+# dense block (tests/test_longcat.py, tests/test_kimi_linear.py and
+# tests/test_granite_hybrid.py trace them)
 @pytest.mark.parametrize(
     "name", sorted(set(trace.SPAN_CATALOG) - {"nns.moe.routing", "nns.state.update"}))
 def test_every_cataloged_span_is_on_the_profilers_timeline(traced, name):
     events, _, _ = traced
     assert any(e["name"] == name for e in events), (
         f"{name} is cataloged but the traced serving run wrote no such event")
+
+
+@pytest.mark.parametrize("family", ["kimi_linear", "granite_hybrid"])
+def test_state_update_is_cataloged_for_both_families_with_per_slot_state(family):
+    layer, emitter, attrs = trace.SPAN_CATALOG["nns.state.update"]
+    assert f"models/{family}.py" in emitter and "slot_layers" in attrs
+    assert layer == "KDA layers, SSM layers"
+    assert f"models/{family}.py" in trace.SPAN_CATALOG["nns.moe.routing"][1]
 
 
 def _inside(inner, outer):
