@@ -21,16 +21,25 @@ The model keeps TWO kinds of cache. An attention layer leaves, per token, its
 keys and values: the dense family's block leaves ``[attention layers, N, bs,
 KV, Dh]`` (kv/gather.init_arena), read by the same block-table kernel with the
 published ``attention_multiplier`` as its scale. A Mamba-2 layer leaves
-nothing per token: per SLOT it keeps the float32 state ``S`` [H, P, N] and the
-last ``conv - 1`` inputs of its convolution: the slot leaves ``[SSM layers,
-n_slots + 1, ...]`` (lane b is row b, the last row is scratch), carried and
-donated with the block leaves. Prefill and ``chunk`` compute the scan chunk by
-chunk (``ssm_chunked``: inside a chunk as ``(C B^T * L) x~`` with ``L_ij =
+nothing per token: per SLOT it keeps the float32 state ``S`` (H heads of P x
+N) and the last ``conv - 1`` inputs of its convolution: the slot leaves ``[SSM
+layers, n_slots + 1, ...]`` (lane b is row b, the last row is scratch), carried
+and donated with the block leaves. The state's leaf is laid for the decode
+kernel, ``[..., H / k, N, k P]``: ``N`` on the sublanes and ``k`` heads side by
+side on the lanes (``ssm.heads_per_row``: 2 at the published P = 64, a 128 x
+128 tile), so that a step's per-head work is row broadcasts, whole-vreg
+arithmetic and a sum over sublanes; over ``[H, P, N]`` the same kernel is
+bound by column broadcasts and 128-lane reductions, not by the memory
+(PERF.md section 6, PR 38). The same bytes; ``k`` is read off the shapes.
+Prefill and ``chunk`` compute the scan chunk by chunk on ``[B, H, P,
+N]`` (``ssm_chunked``: inside a chunk as ``(C B^T * L) x~`` with ``L_ij =
 exp(l_i - l_j)`` from cumulative sums of ``log a``, between chunks through
-``S``; float32, matmuls at highest precision); decode is one kernel that
-reads each head's state once and writes it in place (ops/pallas/ssm.py), or
-the plain recurrence off a TPU. Padding (ids < 0) leaves the state as it was:
-``D = 0`` there, and the convolution tail is the last three REAL inputs.
+``S``; float32, matmuls at highest precision) and convert where the stage is
+read and written (``ssm.leaf_to_state`` / ``state_to_leaf``); decode is one
+kernel that reads each head's state once and writes it in place
+(ops/pallas/ssm.py), or the plain recurrence over the same leaf off a TPU.
+Padding (ids < 0) leaves the state as it was: ``D = 0`` there, and the
+convolution tail is the last three REAL inputs.
 
 The expert layer routes over every router output (float32 at highest
 precision, the ``topk`` largest logits, weights a softmax over those), runs
@@ -442,15 +451,20 @@ def _layers(params, x, c: GraniteHybridConfig, live, ssm_mix, attn_mix):
 
 def _run_bucket(params, tokens, c: GraniteHybridConfig, states, tails, attn_mix):
     """What prefill and chunk share: a bucket's layers with the scan in the
-    chunked form from ``states`` [Ls, B, H, P, N] and ``tails`` [Ls, B,
+    chunked form from ``states`` [Ls, B, H / k, N, k P] (the slot leaf's
+    layout; the scan itself works on [B, H, P, N]) and ``tails`` [Ls, B,
     conv - 1, W] -> (x, states, tails after the bucket's real tokens)."""
+    from nnstreamer_tpu.ops.pallas.ssm import leaf_to_state, state_to_leaf
+
     live = tokens >= 0
+    k = c.ssm_heads // states.shape[2]
     new_states, new_tails = [], []
 
     def ssm_mix(j, a, sp):
         z, x, bm, cm, dt, la, window = ssm_project(a, live, tails[j], sp, c)
-        y, s = ssm_chunked(x, bm, cm, dt, la, states[j], c.ssm_chunk)
-        new_states.append(s)
+        y, s = ssm_chunked(x, bm, cm, dt, la, leaf_to_state(states[j], k),
+                           c.ssm_chunk)
+        new_states.append(state_to_leaf(s, k))
         new_tails.append(_real_tail(window, live, c.conv))
         return ssm_output(y + sp["d_skip"][:, None] * x, z, sp, c)
 
@@ -460,17 +474,22 @@ def _run_bucket(params, tokens, c: GraniteHybridConfig, states, tails, attn_mix)
 
 def empty_slot_stage(c: GraniteHybridConfig, batch: int, dtype):
     """Zero state and convolution tails of ``batch`` sequences: a prompt's
-    start."""
-    return (jnp.zeros((c.n_ssm, batch, c.ssm_heads, c.ssm_head_dim, c.ssm_state),
-                      jnp.float32),
+    start. The state in the slot leaf's layout (ops/pallas/ssm.py): ``N`` on
+    the sublanes, ``k`` heads side by side on the lanes."""
+    from nnstreamer_tpu.ops.pallas.ssm import heads_per_row
+
+    k = heads_per_row(c.ssm_heads, c.ssm_head_dim)
+    return (jnp.zeros((c.n_ssm, batch, c.ssm_heads // k, c.ssm_state,
+                       k * c.ssm_head_dim), jnp.float32),
             jnp.zeros((c.n_ssm, batch, c.conv - 1, c.conv_width), dtype))
 
 
 def prefill(params, tokens, c: GraniteHybridConfig, cache_dtype):
     """tokens [B, T] (ids < 0 are padding, at the end) -> (logits [B, T, V]
     float32, stage: the keys and values (k, v [La, B, T, KV, Dh]) in the
-    cache's dtype, then the state [Ls, B, H, P, N] float32 and the
-    convolution tails [Ls, B, conv - 1, W] after the real tokens)."""
+    cache's dtype, then the state [Ls, B, H / k, N, k P] float32 (the slot
+    leaf's layout) and the convolution tails [Ls, B, conv - 1, W] after the
+    real tokens)."""
     b, t = tokens.shape
     mask = jnp.broadcast_to(
         (jnp.arange(t)[:, None] >= jnp.arange(t)[None, :])[None], (b, t, t))
@@ -501,8 +520,8 @@ def apply(params, tokens, c: GraniteHybridConfig, cache_dtype=None):
 def chunk(params, tokens, cpos, stage, c: GraniteHybridConfig,
           return_logits: bool = True):
     """One bucket of chunked prefill at absolute position ``cpos`` against a
-    stage (k, v [La, 1, S, KV, Dh], state [Ls, 1, H, P, N], tails [Ls, 1,
-    conv - 1, W]): the bucket's keys and values are written at ``cpos`` and
+    stage (k, v [La, 1, S, KV, Dh], state [Ls, 1, H / k, N, k P], tails [Ls,
+    1, conv - 1, W]): the bucket's keys and values are written at ``cpos`` and
     its queries attend the stage up to their own positions; the scan goes on
     from the stage's state and tails. -> (logits or None, stage)."""
     b, t = tokens.shape
@@ -527,7 +546,7 @@ def decode_step(params, tok, pos, active, arena, tables, c: GraniteHybridConfig,
                 attn_fn: Optional[Callable] = None):
     """One decode step of a slot batch off the arena (block leaves k, v ``[La,
     N, bs, KV, Dh]`` through the tables [B, nb]; slot leaves state ``[Ls,
-    B + 1, H, P, N]`` and tails ``[Ls, B + 1, conv - 1, W]``, lane b row b).
+    B + 1, H / k, N, k P]`` and tails ``[Ls, B + 1, conv - 1, W]``, lane b row b).
     With ``attn_fn`` (the block-table kernel) the scan runs ``ssm_decode_step``
     too; without it both take their XLA formulation. A dead lane's state and
     tails stay as they were. -> (logits [B, V], arena, pos', aux [5] int32:

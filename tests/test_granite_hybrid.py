@@ -13,6 +13,7 @@ left as the parent lowered them."""
 import functools
 import hashlib
 import importlib.util
+import math
 import os
 
 import jax
@@ -157,7 +158,8 @@ def test_padding_does_not_move_the_state_and_the_tail_is_the_last_real_inputs():
     assert float(jnp.max(jnp.abs(s_pad - s_real))) < 1e-6
 
 
-@pytest.mark.parametrize("case", ["dead-lanes-two-groups", "one-group"])
+@pytest.mark.parametrize("case", ["dead-lanes-two-groups", "one-group",
+                                  "one-head-a-row"])
 def test_decode_kernel_equals_its_oracle(case):
     from nnstreamer_tpu.ops.pallas import registry
 
@@ -167,12 +169,66 @@ def test_decode_kernel_equals_its_oracle(case):
     assert float(jnp.max(jnp.abs(got - want))) < atol
 
 
+# (H, P) -> heads side by side on a row of the leaf: the published widths,
+# a head as wide as a vreg and wider, the tiny test widths, a head count that
+# the vreg's width over P does not divide
+PACKING = [(128, 64, 2), (32, 128, 1), (4, 256, 1), (8, 8, 8), (64, 8, 16),
+           (6, 32, 3), (7, 16, 7), (5, 64, 1)]
+
+
+@pytest.mark.parametrize("h,p,k", PACKING)
+def test_heads_on_a_row_come_from_the_shapes(h, p, k):
+    from nnstreamer_tpu.ops.pallas.ssm import heads_per_row
+
+    assert heads_per_row(h, p) == k
+    cfg = _cfg(ssm_heads=h, ssm_head_dim=p)
+    state, tails = gh.empty_slot_stage(cfg, 3, jnp.float32)
+    assert state.shape == (3, 3, h // k, 16, k * p) and state.dtype == jnp.float32
+    assert state.size == 3 * 3 * h * p * 16               # the same bytes
+    assert tails.shape == (3, 3, 3, h * p + 32)
+
+
+@pytest.mark.parametrize("h,p,k", PACKING)
+def test_leaf_layout_round_trip_is_exact(h, p, k):
+    """[H, P, N] -> the leaf -> [H, P, N] moves values and changes none; in
+    the leaf, head ``g k + j``'s channel ``c`` at state ``n`` is row ``g``,
+    sublane ``n``, lane ``j P + c``."""
+    from nnstreamer_tpu.ops.pallas.ssm import leaf_to_state, state_to_leaf
+
+    s = np.random.default_rng(h * p).normal(size=(2, 3, h, p, 16)).astype(np.float32)
+    leaf = np.asarray(state_to_leaf(jnp.asarray(s), k))
+    assert leaf.shape == (2, 3, h // k, 16, k * p)
+    np.testing.assert_array_equal(np.asarray(leaf_to_state(jnp.asarray(leaf), k)), s)
+    head, c, n = h - 1, p // 2, 5
+    np.testing.assert_array_equal(leaf[1, 2, head // k, n, (head % k) * p + c],
+                                  s[1, 2, head, c, n])
+
+
+@pytest.mark.parametrize("h,p", [(8, 8), (4, 64), (2, 128)])
+def test_decode_oracle_over_the_leaf_is_the_token_recurrence(h, p):
+    """``ssm_decode_step_ref`` on the leaf against ``ssm_recurrent`` on [H, P,
+    N]: the layout moves values, the recurrence is the same."""
+    from nnstreamer_tpu.ops.pallas.ssm import (
+        heads_per_row, leaf_to_state, ssm_decode_step_ref, state_to_leaf)
+
+    x, bm, cm, dt, la, s0 = _scan_inputs(h + p, 3, 1, h, p, 16)
+    k = heads_per_row(h, p)
+    want_y, want_s = gh.ssm_recurrent(x, bm, cm, dt, la, s0)
+    leaf = state_to_leaf(jnp.stack([jnp.zeros_like(s0), s0]), k)
+    new, y = ssm_decode_step_ref(leaf, x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0],
+                                 jnp.exp(la[:, 0]), jnp.zeros((h,)),
+                                 jnp.ones((3,), bool), layer=1)
+    assert float(jnp.max(jnp.abs(y - want_y[:, 0]))) < 1e-5
+    assert float(jnp.max(jnp.abs(leaf_to_state(new[1], k) - want_s))) < 1e-6
+    assert float(jnp.max(jnp.abs(new[0]))) == 0.0
+
+
 def test_decode_kernel_leaves_dead_lanes_and_other_layers_untouched():
     from nnstreamer_tpu.ops.pallas.ssm import ssm_decode_step
 
     x, bm, cm, dt, la, _ = _scan_inputs(3, 4, 1, 8, 8, 16)
     rng = np.random.default_rng(1)
-    state = jnp.asarray(rng.normal(size=(3, 5, 8, 8, 16)), jnp.float32)
+    state = jnp.asarray(rng.normal(size=(3, 5, 1, 16, 64)), jnp.float32)
     active = jnp.asarray([True, False, True, False])
     new, y = ssm_decode_step(state, x[:, 0], bm[:, 0], cm[:, 0], dt[:, 0],
                              jnp.exp(la[:, 0]), jnp.ones((8,)), active, layer=1,
@@ -252,7 +308,7 @@ def test_prefill_then_decode_through_state_and_kv_blocks_matches_reference(
     want = np.asarray(ref.logits(_shape(cfg), seed, full, "float32"))
     arena = fam.arena(3 * nb, bs, False, 3)
     assert [a.shape for a in arena] == [
-        (1, 13, 16, 2, 16), (1, 13, 16, 2, 16), (3, 4, 8, 8, 16), (3, 4, 3, 96)]
+        (1, 13, 16, 2, 16), (1, 13, 16, 2, 16), (3, 4, 1, 16, 64), (3, 4, 3, 96)]
     arena = arena[:2] + (arena[2].at[:, 2].set(7.0), arena[3])
     tables = 1 + np.random.default_rng(seed).permutation(3 * nb).reshape(3, nb)
     _, land = kvg.make_staging_ops(False, jnp.float32)
@@ -421,6 +477,34 @@ def test_launch_span_and_gauge_carry_the_slot_state_bytes():
             "moe_state_updates"] > 0
     finally:
         obs_metrics.disable()
+
+
+def test_state_byte_counts_are_the_parents_at_the_published_widths(monkeypatch):
+    """The leaf's layout moves no byte count: what ``nns.state.update``'s
+    ``bytes``, ``nns.pump.launch``'s ``state_bytes`` and the gauge
+    ``nns_slot_state_bytes`` read at the cell's shapes (9 SSM layers, 64
+    slots, bfloat16 tails) are the numbers they read with the state stored
+    [H, P, N] (PR 36): the benchmark's rooflines rest on the counter."""
+    from nnstreamer_tpu import trace as nns_trace
+
+    cfg = gh.config_from_options({"n_layers": "10", "experts_held": "36",
+                                  "vocab": "50176"})
+    fam = gh.GraniteHybridFamily(cfg, jnp.bfloat16)
+    arena = jax.eval_shape(lambda: fam.arena(64, 16, False, 64))
+    state, tails = arena[-fam.slot_leaves:]
+    assert state.shape == (9, 65, 64, 128, 128) and state.dtype == jnp.float32
+    # the batcher's own arithmetic (serving.py: a slot's row over the slot leaves)
+    per_slot = sum(math.prod(leaf.shape) * leaf.dtype.itemsize // leaf.shape[1]
+                   for leaf in (state, tails))
+    assert per_slot == 9 * (128 * 64 * 128 * 4 + 3 * 8448 * 2) == 38_204_928
+    assert per_slot * 64 == 2_445_115_392               # the gauge at 64 slots
+    seen = []
+    monkeypatch.setattr(nns_trace, "instant",
+                        lambda name, **attrs: seen.append((name, attrs)))
+    counts = dict(zip(gh.AUX_NAMES, (640, 3200, 360, 6400, 576)))
+    fam.note_aux(counts, None)
+    update = dict(seen)["nns.state.update"]
+    assert update == {"slot_layers": 576, "bytes": 576 * 8_388_608}
 
 
 # -- served from a launch string ---------------------------------------------

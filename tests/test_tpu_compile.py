@@ -154,15 +154,16 @@ CELLS = {
 NB, BS, HD = 64, 16, 128
 
 
-def _big_moves(text, floor, tail):
+def _big_moves(text, floor, tail="", ops="copy|copy-start|dynamic-slice",
+               dtype=r"\w+"):
     """Result shapes of every ``copy`` / ``dynamic-slice`` (and their
-    async starts) in a compiled text that is arena-shaped (its dims end
-    with ``tail``: block size, KV heads, head dim) and holds at least
-    ``floor`` elements."""
+    async starts; or the ``ops`` given) in a compiled text, fused
+    computations' instructions included, whose dims end with ``tail``
+    (an arena's: block size, KV heads, head dim), whose element type is
+    ``dtype`` and which hold at least ``floor`` elements."""
     found = []
     for m in re.finditer(
-        r"= \(?\w+\[([\d,]+)\][^=\n]*? (copy|copy-start|dynamic-slice)\(",
-        text,
+        rf"= \(?{dtype}\[([\d,]+)\][^=\n]*? ({ops})\(", text,
     ):
         n = math.prod(int(x) for x in m.group(1).split(","))
         if n >= floor and m.group(1).endswith(tail):
@@ -260,35 +261,52 @@ def test_kda_decode_step_cell_shape(one_chip, heads):
     assert not _big_moves(text, b * h * d * d, f",{h},{d},{d}")
 
 
-@pytest.mark.parametrize("heads", [8, 32])
+def _step_sized_moves(text, floor):
+    """Every float32 ``copy`` / ``transpose`` of at least ``floor`` elements:
+    a step's vectors are float32; the weights a program also moves are not."""
+    return _big_moves(text, floor, ops="copy|copy-start|transpose", dtype="f32")
+
+
+@pytest.mark.parametrize("heads", [8, 32, 64])
 def test_ssm_decode_step_cell_shape(one_chip, heads):
     """Mamba-2's decode scan as granite-4.0-h-small's cell launches it: 64
-    lanes, 128 heads of 64 x 128 float32 state, 9 SSM layers in one leaf, the
-    leaf donated and updated in place."""
-    from nnstreamer_tpu.ops.pallas.ssm import ssm_decode_step
+    lanes, 128 heads of 64 x 128 float32 state two to a row of the leaf
+    ([9, 65, 64, 128, 128]), 9 SSM layers in one leaf, the leaf donated and
+    updated in place; the step's vectors ([B, H x P], as the projection leaves
+    them) reach the kernel's rows and its rows the output by reshapes: nothing
+    their size is transposed beside the kernel."""
+    from nnstreamer_tpu.ops.pallas.ssm import heads_per_row, ssm_decode_step
 
     b, h, p, n, layers = 64, 128, 64, 128, 9
+    k = heads_per_row(h, p)
+    assert k == 2
     args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in (
-        ((layers, b + 1, h, p, n), f32), ((b, h, p), f32), ((b, n), f32),
+        ((layers, b + 1, h // k, n, k * p), f32), ((b, h * p), f32), ((b, n), f32),
         ((b, n), f32), ((b, h), f32), ((b, h), f32), ((h,), f32),
         ((b,), jnp.bool_))]
-    text = jax.jit(
-        lambda s, x, bm, cm, dt, a, d, act: ssm_decode_step(
-            s, x, bm, cm, dt, a, d, act, layer=4, heads=heads, interpret=False),
-        donate_argnums=0,
-    ).lower(*args).compile().as_text()
+
+    def step(s, x, bm, cm, dt, a, d, act):
+        s, y = ssm_decode_step(s, x.reshape(b, h, p), bm, cm, dt, a, d, act,
+                               layer=4, heads=heads, interpret=False)
+        return s, y.reshape(b, h * p)
+
+    text = jax.jit(step, donate_argnums=0).lower(*args).compile().as_text()
     _assert_kernel(text)
     aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
     assert "alias" in aliased   # the state comes back in its own buffer
-    assert not _big_moves(text, b * h * p * n, f",{h},{p},{n}")
+    assert not _big_moves(text, b * n * h * p, f",{n},{k * p}")
+    assert not [m for m in _step_sized_moves(text, b * h * p) if m[0] == "transpose"]
 
 
 def test_granite_hybrid_pump_cell_shapes(one_chip, monkeypatch):
     """The decode program granite-4.0-h-small's cell serves: the batcher's pump
     over the paged layout at 64 slots x 2048, every published width, 10 layers
     of which one is attention, 36 held experts, bfloat16 weights and K/V, the
-    whole arena (K/V blocks, state, tails) donated. Both kernels are in it and
-    nothing the size of the state leaf is copied."""
+    whole arena (K/V blocks, state, tails) donated. Both kernels are in it
+    (nine scans, one attention), the state leaf ([9, 65, 64, 128, 128]) comes
+    back in its own buffer and nothing its size is copied, and the kernel's
+    rows are reshapes of a step's [B, H, P] vectors: no transpose their size
+    stands in the program."""
     from nnstreamer_tpu.models import granite_hybrid as gh
     from nnstreamer_tpu.models.serving import _PagedLayout, make_pump
     from nnstreamer_tpu.ops.pallas import ssm
@@ -311,6 +329,8 @@ def test_granite_hybrid_pump_cell_shapes(one_chip, monkeypatch):
         lambda x: sds(x.shape, x.dtype), tree)
     params = described(jax.eval_shape(lambda: gh.init_params(cfg, 0, bf16)))
     arena = described(jax.eval_shape(lambda: family.arena(b * nb, BS, False, b)))
+    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+    assert arena[2].shape == (9, b + 1, h // 2, n, 2 * p) and arena[2].dtype == f32
     vec, fvec = sds((b,), i32), sds((b,), f32)
     compiled = pump.lower(
         (params, None), vec, vec, sds((b,), jnp.bool_), arena,   # tok pos active
@@ -321,10 +341,23 @@ def test_granite_hybrid_pump_cell_shapes(one_chip, monkeypatch):
     ).compile()
     text = compiled.as_text()
     assert "jit_impl" in text  # the name benchmark/configs select it by
-    assert "ssm_decode_step" in text
-    assert text.count("tpu_custom_call") >= 10   # 9 scans and the attention
-    h, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    assert not _big_moves(text, b * h * p * n, f",{h},{p},{n}")
+    calls = re.findall(r"custom-call\([^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*",
+                       text)
+    assert sum("ssm_decode_step" in c for c in calls) == 9, len(calls)
+    assert sum("paged_decode_attention" in c for c in calls) == 1
+    assert not _big_moves(text, b * h * p * n, f",{n},{2 * p}")
+    # K/V blocks, state, tails and history: donated, back in their own buffers
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert aliased.count("may-alias") + aliased.count("must-alias") == 5
+    # beside a scan nothing the size of its [B, H x P] vectors is transposed;
+    # ONE copy a layer re-tiles the projection's output (lanes of 8 on the
+    # sublanes) into the kernel's rows. The [H, P, N] layout had six such
+    # moves a layer and copied the whole tails leaf twice a step.
+    moves = _step_sized_moves(text, b * h * p)
+    assert not [m for m in moves if m[0] == "transpose"], moves
+    assert len(moves) <= cfg.n_ssm, moves
+    assert all(math.prod(int(x) for x in dims.split(",")) == b * h * p
+               for _, dims in moves), moves
     # weights 9.5 GB + arena 3.0 GB + what a step holds besides: under the chip
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
