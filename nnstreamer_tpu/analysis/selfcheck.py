@@ -15,7 +15,7 @@ from __future__ import annotations
 import inspect
 import os
 import re
-from typing import Dict, List, Set
+from typing import Dict, List, Optional, Set
 
 from nnstreamer_tpu import registry
 from nnstreamer_tpu.elements.base import PROPS_ANY
@@ -151,18 +151,18 @@ def _repo_root() -> str:
     )))
 
 
-def _package_sources(skip: str):
+def _package_sources(skip: Optional[str] = None):
     """Text of every ``.py`` file of the package but ``skip`` (a catalog
     module does not count as an emitter of its own names)."""
     import os
 
     pkg_root = os.path.join(_repo_root(), "nnstreamer_tpu")
-    skip = os.path.join(pkg_root, skip)
+    skip = os.path.join(pkg_root, skip) if skip else None
     for dirpath, dirnames, filenames in os.walk(pkg_root):
         dirnames[:] = [d for d in dirnames if d != "__pycache__"]
         for fn in filenames:
             path = os.path.join(dirpath, fn)
-            if fn.endswith(".py") and not os.path.samefile(path, skip):
+            if fn.endswith(".py") and not (skip and os.path.samefile(path, skip)):
                 with open(path, encoding="utf-8") as f:
                     yield f.read()
 
@@ -203,8 +203,10 @@ def obs_self_check() -> List[str]:
                              "METRIC_CATALOG")
 
 
+# ``trace.span(`` / ``trace.instant(`` anywhere, and the bare ``instant(``
+# that trace.py's own emitters (the stall sentinel) call
 _SPAN_EMIT = re.compile(
-    r"""trace\.(?:span|instant)\(\s*["'](nns\.[a-z0-9_.]+)["']"""
+    r"""(?:trace\.|(?<![\w.]))(?:span|instant)\(\s*["'](nns\.[a-z0-9_.]+)["']"""
 )
 
 
@@ -217,7 +219,7 @@ def span_self_check() -> List[str]:
     from nnstreamer_tpu.trace import SPAN_CATALOG
 
     emitted: Set[str] = set()
-    for text in _package_sources("trace.py"):
+    for text in _package_sources():
         emitted |= set(_SPAN_EMIT.findall(text))
     return _catalog_problems("span", SPAN_CATALOG, emitted, "SPAN_CATALOG")
 

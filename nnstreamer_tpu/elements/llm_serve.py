@@ -49,9 +49,11 @@ from nnstreamer_tpu.elements.base import (
     Source,
     Spec,
 )
+from nnstreamer_tpu.log import get_logger
 from nnstreamer_tpu.tensors.frame import EOS_FRAME, Frame
 from nnstreamer_tpu.tensors.spec import TensorFormat, TensorsSpec
 
+_log = get_logger("llm_serve")
 _table: Dict[str, "_LlmServer"] = {}
 _table_lock = threading.Lock()
 
@@ -126,12 +128,13 @@ def _drop_server(srv_id: str, srv) -> None:
     when the table entry still matched: a stale server replaced by a
     fresh one under the same id would otherwise leak its ref forever.
     release_plane is idempotent, so the drained-then-stopped src's two
-    calls release once."""
+    calls release once; so is stopping (and joining) the stall sentinel."""
     with _table_lock:
         if srv is not None and _table.get(srv_id) is srv:
             _table.pop(srv_id, None)
     if srv is not None:
         srv.release_plane()
+        srv.stop_sentinel()
 
 
 def _build_batcher(model: str, options: Dict[str, str], n_slots: int,
@@ -436,15 +439,20 @@ class _LlmServer:
             self._mig_registered = True
             if self._ckpt_dir:
                 self._restore_checkpoints()
+        # the host's stalls on the trace's clock (trace.StallSentinel),
+        # under the operator's existing opt-in: metrics or a Tracer
+        self._sentinel = None
+        if self._obs_reg is not None or _trace.get() is not None:
+            try:
+                self._sentinel = _trace.StallSentinel(self._obs_reg)
+            except OSError as exc:  # no timerfd on this platform
+                _log.warning("no stall sentinel: %s", exc)
+
+    def stop_sentinel(self) -> None:
+        if self._sentinel is not None:
+            self._sentinel.stop()
 
     def submit(self, frame: Frame) -> None:
-        with _trace.span(
-            "nns.llm.submit",
-            prompt_tokens=int(np.prod(frame.tensors[0].shape)),
-        ):
-            self._submit(frame)
-
-    def _submit(self, frame: Frame) -> None:
         t_in = _time.perf_counter()
         if frame.meta.get("_nns_srv") is not None:
             # remember which edge serversrc feeds this server, so
@@ -948,8 +956,8 @@ class _LlmServer:
             # shared-batcher counters + ONLY this stream's request rows
             # (per-stream SLO ledgers: sharers never report each
             # other's — serving_plane/llm.py)
-            return self._plane.stats_for(self._stream)
-        st = self.cb.stats()
+            return self._sentinel_stats(self._plane.stats_for(self._stream))
+        st = self._sentinel_stats(self.cb.stats())
         # per-request SLO rows for nns-top --requests (serving_requests
         # once the executor prefixes the row)
         st["requests"] = {
@@ -971,6 +979,14 @@ class _LlmServer:
             st["spec_acceptance_ema"] = self._acc_ema
         elif self.speculate > 1:
             st["spec_k"] = self.speculate
+        return st
+
+    def _sentinel_stats(self, st: Dict) -> Dict:
+        """The stall sentinel's totals over the server's life, where one runs."""
+        s = self._sentinel
+        if s is not None:
+            st.update(host_stalls=s.stalls, host_stall_ms=s.stall_ms,
+                      host_stalls_machine=s.stalls_machine)
         return st
 
     def pop(self):

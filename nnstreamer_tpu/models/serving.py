@@ -1728,6 +1728,13 @@ class ContinuousBatcher:
         # the registry resolved ONCE here (the FaultGate discipline)
         self._obs_reg = _obs_metrics.get()
         self._slo = SLOLedger(keep=keep_results, obs_registry=self._obs_reg)
+        # the last harvest that left a slot decoding (perf_counter), for
+        # nns.pump.harvest's gap_ms and nns_decode_gap_ms
+        self._harvest_t: Optional[float] = None
+        self._gap_hist = (
+            self._obs_reg.histogram("nns_decode_gap_ms")
+            if self._obs_reg is not None else None
+        )
 
         self._draft = (
             _DraftEngine(
@@ -3386,8 +3393,16 @@ class ContinuousBatcher:
         row iterables) into their requests until budget/stop finishes
         them; returns ({rid: tokens}, n_emitted). One implementation of
         the budget/stop truncation discipline for every pump commit
-        path (caller holds _lock)."""
-        with _trace.span("nns.pump.harvest"):
+        path (caller holds _lock). Where the previous harvest left a slot
+        decoding, the time since it is the gap that slot waited
+        (``gap_ms``, ``nns_decode_gap_ms``)."""
+        now = _time.perf_counter()
+        gap = {}
+        if self._harvest_t is not None:
+            gap["gap_ms"] = (now - self._harvest_t) * 1e3
+            if self._gap_hist is not None:
+                self._gap_hist.observe(gap["gap_ms"])
+        with _trace.span("nns.pump.harvest", **gap):
             out: Dict[int, List[int]] = {}
             n_em = 0
             for s, req in enumerate(self._slots):
@@ -3409,6 +3424,7 @@ class ContinuousBatcher:
                     out[req.rid] = got
                 if req.finished():
                     self._finish(s)
+            self._harvest_t = now if self._active.any() else None
             return out, n_em
 
     def _pump_host_state(self, active_np):

@@ -225,6 +225,22 @@ METRIC_CATALOG: Dict[str, str] = {
         "per-request mean time per output token after the first, "
         "milliseconds (histogram; the decode SLO — docs/llm-serving.md)"
     ),
+    "nns_decode_gap_ms": (
+        "time a decoding slot waited from one harvest of a continuous "
+        "batcher's pump to the next, milliseconds (histogram; the "
+        "inter-delivery gap a streaming client feels — docs/observability.md)"
+    ),
+    "nns_host_stalls_total": (
+        "wake-ups of the LLM server's stall sentinel 20 ms or more late, by "
+        "kind label: machine (the kernel woke it late for half the stall or "
+        "more, or the process's CPU over it was under half) / process (woken "
+        "on time, it waited for the interpreter lock a running thread of the "
+        "process held) (counter)"
+    ),
+    "nns_host_stall_ms": (
+        "how late each of those wake-ups was, milliseconds, by kind label "
+        "(histogram)"
+    ),
     "nns_llm_setup_seconds": (
         "what starting a tensor_llm_serversink cost, by phase label, each "
         "set once: weights (the zoo model opened), batcher (the "

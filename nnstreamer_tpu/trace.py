@@ -37,6 +37,11 @@ that capability in-tree:
   event's stats; with a ``Tracer`` enabled the same call also records the
   chrome "X"/"i" event. With neither, an annotation the profiler ignores
   (under a microsecond). ``SPAN_CATALOG`` lists every ``nns.*`` name.
+- ``StallSentinel``: a thread that sleeps 5 ms at a time on a kernel timer
+  and writes ``nns.host.stall`` on the same clock when it wakes late, with
+  whether the machine (the kernel woke it late, or the process got no CPU)
+  or the process (it then waited for the interpreter lock) held it; the LLM
+  server runs one while metrics or a ``Tracer`` are on.
 
 Enable via ``trace.enable()`` / ``nns-launch --trace out.json``; env knob
 ``NNS_TRACE`` (path) mirrors the reference's GST_DEBUG_DUMP_DOT_DIR-style
@@ -46,8 +51,10 @@ opt-in (nnstreamer_conf env > ini > default priority, SURVEY.md §5.6).
 from __future__ import annotations
 
 import contextlib
+import ctypes
 import json
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -330,12 +337,6 @@ def get() -> Optional[Tracer]:
 # code and to docs/observability.md by analysis/selfcheck.span_self_check;
 # the names are what benchmark/lib/host_spans.py and its readers look for.
 SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
-    "nns.llm.submit": (
-        "elements and executor",
-        "_LlmServer.submit, whole call on the sink's thread: arrival at the "
-        "element to slot claimed, the back-pressure pumps included",
-        "prompt_tokens",
-    ),
     "nns.llm.admitted": (
         "elements and executor",
         "instant at the end of a submit that claimed a slot",
@@ -412,7 +413,23 @@ SPAN_CATALOG: Dict[str, Tuple[str, str, str]] = {
     "nns.pump.harvest": (
         "batcher",
         "commit of the carried state and _harvest_rows_locked, to return",
-        "",
+        "gap_ms (from the previous harvest to this one; only where the "
+        "previous harvest left a slot decoding)",
+    ),
+    "nns.host.stall": (
+        "host",
+        "instant: StallSentinel, a wake-up STALL_MIN_MS or more late; the "
+        "stall is [event - late_ms, event]",
+        "late_ms, os_late_ms (how late the kernel woke the thread; the rest "
+        "is its wait for the interpreter lock), cpu_ms (process CPU over the "
+        "same sleep), kind (machine where os_late_ms is at least, or cpu_ms "
+        "under, MACHINE_SHARE of late_ms; else process)",
+    ),
+    "nns.host.watch": (
+        "host",
+        "instant: StallSentinel's heartbeat, once a second",
+        "ticks (sleeps since the last heartbeat), late_max_ms (the latest "
+        "wake-up among them)",
     ),
     "nns.moe.routing": (
         "expert layer",
@@ -521,6 +538,127 @@ def instant(name: str, **attrs) -> None:
     t = get()
     if t is not None:
         t.instant(name, cat="span", **attrs)
+
+
+# -- the host's own stalls, on the same clock ---------------------------------
+
+STALL_TICK_S = 0.005      # the sentinel's sleep
+STALL_MIN_MS = 20.0       # a wake-up at least this late is a stall
+OS_GRAIN_MS = 1           # the period the kernel counts a late wake-up in
+MACHINE_SHARE = 0.5       # the share of a stall that tells the machine's from the process's
+WATCH_EVERY_S = 1.0       # the heartbeat
+
+
+def stall_kind(late_ms: float, os_late_ms: float, cpu_ms: float) -> str:
+    """``"machine"`` where nothing of this process was let run (the machine
+    stopped, or its CPUs were taken), by either of two witnesses: the
+    operating system woke the sleeping thread late for at least
+    ``MACHINE_SHARE`` of the stall, or the process's CPU time over it stayed
+    under that share. Otherwise ``"process"``: the thread was woken on time
+    and then waited for the interpreter lock, which a running thread of this
+    process held (a trace, a compile, a collection).
+
+    Each witness alone misses some freezes and neither invents one: the
+    kernel's count cannot see a freeze that falls while the thread, already
+    woken, waits for the lock; a CPU clock that charges a freeze to whatever
+    thread was running when it began reads it as work."""
+    share = MACHINE_SHARE * late_ms
+    return "machine" if os_late_ms >= share or cpu_ms < share else "process"
+
+
+class _Timer:
+    """A CLOCK_MONOTONIC timerfd (Linux, libc through ctypes). ``wait()``
+    arms it for ``STALL_TICK_S`` and then every ``OS_GRAIN_MS``, blocks in
+    ``read`` and returns the kernel's count of expirations at the moment the
+    thread was woken, taken before the thread waits for the interpreter lock
+    again: (count - 1) x ``OS_GRAIN_MS`` is how late the OS woke it."""
+
+    class _Spec(ctypes.Structure):
+        _fields_ = [("interval_s", ctypes.c_long), ("interval_ns", ctypes.c_long),
+                    ("value_s", ctypes.c_long), ("value_ns", ctypes.c_long)]
+
+    def __init__(self) -> None:
+        libc = ctypes.PyDLL(None, use_errno=True)  # settime keeps the GIL: a short syscall
+        create, self._settime = libc.timerfd_create, libc.timerfd_settime
+        create.argtypes, create.restype = [ctypes.c_int, ctypes.c_int], ctypes.c_int
+        self._settime.argtypes = [ctypes.c_int, ctypes.c_int,
+                                  ctypes.POINTER(self._Spec), ctypes.c_void_p]
+        self._settime.restype = ctypes.c_int
+        self._spec = self._Spec(0, OS_GRAIN_MS * 1_000_000, 0, int(STALL_TICK_S * 1e9))
+        self._fd = create(time.CLOCK_MONOTONIC, os.O_CLOEXEC)
+        if self._fd < 0:
+            raise OSError(ctypes.get_errno(), "timerfd_create")
+
+    def wait(self) -> int:
+        if self._settime(self._fd, 0, self._spec, None) < 0:
+            raise OSError(ctypes.get_errno(), "timerfd_settime")
+        return int.from_bytes(os.read(self._fd, 8), sys.byteorder)
+
+    def close(self) -> None:
+        os.close(self._fd)
+
+
+class StallSentinel:
+    """One daemon thread that sleeps ``STALL_TICK_S`` at a time and measures
+    how late it woke. A wake-up ``STALL_MIN_MS`` or more late writes the
+    instant ``nns.host.stall`` (``late_ms``: the stall is [event − late_ms,
+    event] on the trace's clock; ``os_late_ms``: how late the OS woke the
+    thread, the rest being its wait for the interpreter lock; ``cpu_ms``:
+    ``time.process_time()`` over the same sleep; ``kind`` by
+    :func:`stall_kind`) and, with a registry, counts
+    ``nns_host_stalls_total{kind}`` and ``nns_host_stall_ms{kind}``. Once a
+    second ``nns.host.watch`` (``ticks``, ``late_max_ms`` since the last one)
+    says the sentinel ran, so that no stall reads apart from no sentinel.
+    ``stalls``, ``stall_ms`` and ``stalls_machine`` are the totals of its life.
+    Raises OSError where the platform has no timerfd."""
+
+    def __init__(self, registry=None) -> None:
+        self._reg = registry
+        self._stopped = False
+        self.stalls = self.stalls_machine = 0
+        self.stall_ms = 0.0
+        self._timer = _Timer()
+        self._thread = threading.Thread(
+            target=self._run, name="nns-stall-sentinel", daemon=True)
+        self._thread.start()
+
+    def _run(self) -> None:
+        tick_ms = STALL_TICK_S * 1e3
+        ticks, late_max = 0, 0.0
+        t_watch = time.perf_counter()
+        try:
+            while not self._stopped:
+                t0, c0 = time.perf_counter(), time.process_time()
+                n = self._timer.wait()
+                t1, c1 = time.perf_counter(), time.process_time()
+                late = (t1 - t0) * 1e3 - tick_ms
+                ticks += 1
+                late_max = max(late_max, late)
+                if late >= STALL_MIN_MS:
+                    self._stall(late, (n - 1) * OS_GRAIN_MS, (c1 - c0) * 1e3)
+                if t1 - t_watch >= WATCH_EVERY_S:
+                    instant("nns.host.watch", ticks=ticks,
+                            late_max_ms=round(late_max, 3))
+                    ticks, late_max, t_watch = 0, 0.0, t1
+        finally:
+            self._timer.close()
+
+    def _stall(self, late_ms: float, os_late_ms: float, cpu_ms: float) -> None:
+        kind = stall_kind(late_ms, os_late_ms, cpu_ms)
+        instant("nns.host.stall", late_ms=round(late_ms, 3), os_late_ms=os_late_ms,
+                cpu_ms=round(cpu_ms, 3), kind=kind)
+        self.stalls += 1
+        self.stall_ms += late_ms
+        self.stalls_machine += kind == "machine"
+        if self._reg is not None:
+            self._reg.counter("nns_host_stalls_total", kind=kind).inc()
+            self._reg.histogram("nns_host_stall_ms", kind=kind).observe(late_ms)
+
+    def stop(self) -> None:
+        """End the thread and join it (idempotent)."""
+        self._stopped = True
+        if self._thread is not threading.current_thread():
+            self._thread.join(timeout=1.0)  # it wakes every STALL_TICK_S
 
 
 @contextlib.contextmanager

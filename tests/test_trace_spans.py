@@ -125,9 +125,13 @@ def ops(trace_file):
 # ``nns.moe.routing`` is written by the routed-expert families only and
 # ``nns.state.update`` by the two with per-slot state; this run serves the
 # dense block (tests/test_longcat.py, tests/test_kimi_linear.py and
-# tests/test_granite_hybrid.py trace them)
-@pytest.mark.parametrize(
-    "name", sorted(set(trace.SPAN_CATALOG) - {"nns.moe.routing", "nns.state.update"}))
+# tests/test_granite_hybrid.py trace them). ``nns.host.*`` come from the stall
+# sentinel, which runs only with metrics or a Tracer on (this run has neither),
+# and a stall only when the host stops (tests/test_host_stalls.py)
+NOT_HERE = {"nns.moe.routing", "nns.state.update", "nns.host.stall", "nns.host.watch"}
+
+
+@pytest.mark.parametrize("name", sorted(set(trace.SPAN_CATALOG) - NOT_HERE))
 def test_every_cataloged_span_is_on_the_profilers_timeline(traced, name):
     events, _, _ = traced
     assert any(e["name"] == name for e in events), (
@@ -191,8 +195,7 @@ def test_a_requests_events_share_its_rid_in_time_order(traced):
 def test_attributes_round_trip(traced):
     events, _, _ = traced
     by = lambda n: [e for e in events if e["name"] == n]  # noqa: E731
-    assert sorted(e["stats"]["prompt_tokens"] for e in by("nns.llm.submit")) \
-        == sorted(PROMPT_LENS)
+    assert len(by("nns.llm.admitted")) == len(PROMPT_LENS)
     assert all(e["stats"]["tokens"] == NEW and e["stats"]["tpot_ms"] > 0
                and e["stats"]["preemptions"] == 0 for e in by("nns.req.done"))
     assert all(e["stats"]["n_steps"] == PUMP for e in by("nns.pump"))
@@ -206,8 +209,32 @@ def test_attributes_round_trip(traced):
     # five requests on two slots: some submit waited, inside its span
     assert any(e["stats"]["retries"] > 0 and e["stats"]["slot_wait_ms"] > 0
                for e in admitted)
-    for a in admitted:
-        assert any(_inside(a, s) for s in by("nns.llm.submit"))
+    gaps = [e["stats"]["gap_ms"] for e in by("nns.pump.harvest") if "gap_ms" in e["stats"]]
+    assert gaps and all(g > 0 for g in gaps)
+
+
+def test_harvest_gap_is_the_spacing_of_successive_harvests(traced):
+    """``gap_ms`` is on every ``nns.pump.harvest`` after the first whose
+    predecessor left a slot decoding (its launch's ``active`` less the
+    requests it finished), and is the time between the two harvests."""
+    events, _, _ = traced
+
+    def by(n):
+        return sorted((e for e in events if e["name"] == n), key=lambda e: e["start"])
+
+    harvests, launches, dones = by("nns.pump.harvest"), by("nns.pump.launch"), by("nns.req.done")
+    assert len(harvests) > 2 and "gap_ms" not in harvests[0]["stats"]
+    spaced = 0
+    for prev, h in zip(harvests, harvests[1:]):
+        launch = [x for x in launches if x["start"] < prev["start"]][-1]
+        left = launch["stats"]["active"] - sum(_inside(d, prev) for d in dones)
+        if left > 0:
+            assert h["stats"]["gap_ms"] == pytest.approx(
+                (h["start"] - prev["start"]) * 1e-6, abs=1.0)
+            spaced += 1
+        else:
+            assert "gap_ms" not in h["stats"]
+    assert spaced > 0
 
 
 def test_admit_spans_count_what_they_spliced_in_one_launch(traced, ops):
@@ -317,7 +344,7 @@ def test_three_shares_sum_to_the_idle_share():
                 # a phase whose pump was open when the profiler stopped, and so
                 # was never recorded, is still the pump's
                 ("nns.pump.admit", "src", 95, 100),
-                ("nns.llm.submit", "sink", 22, 60)],  # not a class of its own
+                ("nns.host.stall", "watch", 22, 60)],  # not a class of its own
                [(10, 20), (30, 50)])
     try:
         pump = host_spans.idle_overlap(ctx, host_spans.PUMP)
@@ -331,7 +358,7 @@ def test_three_shares_sum_to_the_idle_share():
         assert table["nns.pump.wait"] == pytest.approx(3e-9)
         assert table["nns.pump.admit"] == pytest.approx(5e-9)
         assert table["nns.llm.emit"] == pytest.approx(emit)
-        assert "nns.llm.submit" not in table
+        assert "nns.host.stall" not in table
     finally:
         host_spans._parsed.clear()
 
