@@ -37,9 +37,10 @@ tail is the last three REAL inputs.
 The expert layer routes over every router output (sigmoid in float32 at highest
 precision, top-k of ``s + bias``, weights ``s / sum(s picked) * scale``), runs
 the pairs that fall on the experts held here through LongCat-Flash's
-sort-by-held-expert dispatch (a prompt's bucket) or every held expert over
-every token weighted by its gate (a decode step's few tokens), and adds the
-shared expert for every token. What the absent experts would have added is
+sort-by-held-expert dispatch (a prompt's bucket) or each held expert that
+some token picked over every token, weighted by its gate (a decode step's few
+tokens: ``ops/pallas/held_experts.held_experts_ffn``), and adds the shared
+expert for every token. What the absent experts would have added is
 left out.
 
 Storage dtype is stated by the caller, as in models/longcat.py: weights, latent
@@ -50,7 +51,6 @@ gates and the recurrent state float32. Weights follow the recipe of
 
 from __future__ import annotations
 
-import functools
 import math
 from dataclasses import dataclass
 from typing import Callable, Dict, Optional, Tuple
@@ -71,6 +71,13 @@ from nnstreamer_tpu.models.longcat import (
     mla_attend_expanded,
     mla_unabsorb,
     rmsnorm,
+)
+from nnstreamer_tpu.ops.dispatch import record
+from nnstreamer_tpu.ops.pallas._compat import pallas_ok
+from nnstreamer_tpu.ops.pallas.held_experts import (
+    gates_of,
+    held_experts_ffn,
+    held_experts_ffn_ref,
 )
 
 # the counters one decode step adds up on the device (nns.moe.routing,
@@ -95,10 +102,11 @@ KDA_CHUNK = 64
 # of 8 a token): a bucket of more than MOE_FEW_PAIRS pairs tries 5/16 of them
 # first (a prompt's 4096 pairs hold 1024 +- 28 local ones)
 MOE_FEW = 5 / 16
-# up to this many tokens (a decode step's lanes) every held expert runs over
-# every token: at 128 tokens the 64 held experts are 116 GFLOP a layer, 0.6 ms
-# of an idle matrix unit, under the 1.1 ms their weights take to stream; the
-# grouped matmul over 4 pairs an expert took 2.3 ms (PERF.md section 6, PR 33)
+# up to this many tokens (a decode step's lanes) each held expert that some
+# token picked streams once, every token through it (``held_experts_ffn``): at
+# 128 tokens an expert is 1.8 GFLOP, under the time its weights take to
+# stream; the grouped matmul over 4 pairs an expert took 2.3 ms a layer
+# (PERF.md section 6)
 MOE_DENSE_TOKENS = 128
 _HI = jax.lax.Precision.HIGHEST
 
@@ -464,14 +472,21 @@ def moe(b, live, lp: Dict, c: KimiLinearConfig, shared: bool = True):
     """The expert layer's share. b [T, d] float32 (normed), live [T] bool ->
     (y [T, d] float32: the held experts' weighted outputs and, where
     ``shared``, the shared expert's; stats [4] int32 in ``MOE_STATS`` order).
-    Up to ``MOE_DENSE_TOKENS`` tokens (a decode step) every held expert runs
-    over every token; a prompt's bucket takes the sort-by-held-expert dispatch."""
+    Up to ``MOE_DENSE_TOKENS`` tokens (a decode step) each held expert that
+    some token picked runs over every token (``held_experts_ffn``); a
+    prompt's bucket takes the sort-by-held-expert dispatch."""
     e0, n = c.expert_offset, c.n_held
     idx, w = route(b, lp, c)
     local = live[:, None] & (idx >= e0) & (idx < e0 + n)
-    run = experts_dense if b.shape[0] <= MOE_DENSE_TOKENS else functools.partial(
-        dispatch_held, few=MOE_FEW)
-    y, sizes = run(b, w, local, jnp.where(local, idx - e0, n), lp, n)
+    group = jnp.where(local, idx - e0, n)
+    if b.shape[0] <= MOE_DENSE_TOKENS:
+        ok, _ = pallas_ok("held_experts_ffn", lp["e_gate"].dtype)
+        record("moe_experts", "pallas" if ok else "jnp")
+        y, sizes = (held_experts_ffn if ok else held_experts_ffn_ref)(
+            b.astype(lp["e_gate"].dtype), gates_of(w, group, n),
+            lp["e_gate"], lp["e_up"], lp["e_down"])
+    else:
+        y, sizes = dispatch_held(b, w, local, group, lp, n, few=MOE_FEW)
     if shared:
         y = y + ffn(b[None], lp["shared"])[0]
     stats = jnp.stack([
@@ -617,7 +632,6 @@ def decode_step(params, tok, pos, active, arena, tables, c: KimiLinearConfig,
     pos', aux [5] int32: the MoE stats summed over layers, then the (live
     lane, KDA layer) state updates)."""
     from nnstreamer_tpu.kv.block_attn import write_fresh_window
-    from nnstreamer_tpu.ops.dispatch import record
     from nnstreamer_tpu.ops.pallas.kda import kda_decode_step, kda_decode_step_ref
     from nnstreamer_tpu.ops.pallas.mla_attention import mla_paged_attention_ref
 

@@ -25,7 +25,9 @@ highest precision, top-k of ``p + bias``, weights ``scale * p``), sorts the
 pairs that fall on its own experts by expert, runs one grouped matmul over the
 experts held (no capacity, no token dropped; pairs of absent experts and of
 dead tokens form the tail no group covers) and adds the identity experts'
-``(sum w) b``. What the absent experts would have added is left out.
+``(sum w) b``. What the absent experts would have added is left out. A
+decode step's few tokens instead stream each held expert that some token
+picked once, through ``ops/pallas/held_experts.held_experts_ffn``.
 
 Storage dtype is stated by the caller: weights and latent cache are held in
 ``dtype`` (bfloat16 as served, float32 in the CPU tests); residual, norms,
@@ -47,6 +49,14 @@ from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from nnstreamer_tpu.ops.dispatch import record
+from nnstreamer_tpu.ops.pallas._compat import pallas_ok
+from nnstreamer_tpu.ops.pallas.held_experts import (
+    gates_of,
+    held_experts_ffn,
+    held_experts_ffn_ref,
+)
 
 NEG_INF = -1e30
 LANES = 128
@@ -325,6 +335,10 @@ def grouped_ffn_ragged(xs, sizes, lp: Dict):
 # 6144 pairs hold about 128 local ones, an eighth covers them with room, and
 # the worst case stays exact
 MOE_FEW_PAIRS = 1024
+# up to this many tokens (a decode step's lanes) each held expert that some
+# token picked streams once, every token through it (``held_experts_ffn``);
+# a prompt's bucket takes the grouped matmul
+MOE_DENSE_TOKENS = 128
 
 
 def dispatch_held(b, w, local, group, lp: Dict, n: int, few: float = 1 / 8):
@@ -376,7 +390,15 @@ def moe(b, live, lp: Dict, c: LongcatConfig):
     zero = pick & (idx >= c.n_routed)
     local = pick & (idx >= e0) & (idx < e0 + n)
     ident = jnp.sum(jnp.where(zero, w, 0.0), axis=-1)
-    s, sizes = dispatch_held(b, w, local, jnp.where(local, idx - e0, n), lp, n)
+    group = jnp.where(local, idx - e0, n)
+    if b.shape[0] <= MOE_DENSE_TOKENS:
+        ok, _ = pallas_ok("held_experts_ffn", lp["e_gate"].dtype)
+        record("moe_experts", "pallas" if ok else "jnp")
+        s, sizes = (held_experts_ffn if ok else held_experts_ffn_ref)(
+            b.astype(lp["e_gate"].dtype), gates_of(w, group, n),
+            lp["e_gate"], lp["e_up"], lp["e_down"])
+    else:
+        s, sizes = dispatch_held(b, w, local, group, lp, n)
     s = s + ident[:, None] * b
     stats = jnp.stack([
         jnp.sum(live), jnp.sum(local), jnp.sum(sizes > 0), jnp.sum(zero),
@@ -495,7 +517,6 @@ def decode_step(params, tok, pos, active, arena, tables, c: LongcatConfig,
     sublayer takes its view through the tables (the XLA oracle there).
     -> (logits [B, V], arena, pos', MoE stats [5] int32 summed over layers)."""
     from nnstreamer_tpu.kv.block_attn import write_fresh_window
-    from nnstreamer_tpu.ops.dispatch import record
     from nnstreamer_tpu.ops.pallas.mla_attention import mla_paged_attention_ref
 
     record("mla_attention", "xla" if attn_fn is None else "pallas")

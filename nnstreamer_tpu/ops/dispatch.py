@@ -60,6 +60,7 @@ KNOWN_OPS = (
     "flash_attention",
     "kda_recurrence",
     "mla_attention",
+    "moe_experts",
     "nms",
     "resize_bilinear",
     "serving_attention",

@@ -4,7 +4,7 @@ XLA fuses most of the pipeline (SURVEY.md §7 design mapping); these kernels
 cover the cases where explicit VMEM blocking beats the fusion XLA picks —
 flash attention, the serving decode kernels (contiguous and paged cache
 layouts, the latent cache, Kimi Delta Attention's and Mamba-2's recurrent
-state), and the
+state, the routed experts some token picked), and the
 pre/post-processing set (docs/on-device-ops.md):
 MXU bilinear crop/resize with a fused normalize epilogue, and the greedy
 NMS suppression recurrence. Every kernel has an ``interpret=True`` path so
@@ -23,6 +23,7 @@ from nnstreamer_tpu.ops.pallas.image_kernels import (  # noqa: F401
     crop_and_resize,
     resize_bilinear,
 )
+from nnstreamer_tpu.ops.pallas.held_experts import held_experts_ffn  # noqa: F401
 from nnstreamer_tpu.ops.pallas.kda import kda_decode_step  # noqa: F401
 from nnstreamer_tpu.ops.pallas.mla_attention import (  # noqa: F401
     mla_paged_decode_attention,

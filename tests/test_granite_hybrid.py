@@ -634,16 +634,20 @@ def test_state_instant_is_on_the_profilers_timeline(tmp_path):
 
 # -- the accepted expert families' programs are the parent's ------------------
 
-# sha256 of the lowered text of each program at a small size, taken on the
-# parent commit (c8e5186) by this same function: the new family imports
-# longcat's and Kimi-Linear's functions and edits none, so neither family gets
-# a new compile-cache key (PERF.md section 7, 5: what refused PR 32)
+# sha256 of the lowered text of each program at a small size, by this same
+# function: the new family imports longcat's and Kimi-Linear's functions and
+# edits none, so neither family gets a new compile-cache key (PERF.md section
+# 7, 5). The prompt programs, over a bucket of more than ``MOE_DENSE_TOKENS``
+# tokens as every served bucket is, were taken on commit 25e2794 and have not
+# moved since; the decode programs were taken again when their expert layers
+# moved to ``held_experts_ffn`` (PERF.md section 6).
 PARENT_PROGRAMS = {
-    "longcat.prefill": "b1ef732d11e84a1df716f51916b622aa9033181cb5fee4bbb4e120c381041623",
-    "longcat.decode": "2fe3847fbf86a73d9814910e959ac90b724444804ff98ec1c1371d0f7e22d81a",
-    "kimi_linear.prefill": "ca658fa041cee37a34767058cd1d48aadc20258e8b58f81c15ba99e78dcd0b48",
-    "kimi_linear.decode": "0838f7bc609f89f350e1c5ff287f85db82b1fc1ff76f0df79c10083271c36cbc",
+    "longcat.prefill": "ac196f07a3ca1f70e18a7d8ec8f08020c0e87823ed6b7dd83cf8411a4c081dce",
+    "longcat.decode": "d5f653f70f423e3969e3e38c659169b2cfabb842f182107dd3060cd748676efa",
+    "kimi_linear.prefill": "84dcc7068b104f785fd8c7df5f45fcdcf5d11b4fb9ecbccb9038ca5afcb5bd78",
+    "kimi_linear.decode": "e4051ab82488177e5af0f90d9ce7615fd0264f7f63ac0850e4a073cfb50e7b01",
 }
+PROMPT = 160  # a bucket that takes the grouped matmul
 
 
 def lowered_programs():
@@ -670,7 +674,7 @@ def lowered_programs():
         arena = jax.eval_shape(lambda: fam.arena(8, 16, False, 2))
         i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
         texts = {
-            "prefill": jax.jit(fam.prefill).lower(params, i32((1, 32))).as_text(),
+            "prefill": jax.jit(fam.prefill).lower(params, i32((1, PROMPT))).as_text(),
             "decode": jax.jit(fam.decode_step).lower(
                 params, i32((2,)), i32((2,)), jax.ShapeDtypeStruct((2,), jnp.bool_),
                 arena, i32((2, 4))).as_text(),

@@ -324,9 +324,10 @@ def test_the_four_shares_add_up_to_the_uncut_expert_layer(ref):
 
 @pytest.mark.parametrize("path", ["dense", "grouped", "grouped-few-rows"])
 def test_the_expert_layers_three_paths_agree(monkeypatch, path):
-    """A step's few tokens run every held expert densely; a bucket takes the
-    sort-by-held-expert dispatch, above ``MOE_FEW_PAIRS`` pairs 5/16 of the
-    rows first: the same layer, the same counters."""
+    """A step's few tokens run each held expert some token picked
+    (``held_experts_ffn``); a bucket takes the sort-by-held-expert dispatch,
+    above ``MOE_FEW_PAIRS`` pairs 5/16 of the rows first: the same layer, the
+    same counters."""
     from nnstreamer_tpu.models import longcat as lc
 
     cfg = _cfg(2, 2)

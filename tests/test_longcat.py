@@ -372,11 +372,13 @@ def test_routing_instant_is_on_the_profilers_timeline(tmp_path):
 def test_many_pairs_take_the_short_grouped_matmul_only_when_it_holds_them(
         monkeypatch, n_held, offset, path):
     """Above ``MOE_FEW_PAIRS`` pairs the layer first tries an eighth of the
-    rows; both branches give what the single grouped matmul gives."""
+    rows; both branches give what the single grouped matmul gives. (40
+    tokens are a bucket here: a decode step's few take the kernel.)"""
     cfg = _cfg(n_held, offset)
     lp = lc.init_params(cfg, 4, jnp.float32)["layers"][1]
     b = jnp.asarray(np.random.default_rng(5).normal(size=(40, cfg.d_model)), jnp.float32)
     live = jnp.ones((40,), bool).at[7].set(False)
+    monkeypatch.setattr(lc, "MOE_DENSE_TOKENS", 0)
     want, stats = lc.moe(b, live, lp, cfg)
     monkeypatch.setattr(lc, "MOE_FEW_PAIRS", 16)
     assert (int(stats[1]) <= 40 * cfg.topk // 8) == (path == "few")

@@ -261,6 +261,31 @@ def test_kda_decode_step_cell_shape(one_chip, heads):
     assert not _big_moves(text, b * h * d * d, f",{h},{d},{d}")
 
 
+#: (tokens, d_model, expert width, held experts) of a decode step's expert layer
+EXPERT_CELLS = {"longcat-flash-chat": (64, 6144, 2048, 16),
+                "kimi-linear-48b-a3b": (128, 2304, 1024, 64)}
+
+
+@pytest.mark.parametrize("cell", list(EXPERT_CELLS))
+def test_held_experts_ffn_cell_shapes(one_chip, cell):
+    """The decode step's expert kernel as the two cells launch it: every
+    held expert's bfloat16 weights whole (the kernel reads the hit ones
+    through its index maps), a step's tokens, float32 gates. Nothing the
+    size of one expert's projection is copied or sliced beside it."""
+    from nnstreamer_tpu.ops.pallas.held_experts import held_experts_ffn
+
+    t, d, f, n = EXPERT_CELLS[cell]
+    bf16 = jnp.bfloat16
+    text = _compile(
+        lambda x, g, wg, wu, wd: held_experts_ffn(x, g, wg, wu, wd, interpret=False),
+        one_chip, ((t, d), bf16), ((t, n), f32), ((n, d, f), bf16),
+        ((n, d, f), bf16), ((n, f, d), bf16),
+    )
+    _assert_kernel(text)
+    assert "held_experts_ffn" in text   # the name the roofline's reader finds
+    assert not _big_moves(text, d * f)
+
+
 def _step_sized_moves(text, floor):
     """Every float32 ``copy`` / ``transpose`` of at least ``floor`` elements:
     a step's vectors are float32; the weights a program also moves are not."""
